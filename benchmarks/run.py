@@ -21,6 +21,7 @@ import argparse
 import sys
 import time
 import traceback
+from pathlib import Path
 
 
 def main() -> None:
@@ -29,6 +30,8 @@ def main() -> None:
                     help="substring filter on benchmark module names")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parent.parent)
     from benchmarks import (bench_batch, bench_cascade, bench_serve,
                             fig8_tradeoff, kernels_bench, sinkhorn_compare,
                             table3_complexity, table5_mnist, table6_dense)

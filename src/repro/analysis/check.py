@@ -63,9 +63,10 @@ def _parse(argv):
     p.add_argument("--update-manifests", action="store_true",
                    help="regenerate the golden collective manifest "
                         "before checking against it")
-    p.add_argument("--vmem-budget-mb", type=float, default=16.0,
+    p.add_argument("--vmem-budget-mb", type=float, default=None,
                    help="per-core VMEM budget the kernel layouts must "
-                        "clear (default: 16)")
+                        "clear (default: the kernels' scoped-VMEM "
+                        "request, kernels/tiling.VMEM_LIMIT_BYTES)")
     return p.parse_args(argv)
 
 
@@ -99,7 +100,7 @@ def main(argv=None) -> int:
     for name in selected:
         mod = importlib.import_module(PASSES[name][0])
         kwargs = {}
-        if name == "vmem":
+        if name == "vmem" and args.vmem_budget_mb is not None:
             kwargs["budget_bytes"] = int(args.vmem_budget_mb * 2**20)
         if name == "collectives":
             kwargs["update_manifests"] = args.update_manifests

@@ -24,6 +24,9 @@ wire model). Two contracts:
   ``search`` is exempt (``lax.top_k`` does not partition — its top-l
   legitimately gathers scores; the cascade step exists to avoid that),
   as are fractional-budget cascades (candidate counts scale by design).
+* **narrowing** — every bf16 case must all-gather fewer bytes than its
+  float32 twin (the same name without ``:bf16``): the policy exists to
+  narrow the ladder handoff on the wire.
 
 Requires 8 host devices: the CLI sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax
@@ -151,6 +154,25 @@ def check_scaling(case: S.StepCase, mesh, *,
     return []
 
 
+def check_narrowing(steps: dict[str, dict], cases) -> list[Violation]:
+    """bf16 cases whose all-gather bytes (``steps``: name -> profile) do
+    not undercut their float32 twin's."""
+    out = []
+    for case in cases:
+        if case.precision != "bf16" or case.name not in steps:
+            continue
+        twin = case.name.removesuffix(":bf16")
+        narrow = steps[case.name].get("all-gather", 0.0)
+        wide = steps.get(twin, {}).get("all-gather", 0.0)
+        if not narrow < wide:
+            out.append(Violation(
+                "collectives", case.name,
+                f"bf16 step all-gathers {narrow:.0f} bytes, not fewer than "
+                f"its float32 twin {twin} ({wide:.0f}) — the narrowed "
+                "handoff is crossing the mesh at full width"))
+    return out
+
+
 def run(*, update_manifests: bool = False,
         manifest_path: str = MANIFEST_PATH,
         ) -> tuple[list[Violation], int]:
@@ -180,8 +202,9 @@ def run(*, update_manifests: bool = False,
                   "still enforced")
 
     w = check_workload()
+    measured = {}
     for case in cases:
-        got = step_collectives(case, w, mesh)
+        got = measured[case.name] = step_collectives(case, w, mesh)
         want = pinned.get(case.name)
         if want is None:
             if manifest is not None:
@@ -198,6 +221,7 @@ def run(*, update_manifests: bool = False,
                 print(f"collectives: WARN {case.name}: {msg}")
         if case.scale_guarded:
             out += check_scaling(case, mesh)
+    out += check_narrowing(measured, cases)
 
     stale = sorted(set(pinned) - {c.name for c in cases})
     for name in stale:

@@ -11,7 +11,7 @@ included):
   be a single dispatched program. The host-only exact-EMD rescorer is
   exactly the thing this catches if someone traces it into a mesh step.
 * **float64 promotions** — each step is traced UNDER x64 mode
-  (``jax.experimental.enable_x64``) with its real float32/int32 input
+  (``jax.enable_x64``) with its real float32/int32 input
   avals; any equation then producing f64/c128 reveals a latent promotion
   (a Python float folded at trace time, an np.float64 constant) that
   doubles memory and collective bytes the moment a caller enables x64.
@@ -98,7 +98,7 @@ def check_fn(name: str, fn, specs, *,
     hazard being probed.
     """
     try:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(fn)(*specs)
     except Exception as e:  # noqa: BLE001 - surface, don't crash the suite
         return [Violation("hazards", name,

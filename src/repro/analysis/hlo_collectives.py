@@ -47,11 +47,15 @@ _CONST_RE = re.compile(r"constant\((\d+)\)")
 _CALL_RE = re.compile(r"(?:to_apply|calls)=%?([\w\.\-]+)")
 _OPERAND_RE = re.compile(
     r"\(\s*(?:[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?\s+)?%([\w\.\-]+)")
-#: ``<wide> convert(<narrow>[...`` — the CPU collective-type widener's
-#: producer-side upcast (narrow float -> the collective's wire dtype).
-_NARROW_CONVERT_RE = re.compile(
-    r"=\s*(?P<wide>f32|f64)\[[0-9,]*\](?:\{[^}]*\})?\s+"
-    r"convert\(\s*(?P<narrow>bf16|f16|f8e4m3fn|f8e5m2)\[")
+#: ``<wide> convert(...%src)`` — the CPU collective-type widener's
+#: producer-side upcast into the collective's wire dtype. The operand's
+#: own type is printed inline by older XLA (``convert(bf16[..] %src)``)
+#: and only at its definition by newer XLA (``convert(%src)``).
+_WIDE_CONVERT_RE = re.compile(
+    r"=\s*(?P<wide>f32|f64)\[[0-9,]*\](?:\{[^}]*\})?\s+convert\(\s*"
+    r"(?:(?P<dtype>[a-z0-9]+)\[[0-9,]*\](?:\{[^}]*\})?\s+)?"
+    r"%(?P<src>[\w\.\-]+)")
+_NARROW_FLOATS = ("bf16", "f16", "f8e4m3fn", "f8e5m2")
 
 
 def _shape_bytes(text: str) -> int:
@@ -147,11 +151,25 @@ def _semantic_scale(line: str, kind: str, comps, comp_lines) -> float:
     if cm and "fusion" in prod:
         cands += comps.get(cm.group(1), [])
     for ln in cands:
-        nm = _NARROW_CONVERT_RE.search(ln)
-        if nm and nm.group("wide") == wire:
-            return (_DTYPE_BYTES[nm.group("narrow")]
-                    / _DTYPE_BYTES[nm.group("wide")])
+        cm = _WIDE_CONVERT_RE.search(ln)
+        if not cm or cm.group("wide") != wire:
+            continue
+        narrow = cm.group("dtype") or _defined_dtype(cm.group("src"),
+                                                     cands + comp_lines)
+        if narrow in _NARROW_FLOATS:
+            return _DTYPE_BYTES[narrow] / _DTYPE_BYTES[wire]
     return 1.0
+
+
+def _defined_dtype(name: str, lines) -> str | None:
+    """Element type at the definition ``%name = <dtype>[...`` in
+    ``lines``, or None."""
+    pat = re.compile(rf"%{re.escape(name)}\s+=\s+([a-z0-9]+)\[")
+    for ln in lines:
+        m = pat.search(ln)
+        if m:
+            return m.group(1)
+    return None
 
 
 def collective_bytes(hlo: str, n_devices: int) -> dict[str, float]:

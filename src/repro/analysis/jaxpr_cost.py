@@ -22,17 +22,7 @@ import math
 import jax
 import numpy as np
 
-try:
-    # The supported introspection surface (jax >= 0.4.16 ships
-    # jax.extend.core; ClosedJaxpr joined it later).
-    from jax.extend import core as jcore
-
-    _ = jcore.ClosedJaxpr
-except (ImportError, AttributeError):  # pragma: no cover - old-jax shim
-    # Fallback for jax builds whose extend surface predates ClosedJaxpr.
-    # Private import, kept ONLY as the shim: it breaks silently on jax
-    # upgrades, which is why the supported path above is tried first.
-    from jax._src import core as jcore
+from jax.extend import core as jcore
 
 _RECURSE_PARAM_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr", "cond_jaxpr")
 #: Body-carrying params of the control-flow primitives ``_count`` handles

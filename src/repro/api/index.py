@@ -35,12 +35,13 @@ def _pad_rows(x: Array, n_padded: int) -> Array:
     return jnp.pad(x, ((0, n_padded - x.shape[0]), (0, 0)))
 
 
-def _mesh_context(mesh):
-    """Ambient-mesh context for sharding annotations. ``jax.set_mesh``
-    landed after 0.4.x; without it the in_shardings on the jitted step
-    still place data correctly and ``annotate.constrain`` no-ops."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh else contextlib.nullcontext()
+def _ambient_mesh(mesh):
+    """``jax.set_mesh(mesh)``, unless ``mesh`` already is the ambient
+    mesh — as when a caller jit-compiles ``search`` under it, where
+    ``set_mesh`` cannot be entered."""
+    if jax.sharding.get_abstract_mesh() == mesh.abstract_mesh:
+        return contextlib.nullcontext()
+    return jax.set_mesh(mesh)
 
 
 @dataclasses.dataclass(frozen=True, repr=False)
@@ -200,7 +201,7 @@ class EmdIndex:
         qi = _pad_rows(qi, -(-nq // dp) * dp)
         qw = _pad_rows(qw, -(-nq // dp) * dp)
         p = self._padded_corpus
-        with _mesh_context(self._mesh):
+        with _ambient_mesh(self._mesh):
             return step(p.ids, p.w, p.coords, qi, qw, *extra)
 
     def scores(self, q_ids: Array, q_w: Array) -> Array:

@@ -31,6 +31,7 @@ from repro.candidates.base import (EMPTY_CENTER, SourceSpec,
                                    refine_by_centroid, register_source,
                                    slot_centroids)
 from repro.core import lc
+from repro.core.geometry import weighted_centroids
 
 
 @register_source
@@ -208,7 +209,7 @@ class CentroidLSHSource:
         ``refine``; ``budget`` truncates to the best-ranked columns.
         Jittable; every shape is fixed by the spec, and the only data
         touched scales with probed rows."""
-        qc = jnp.einsum("qh,qhm->qm", q_w, corpus.coords[q_ids])
+        qc = weighted_centroids(q_w, corpus.coords[q_ids])
         d = jnp.linalg.norm(self.centroids[None, :, :] - qc[:, None, :],
                             axis=-1)
         # EMPTY_CENTER distances overflow to +inf, which breaks the

@@ -30,6 +30,7 @@ from repro.candidates.base import (EMPTY_CENTER, SourceSpec,
                                    refine_by_centroid, register_source,
                                    slot_centroids)
 from repro.core import lc
+from repro.core.geometry import weighted_centroids
 
 
 def _level_offset(branching: int, level: int) -> int:
@@ -224,7 +225,7 @@ class ClusterTreeSource:
         the ``probes`` best leaves' rows. ``budget`` truncates to the
         best-ranked columns."""
         spec, B = self.spec, self.spec.branching
-        qc = jnp.einsum("qh,qhm->qm", q_w, corpus.coords[q_ids])
+        qc = weighted_centroids(q_w, corpus.coords[q_ids])
         nq = q_ids.shape[0]
         # Level 1: score all B children of the (implicit) root.
         lb = self._bound(qc, jnp.broadcast_to(

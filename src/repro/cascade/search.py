@@ -50,6 +50,10 @@ class CascadeResult(NamedTuple):
     indices: Array
 
 
+#: Largest per-block budget the shard-blocked top-k unrolls.
+SHARD_TOPK_MAX = 128
+
+
 def topk_smallest(scores: Array, k: int, blocks: int = 1):
     """(values, indices) of the k smallest entries per row, ascending.
 
@@ -57,7 +61,11 @@ def topk_smallest(scores: Array, k: int, blocks: int = 1):
     step's ladder merge): per-block local top-k, then one merge over the
     ``blocks * min(k, n/blocks)`` winners. Exact for any block count —
     a block can hold at most min(k, n/blocks) of the true top-k — and
-    falls back to plain ``lax.top_k`` when n does not split evenly.
+    falls back to plain ``lax.top_k`` when n does not split evenly, and
+    when a block keeps more than ``SHARD_TOPK_MAX`` rows: the extraction
+    unrolls one round per kept row (a 40% budget over 4 shards of
+    20News is ~4,700 rounds, minutes of compiling), and a block that
+    keeps all its rows selects nothing at all.
 
     The per-block selection is ``lc.streaming_smallest_k``, NOT
     ``lax.top_k``: top_k lowers to a sort/TopK custom call the SPMD
@@ -69,8 +77,8 @@ def topk_smallest(scores: Array, k: int, blocks: int = 1):
     and makes the same selection (ascending, ties to the lowest column).
     """
     n = scores.shape[-1]
-    if blocks > 1 and n % blocks == 0:
-        per = n // blocks
+    per = n // blocks
+    if blocks > 1 and n % blocks == 0 and min(k, per) <= SHARD_TOPK_MAX:
         kb = min(k, per)
         s = annotate.emd_shard_topk(
             scores.reshape(scores.shape[:-1] + (blocks, per)))
@@ -118,14 +126,25 @@ def stage_rows(spec: CascadeSpec, n: int, top_l: int) -> dict[str, int]:
     return rows
 
 
+def _row_order(cand: Array, cmask: Array | None):
+    """Survivors sorted by global row id (their mask alongside). Every
+    later selection takes the lowest POSITION among exactly tied scores;
+    in row order that is the lowest row id — the full-corpus top-k's own
+    rule, without which a tie at the top-l boundary could return another
+    row than full-corpus search does."""
+    if cmask is None:
+        return jnp.sort(cand, axis=1), None
+    return tuple(jax.lax.sort((cand, cmask), dimension=1, num_keys=1))
+
+
 def _prune(corpus: lc.Corpus, Q_ids: Array, Q_w: Array, spec: CascadeSpec,
            budgets: tuple[int, ...], *, n_valid, topk_blocks, engine,
            source=None, **knobs):
     """Run the pruning ladder; returns ``(cand, cmask)``: the
-    (nq, budgets[-1]) global row ids surviving every stage, plus their
-    validity mask when stage 1 was fed by a sublinear source (``None``
-    on the full-scan path, where every survivor is real). Traced under
-    jit by the callers.
+    (nq, budgets[-1]) global row ids surviving every stage, in row order
+    (:func:`_row_order`), plus their validity mask when stage 1 was fed
+    by a sublinear source (``None`` on the full-scan path, where every
+    survivor is real). Traced under jit by the callers.
 
     Full scan keeps the original path BITWISE: full-corpus
     ``batch_scores`` + (shard-blocked) top-budget. A sourced stage 1
@@ -153,6 +172,7 @@ def _prune(corpus: lc.Corpus, Q_ids: Array, Q_w: Array, spec: CascadeSpec,
         _, pos = topk_smallest(sc, budgets[0])
         cand = jnp.take_along_axis(cand, pos, axis=1)
         cmask = jnp.take_along_axis(cmask, pos, axis=1)
+    cand, cmask = _row_order(cand, cmask)
     for stage, b in zip(spec.stages[1:], budgets[1:], strict=True):
         sc = retrieval.cand_scores(corpus, Q_ids, Q_w, cand,
                                    method=stage.method, iters=stage.iters,
@@ -163,6 +183,7 @@ def _prune(corpus: lc.Corpus, Q_ids: Array, Q_w: Array, spec: CascadeSpec,
         cand = jnp.take_along_axis(cand, pos, axis=1)
         if cmask is not None:
             cmask = jnp.take_along_axis(cmask, pos, axis=1)
+        cand, cmask = _row_order(cand, cmask)
     return cand, cmask
 
 

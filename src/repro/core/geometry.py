@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import matmul_precision
+
 Array = jax.Array
 
 #: RELATIVE zero-snap: squared distances below ZERO_SNAP^2 x (|a|^2+|b|^2)
@@ -27,7 +29,7 @@ def pairwise_sqdist(a: Array, b: Array) -> Array:
     b = jnp.asarray(b)
     a2 = jnp.sum(a * a, axis=-1, keepdims=True)          # (na, 1)
     b2 = jnp.sum(b * b, axis=-1, keepdims=True).T        # (1, nb)
-    cross = a @ b.T                                      # (na, nb) — MXU
+    cross = jnp.matmul(a, b.T, precision=matmul_precision(a.dtype))  # MXU
     return jnp.maximum(a2 + b2 - 2.0 * cross, 0.0)
 
 
@@ -40,8 +42,9 @@ def pairwise_dist(a: Array, b: Array, snap: float = ZERO_SNAP, *,
     MXU matmul OPERANDS to the reduced dtype — the contraction still
     accumulates into float32 (``preferred_element_type``), and the norm
     terms, snap, and sqrt stay in the input dtype, so the result dtype
-    is unchanged. ``None`` (or the input dtype itself) leaves the
-    original graph untouched.
+    is unchanged. ``None`` (or the input dtype itself) contracts in the
+    input dtype. Either way the operand dtype sets the MXU precision
+    (:func:`~repro.core.precision.matmul_precision`).
     """
     a = jnp.asarray(a)
     b = jnp.asarray(b)
@@ -50,14 +53,22 @@ def pairwise_dist(a: Array, b: Array, snap: float = ZERO_SNAP, *,
     if compute_dtype is not None and jnp.dtype(compute_dtype) != a.dtype:
         cross = jax.lax.dot_general(
             a.astype(compute_dtype), b.astype(compute_dtype),
-            (((1,), (1,)), ((), ())),
+            (((1,), (1,)), ((), ())), precision=matmul_precision(compute_dtype),
             preferred_element_type=jnp.float32).astype(a.dtype)
     else:
-        cross = a @ b.T
+        cross = jnp.matmul(a, b.T, precision=matmul_precision(a.dtype))
     d2 = jnp.maximum(a2 + b2 - 2.0 * cross, 0.0)
     if snap:
         d2 = jnp.where(d2 < snap * snap * (a2 + b2), 0.0, d2)
     return jnp.sqrt(d2)
+
+
+def weighted_centroids(w: Array, x: Array) -> Array:
+    """Weighted centroids ``sum_s w[..., s] * x[..., s, :]`` of
+    histograms: w (..., s), x (..., s, m) -> (..., m), one contraction
+    at the operands' precision (``matmul_precision``)."""
+    return jnp.einsum("...s,...sm->...m", w, x,
+                      precision=matmul_precision(x.dtype))
 
 
 def l1_normalize(w: Array, axis: int = -1, eps: float = 1e-12) -> Array:

@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.geometry import pairwise_dist
-from repro.core.precision import pad_dist_for, resolve as resolve_precision
+from repro.core.precision import (matmul_precision, pad_dist_for,
+                                  resolve as resolve_precision)
 from repro.sharding import annotate
 
 Array = jax.Array
@@ -200,6 +201,17 @@ def streaming_smallest_k(D: Array, k: int, chunk: int = 512):
     return jax.lax.fori_loop(1, nchunks, body, (Z0, S0))
 
 
+def take_bins(q_w: Array, S: Array) -> Array:
+    """``q_w[S]`` along the last axis — each query's capacities at its
+    selected bins: q_w (..., h), S (..., v, k) -> (..., v, k). An exact
+    one-hot sum over the h bins (one nonzero per entry) instead of a
+    gather: the TPU compiler spends minutes on a gather of v*k indices
+    from a length-h row at 20News widths, and seconds on this."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (q_w.shape[-1],), 0)
+    hit = S[..., None] == col                            # (..., v, k, h)
+    return jnp.sum(jnp.where(hit, q_w[..., None, None, :], 0), axis=-1)
+
+
 def phase1(coords: Array, q_ids: Array, q_w: Array, k: int):
     """Phase 1: fused distance + row-top-k against the query.
 
@@ -211,8 +223,7 @@ def phase1(coords: Array, q_ids: Array, q_w: Array, k: int):
     D = pairwise_dist(coords, qc)                        # (v, h)
     D = jnp.where(q_w[None, :] > 0.0, D, pad_dist_for(D.dtype))
     Z, S = streaming_smallest_k(D, k)                    # (v, k)
-    W = q_w[S]
-    return Z, W
+    return Z, take_bins(q_w, S)
 
 
 #: Dedup the Phase-1 column stack only when it exceeds the vocabulary by
@@ -300,7 +311,7 @@ def phase1_batched(coords: Array, Q_ids: Array, Q_w: Array, k: int,
         jnp.moveaxis(Z, 1, 0).astype(policy.storage))    # (nq, v, k)
     Sq = jnp.moveaxis(S, 1, 0)
     W = annotate.emd_ladder(
-        jax.vmap(lambda w, s: w[s])(Q_w, Sq).astype(policy.storage))
+        take_bins(Q_w, Sq).astype(policy.storage))
     return Zq, W
 
 
@@ -369,17 +380,19 @@ def lc_act_scores(corpus: Corpus, q_ids: Array, q_w: Array, iters: int = 1,
         Z, S = kops.dist_topk(corpus.coords, corpus.coords[q_ids], k,
                               qmask=(q_w > 0.0), block_v=block_v,
                               block_h=block_h)
-        W = q_w[S]
+        W = take_bins(q_w, S)
     else:
         Z, W = phase1(corpus.coords, q_ids, q_w, k)
+    if iters >= 1 and use_kernels:
+        from repro.kernels import ops as kops
+        # rung-major gathers: (k, n, hmax) / (iters, n, hmax)
+        return kops.act_phase2(corpus.w, Z.T[:, corpus.ids],
+                               W.T[:iters][:, corpus.ids], block_n=block_n,
+                               block_h=block_h)
     Zg = Z[corpus.ids]                                   # (n, hmax, k)
     if iters == 0:
         return jnp.sum(corpus.w * Zg[..., 0], axis=-1)
     Wg = W[corpus.ids][..., :iters]                      # (n, hmax, iters)
-    if use_kernels:
-        from repro.kernels import ops as kops
-        return kops.act_phase2(corpus.w, Zg, Wg, block_n=block_n,
-                               block_h=block_h)
     return pour(corpus.w, Zg, Wg, iters)
 
 
@@ -419,7 +432,8 @@ def lc_rwmd_scores_rev(corpus: Corpus, q_ids: Array, q_w: Array,
         Dg = D[ids_blk]                                  # (b, hmax, h)
         Dg = jnp.where(valid_blk[..., None], Dg, big)
         cmin = jnp.min(Dg, axis=1)                       # (b, h)
-        return cmin @ q_w                                # (b,)
+        return jnp.matmul(cmin, q_w,
+                          precision=matmul_precision(cmin.dtype))  # (b,)
 
     n = corpus.n
     pad = (-n) % block
@@ -443,7 +457,7 @@ def lc_omr_scores(corpus: Corpus, q_ids: Array, q_w: Array, *,
         Z, S = kops.dist_topk(corpus.coords, corpus.coords[q_ids], 2,
                               qmask=(q_w > 0.0), block_v=block_v,
                               block_h=block_h)
-        W = q_w[S]
+        W = take_bins(q_w, S)
     else:
         Z, W = phase1(corpus.coords, q_ids, q_w, 2)
     Zg = Z[corpus.ids]                                   # (n, hmax, 2)
@@ -518,9 +532,12 @@ def _phase1_batched_dispatch(corpus: Corpus, Q_ids: Array, Q_w: Array,
                              block_h: int, mesh=None,
                              precision: str = "f32"):
     """Batched Phase 1 via the fused Pallas kernel or the jnp reference.
-    Returns query-major Z, W of shape (nq, v, k) on the handoff layout.
-    On a ``mesh`` whose axes divide (queries over DP, vocabulary over
-    "model") the kernel runs inside a ``shard_map`` partitioning shim.
+    Returns query-major Z (nq, v, k) and W on the handoff layout; the
+    kernel paths keep only the k-1 capacity columns the reductions read
+    (the last rung takes the Phase-3 dump), so no unread column crosses
+    the mesh.
+    On a ``mesh`` whose DP axes divide the queries the kernel runs inside
+    a ``shard_map`` partitioning shim (vocabulary over "model").
     ``precision`` threads the policy's compute dtype into the kernel's
     matmul operands and its storage dtype into the handoff ladders
     (``out_dtype`` — the kernel's Z block buffers shrink with it)."""
@@ -533,7 +550,7 @@ def _phase1_batched_dispatch(corpus: Corpus, Q_ids: Array, Q_w: Array,
             qcs = qcs.astype(policy.compute)
         if mesh is not None:
             from repro.kernels import partition
-            if partition.phase1_shardable(mesh, Q_ids.shape[0], corpus.v):
+            if partition.queries_shardable(mesh, Q_ids.shape[0]):
                 Z, W = partition.dist_topk_sharded(
                     mesh, coords, qcs, Q_w, k,
                     block_v=block_v, block_h=block_h,
@@ -543,7 +560,7 @@ def _phase1_batched_dispatch(corpus: Corpus, Q_ids: Array, Q_w: Array,
                                       qmask=(Q_w > 0.0), block_v=block_v,
                                       block_h=block_h,
                                       out_dtype=policy.storage)
-        W = jax.vmap(lambda w, s: w[s])(Q_w, S).astype(policy.storage)
+        W = take_bins(Q_w, S[..., :k - 1]).astype(policy.storage)
         return annotate.emd_ladder(Z), annotate.emd_ladder(W)
     return phase1_batched(corpus.coords, Q_ids, Q_w, k, precision=precision)
 
@@ -584,8 +601,9 @@ def pour_blocked(corpus: Corpus, Z: Array, W: Array, iters: int,
                     block_q=block_q, block_n=block_n, block_h=block_h)
 
         def blk_k(Zb, Wb):
-            Zg = Zb[:, corpus.ids]                       # (bq, n, hmax, k)
-            Wg = Wb[:, corpus.ids]                       # (bq, n, hmax, iters)
+            # rung-major gathers for the kernel: (bq, k, n, hmax)
+            Zg = jnp.swapaxes(Zb, 1, 2)[:, :, corpus.ids]
+            Wg = jnp.swapaxes(Wb, 1, 2)[:, :, corpus.ids]
             return kops.act_phase2_batched(x, Zg, Wg, block_n=block_n,
                                            block_h=block_h)
         return _map_query_blocks(blk_k, (Z, W), nq, block_q)
@@ -643,7 +661,8 @@ def rev_min_blocked(corpus: Corpus, Dq: Array, Q_w: Array, block: int,
             Dg = _accum(Db[:, ids_blk])                  # (bq, b, hmax, h)
             Dg = jnp.where(valid_blk[None, ..., None], Dg, big)
             cmin = jnp.min(Dg, axis=2)                   # (bq, b, h)
-            return jnp.einsum("qbh,qh->qb", cmin, Wb)
+            return jnp.einsum("qbh,qh->qb", cmin, Wb,
+                              precision=matmul_precision(cmin.dtype))
         out = jax.lax.map(rblock, (ids_b, valid_b))      # (nrb, bq, b)
         return jnp.moveaxis(out, 1, 0).reshape(Db.shape[0], -1)[:, :n]
     return _map_query_blocks(qblock, (Dq, Q_w), Dq.shape[0], block_q)
@@ -663,7 +682,8 @@ def rev_min_full(corpus: Corpus, Dq: Array, Q_w: Array,
         Dg = jnp.where(valid[None, ..., None],
                        _accum(Db[:, corpus.ids]), big)
         cmin = jnp.min(Dg, axis=2)                       # (bq, n, h)
-        return jnp.einsum("qnh,qh->qn", cmin, Wb)
+        return jnp.einsum("qnh,qh->qn", cmin, Wb,
+                          precision=matmul_precision(cmin.dtype))
     return _map_query_blocks(qblock, (Dq, Q_w), Dq.shape[0], block_q)
 
 

@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -76,6 +77,19 @@ def resolve(precision) -> PrecisionPolicy:
         return POLICIES[precision]
     raise ValueError(f"unknown precision policy {precision!r}; "
                      f"one of {sorted(POLICIES)}")
+
+
+def matmul_precision(dtype) -> jax.lax.Precision:
+    """MXU precision of a contraction over ``dtype`` operands — the one
+    place the pipeline decides it. Float32 operands take HIGHEST, the
+    float32 algorithm: XLA's DEFAULT on a TPU rounds them to a single
+    bfloat16 pass, which moves near-tied distances away from the float32
+    reference. Narrower operands take DEFAULT, their one native pass
+    (bf16 x bf16 products are exact in the float32 accumulator, and
+    Mosaic refuses a float32 contraction on bfloat16 operands)."""
+    if jnp.dtype(dtype).itemsize >= 4:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
 
 
 @functools.lru_cache(maxsize=None)

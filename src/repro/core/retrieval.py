@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import lc
+from repro.core.geometry import weighted_centroids
+from repro.core.precision import matmul_precision
 
 Array = jax.Array
 
@@ -329,7 +331,8 @@ def _bow_batch(corpus, q_ids, q_w, **_):
     qv = qv / jnp.maximum(jnp.linalg.norm(qv, axis=1, keepdims=True), 1e-12)
     wn = corpus.w / jnp.maximum(
         jnp.linalg.norm(corpus.w, axis=1, keepdims=True), 1e-12)
-    dots = jnp.einsum("us,qus->qu", wn, qv[:, corpus.ids])
+    dots = jnp.einsum("us,qus->qu", wn, qv[:, corpus.ids],
+                      precision=matmul_precision(wn.dtype))
     return 1.0 - dots
 
 
@@ -343,25 +346,48 @@ def _bow_cand(corpus, q_ids, q_w, cand, **_):
     wn = w_c / jnp.maximum(
         jnp.linalg.norm(w_c, axis=-1, keepdims=True), 1e-12)
     qg = lc.gather_per_query(qv, corpus.ids[cand])
-    return 1.0 - jnp.einsum("qbs,qbs->qb", wn, qg)
+    return 1.0 - jnp.einsum("qbs,qbs->qb", wn, qg,
+                            precision=matmul_precision(wn.dtype))
+
+
+#: Histogram slots per step of the centroid reduction: gathering every
+#: entry's embedding at once is an (n, hmax, m) tensor — 11 GB at
+#: 20News widths, more than a chip holds.
+CENTROID_SLOTS = 64
 
 
 def _corpus_centroids(corpus) -> Array:
-    """(n, m) weight-centroid of every corpus row."""
-    return jax.vmap(lambda i, w: w @ corpus.coords[i])(corpus.ids, corpus.w)
+    """(n, m) weight-centroid of every corpus row, accumulated over
+    blocks of ``CENTROID_SLOTS`` histogram slots (the row axis stays
+    whole, so a row-sharded corpus stays sharded)."""
+    n, hmax = corpus.ids.shape
+    steps = -(-hmax // CENTROID_SLOTS)
+    if steps == 1:
+        return weighted_centroids(corpus.w, corpus.coords[corpus.ids])
+    pad = ((0, 0), (0, steps * CENTROID_SLOTS - hmax))   # id 0, weight 0
+    ids, w = jnp.pad(corpus.ids, pad), jnp.pad(corpus.w, pad)
+
+    def step(j, acc):
+        i = jax.lax.dynamic_slice_in_dim(ids, j * CENTROID_SLOTS,
+                                         CENTROID_SLOTS, 1)
+        x = jax.lax.dynamic_slice_in_dim(w, j * CENTROID_SLOTS,
+                                         CENTROID_SLOTS, 1)
+        return acc + weighted_centroids(x, corpus.coords[i])
+    return jax.lax.fori_loop(0, steps, step,
+                             jnp.zeros((n, corpus.m), corpus.coords.dtype))
 
 
 @_register("wcd", paper_name="Word Centroid Distance baseline",
            symmetric=True)
 def _wcd(corpus, q_ids, q_w, **_):
     """Word Centroid Distance baseline (O(nm))."""
-    qc = q_w @ corpus.coords[q_ids]                       # (m,)
+    qc = weighted_centroids(q_w, corpus.coords[q_ids])    # (m,)
     return jnp.linalg.norm(_corpus_centroids(corpus) - qc[None, :], axis=1)
 
 
 @_register_batch("wcd")
 def _wcd_batch(corpus, q_ids, q_w, **_):
-    qc = jnp.einsum("qh,qhm->qm", q_w, corpus.coords[q_ids])
+    qc = weighted_centroids(q_w, corpus.coords[q_ids])
     cent = _corpus_centroids(corpus)
     return jnp.linalg.norm(cent[None, :] - qc[:, None], axis=-1)
 
@@ -370,9 +396,9 @@ def _wcd_batch(corpus, q_ids, q_w, **_):
 def _wcd_cand(corpus, q_ids, q_w, cand, **_):
     # Centroids only for the (nq, b) candidate rows — materializing all
     # n through the gather would waste O(n/b) of the work.
-    qc = jnp.einsum("qh,qhm->qm", q_w, corpus.coords[q_ids])
-    cent = jnp.einsum("qbh,qbhm->qbm", corpus.w[cand],
-                      corpus.coords[corpus.ids[cand]])
+    qc = weighted_centroids(q_w, corpus.coords[q_ids])
+    cent = weighted_centroids(corpus.w[cand],
+                              corpus.coords[corpus.ids[cand]])
     return jnp.linalg.norm(cent - qc[:, None, :], axis=-1)
 
 

@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the paper's compute hot spots.
 
 dist_topk   — fused pairwise-distance + row-top-k (LC-ACT Phase 1).
-act_phase2  — fused k-round constrained pour (LC-ACT Phases 2+3), on the
-              shared-x full-corpus grid or the per-query candidate grid.
+act_phase2  — fused k-round constrained pour (LC-ACT Phases 2+3) over
+              the full corpus.
 cand_pour   — fused per-query candidate gather + Phase-2/3 reduction for
               the cascade's compacted stages (pour / OMR / reverse-min /
               ICT modes; the (nq, b, hmax, k) gather never hits HBM).
@@ -12,8 +12,8 @@ interpret=True on CPU. ``ops`` holds the jitted padding wrappers; ``ref``
 holds the pure-jnp oracles.
 """
 from repro.kernels import ops, ref
-from repro.kernels.ops import (act_phase2, act_phase2_cand, cand_ict,
-                               cand_omr, cand_pour, cand_rev_min, dist_topk)
+from repro.kernels.ops import (act_phase2, cand_ict, cand_omr, cand_pour,
+                               cand_rev_min, dist_topk)
 
-__all__ = ["ops", "ref", "act_phase2", "act_phase2_cand", "cand_ict",
-           "cand_omr", "cand_pour", "cand_rev_min", "dist_topk"]
+__all__ = ["ops", "ref", "act_phase2", "cand_ict", "cand_omr", "cand_pour",
+           "cand_rev_min", "dist_topk"]

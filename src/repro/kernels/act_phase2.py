@@ -15,6 +15,12 @@ The grid carries a query-batch dimension as its outermost (parallel) axis:
 the residual-weight blocks of x are shared across queries while each query
 streams its own (cost, capacity) ladders, so a batch of queries pours in
 one kernel launch.
+
+The ladders are RUNG-MAJOR, (nq, k, n, hmax): rung l of a block is one
+(bn, bh) tile on the TPU's (sublane, lane) grid, so round l of the pour
+reads a whole tile. With the rung axis last, the k <= 16 rungs would sit
+on the 128-wide lane axis (8x-16x lane padding in VMEM) and every round
+would slice lanes.
 """
 from __future__ import annotations
 
@@ -24,38 +30,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import compiler_params
+
 
 def pour_entry_costs(x, zg, wg, iters: int):
-    """Per-entry poured cost of the k-round water-filling ladder — the
-    pour machinery shared by the shared-x batched kernel and the
-    candidate-grid (per-query x) extension below. x (bn, bh);
-    zg (bn, bh, iters+1); wg (bn, bh, iters) -> (bn, bh)."""
+    """Per-entry poured cost of the k-round water-filling ladder.
+    x (bn, bh); zg (iters+1, bn, bh); wg (iters, bn, bh) -> (bn, bh)."""
     acc = jnp.zeros_like(x)
     prefix = jnp.zeros_like(x)
     poured = jnp.zeros_like(x)
     for l in range(iters):
-        w_l = wg[..., l].astype(jnp.float32)                 # (bn, bh)
-        z_l = zg[..., l].astype(jnp.float32)
+        w_l = wg[l].astype(jnp.float32)                      # (bn, bh)
+        z_l = zg[l].astype(jnp.float32)
         r = jnp.clip(x - prefix, 0.0, w_l)
         acc = acc + r * z_l
         poured = poured + r
         prefix = prefix + w_l
     remainder = jnp.maximum(x - poured, 0.0)
-    return acc + remainder * zg[..., iters].astype(jnp.float32)
+    return acc + remainder * zg[iters].astype(jnp.float32)
 
 
 def _act_phase2_kernel(x_ref, zg_ref, wg_ref, t_ref, *, iters: int):
     """Grid = (nq, n_blocks, h_blocks); the query batch is the outermost
     (parallel) axis and h blocks accumulate into t. The x block is shared
-    across queries (2-D block) on the full-corpus grid, or per-query
-    (3-D block, leading 1) on the candidate grid — each query of a
-    cascade scores its OWN (b, hmax) surviving sub-corpus."""
+    across queries."""
     j = pl.program_id(2)
 
-    x = x_ref[...]
-    if x.ndim == 3:                                          # candidate grid
-        x = x[0]
-    x = x.astype(jnp.float32)                                # (bn, bh)
+    x = x_ref[...].astype(jnp.float32)                       # (bn, bh)
     acc = pour_entry_costs(x, zg_ref[0], wg_ref[0], iters)
     partial = jnp.sum(acc, axis=1, keepdims=True)[None]      # (1, bn, 1)
 
@@ -78,14 +79,14 @@ def act_phase2_pallas(x: jax.Array, zg: jax.Array, wg: jax.Array, *,
     Args:
       x:  (n, hmax) residual database weights, shared by all queries
           (padding slots are 0).
-      zg: (nq, n, hmax, iters+1) per-query ascending transport-cost ladder.
-      wg: (nq, n, hmax, iters) per-query capacity ladder (query weights).
+      zg: (nq, iters+1, n, hmax) per-query ascending transport-cost ladder.
+      wg: (nq, iters, n, hmax) per-query capacity ladder (query weights).
     Returns t: (nq, n, 1) transport-cost lower bounds.
     Caller guarantees n % block_n == 0 and hmax % block_h == 0 (see ops.py).
     """
     n, hmax = x.shape
-    nq, iters = wg.shape[0], wg.shape[-1]
-    assert zg.shape == (nq, n, hmax, iters + 1), (zg.shape, x.shape, iters)
+    nq, iters = wg.shape[0], wg.shape[1]
+    assert zg.shape == (nq, iters + 1, n, hmax), (zg.shape, x.shape, iters)
     assert n % block_n == 0 and hmax % block_h == 0, (n, hmax, block_n, block_h)
     grid = (nq, n // block_n, hmax // block_h)
     kernel = functools.partial(_act_phase2_kernel, iters=iters)
@@ -94,57 +95,14 @@ def act_phase2_pallas(x: jax.Array, zg: jax.Array, wg: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, block_h), lambda q, i, j: (i, j)),
-            pl.BlockSpec((1, block_n, block_h, iters + 1),
-                         lambda q, i, j: (q, i, j, 0)),
-            pl.BlockSpec((1, block_n, block_h, iters),
-                         lambda q, i, j: (q, i, j, 0)),
+            pl.BlockSpec((1, iters + 1, block_n, block_h),
+                         lambda q, i, j: (q, 0, i, j)),
+            pl.BlockSpec((1, iters, block_n, block_h),
+                         lambda q, i, j: (q, 0, i, j)),
         ],
         out_specs=pl.BlockSpec((1, block_n, 1), lambda q, i, j: (q, i, 0)),
         out_shape=jax.ShapeDtypeStruct((nq, n, 1), jnp.float32),
+        compiler_params=compiler_params("parallel", "parallel",
+                                        "arbitrary"),
         interpret=interpret,
     )(x, zg, wg)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_n", "block_h", "interpret"))
-def act_phase2_cand_pallas(xg: jax.Array, zg: jax.Array, wg: jax.Array, *,
-                           block_n: int = 256, block_h: int = 256,
-                           interpret: bool = False) -> jax.Array:
-    """Candidate-grid extension of :func:`act_phase2_pallas`: the database
-    axis is each query's OWN candidate block, so the residual weights are
-    per-query too (a cascade's stage-s+1 sub-corpus differs per query).
-
-    Args:
-      xg: (nq, b, hmax) per-query candidate residual weights.
-      zg: (nq, b, hmax, iters+1) / wg: (nq, b, hmax, iters) pre-gathered
-          per-candidate ladders.
-    Returns t: (nq, b, 1) transport-cost lower bounds.
-
-    This is the unfused half of the candidate pour — callers that already
-    hold gathered ladders (or back-ends without the in-kernel one-hot
-    gather of ``cand_pour``) tile the same pour over (query, candidate)
-    blocks. The fused ``cand_pour`` kernel subsumes gather + pour in one
-    launch and is what the ``lc`` candidate engines route to.
-    Caller guarantees b % block_n == 0 and hmax % block_h == 0 (ops.py).
-    """
-    nq, b, hmax = xg.shape
-    iters = wg.shape[-1]
-    assert zg.shape == (nq, b, hmax, iters + 1), (zg.shape, xg.shape)
-    assert b % block_n == 0 and hmax % block_h == 0, (b, hmax, block_n,
-                                                      block_h)
-    grid = (nq, b // block_n, hmax // block_h)
-    kernel = functools.partial(_act_phase2_kernel, iters=iters)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_n, block_h), lambda q, i, j: (q, i, j)),
-            pl.BlockSpec((1, block_n, block_h, iters + 1),
-                         lambda q, i, j: (q, i, j, 0)),
-            pl.BlockSpec((1, block_n, block_h, iters),
-                         lambda q, i, j: (q, i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n, 1), lambda q, i, j: (q, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nq, b, 1), jnp.float32),
-        interpret=interpret,
-    )(xg, zg, wg)

@@ -1,14 +1,16 @@
 """VMEM-driven tile autotuner for the fused Pallas kernels.
 
 Tile sizes (``block_n`` / ``block_v`` / ``block_h``) decide both whether
-a launch FITS (the 16 MiB double-buffered VMEM budget) and how fast it
+a launch FITS and is LEGAL (the scoped-VMEM budget and Mosaic's
+(8, 128)-or-full block rule, both in ``analysis/vmem``) and how fast it
 runs (arithmetic intensity vs pipeline depth). Rather than hand-tuning,
 this module closes the loop over the two artifacts PR 6 made static:
 
 * candidate enumeration — :func:`admissible_configs` sweeps tile
   assignments and keeps only those ``analysis/vmem.check_launch`` admits
   (same clamp/pad arithmetic as the wrappers, evaluated without
-  tracing), so no timed config can OOM a core;
+  tracing), so no timed config can OOM a core or be refused by the
+  chip's compiler;
 * timing — :func:`tune` runs a paired-interleaved tournament
   (``benchmarks.common.paired``, the benches' own harness: interleaving
   cancels drift between the incumbent and the challenger) and caches the
@@ -39,13 +41,12 @@ from repro.kernels import ops
 FAMILY_KNOBS: dict[str, tuple[tuple[str, str], ...]] = {
     "dist_topk": (("block_v", "v"), ("block_h", "h")),
     "act_phase2": (("block_n", "n"), ("block_h", "h")),
-    "act_phase2_cand": (("block_n", "n"), ("block_h", "h")),
     "cand_pour": (("block_n", "b"), ("block_v", "v")),
     "cand_dist": (("block_n", "b"), ("block_v", "v")),
 }
 
-#: Tile candidates per knob. Sub-8 sizes are real choices: ``cand_dist``
-#: at paper scale (h = 500) only fits with block_n = 2.
+#: Tile candidates per knob. Sub-8 sizes are legal only where they cover
+#: a whole (small) dim; ``check_launch`` drops them everywhere else.
 CANDIDATE_BLOCKS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
 
 
@@ -211,13 +212,13 @@ def _runner(family: str, dims: dict):
             return fn
         return make_run
 
-    if family in ("act_phase2", "act_phase2_cand"):
+    if family == "act_phase2":
         x = jnp.asarray(rng.uniform(size=(d["n"], d["h"])), jnp.float32)
         k = d["iters"] + 1
         zg = jnp.asarray(np.sort(rng.uniform(
-            size=(d["nq"], d["n"], d["h"], k)), -1), jnp.float32)
+            size=(d["nq"], k, d["n"], d["h"])), 1), jnp.float32)
         wg = jnp.asarray(rng.uniform(
-            size=(d["nq"], d["n"], d["h"], d["iters"])), jnp.float32)
+            size=(d["nq"], d["iters"], d["n"], d["h"])), jnp.float32)
 
         def make_run(cfg):
             return jax.jit(lambda: kops.act_phase2_batched(x, zg, wg, **cfg))

@@ -4,37 +4,48 @@ The cascade's candidate engines (``core/lc`` ``*_cand_blocked``) score each
 query against its own (b,) surviving rows: the reference path gathers the
 per-entry cost/capacity ladders with XLA (``Z[ids[cand]]`` — the
 (nq, b, hmax, k) tensor lands in HBM) and then reduces. These kernels do
-BOTH in one launch on a query-batch x candidate-block grid: each (q, i)
-cell holds its query's full Phase-1 table in VMEM, gathers its candidate
-block's per-entry ladder rows in-kernel, and reduces to the (1, bb) scores
-— the (nq, b, hmax, k) gather tensor never materializes.
+BOTH in one launch on a (query, candidate block, vocabulary slab) grid:
+each step gathers its candidate block's entries from one ``block_v`` slab
+of the query's table into a VMEM accumulator, and the last slab reduces
+the completed per-row ladders to the (bb, 1) scores — the
+(nq, b, hmax, k) gather tensor never materializes.
 
-The in-kernel gather is a one-hot matmul streamed over vocabulary chunks:
-for a chunk of ``block_v`` table rows, the (bb*hmax, block_v) one-hot of
-the candidate entry ids against the chunk's row range is contracted with
-the chunk on the MXU — the TPU idiom for an arbitrary-index gather (Mosaic
-has no general dynamic-gather op). Every entry id hits exactly one chunk,
-so accumulation across chunks adds exact zeros and the gathered ladder is
-BITWISE the XLA gather's result.
+Layout. Every per-query table is ROW-MAJOR over its ladder rungs:
+(rows, v), one row per rung (or per query bin), the vocabulary on the
+128-wide lane axis. One candidate row's ids (1, hp) broadcast over a
+slab's sublanes give the (block_v, hp) one-hot, and the MXU contraction
+``table_slab (rows, block_v) @ onehot (block_v, hp)`` lands the row's
+gathered ladder as (rows, hp): rung l is sublane row l, the slots run
+along lanes, so the pour is plain row arithmetic and the per-row score a
+lane reduction. hp is hmax padded to a lane multiple; pad slots carry id
+0 and weight 0 and contribute exactly 0.
 
-The reductions reuse the reference engines' own formulas (``lc.pour``,
-``lc.ict_pour``, the Algorithm-1/masked-min expressions) on identically
-shaped tiles. The conformance contract (``tests/test_cand_kernels.py``):
-the gather is bitwise-exact, scores match the reference candidate engines
-to within a few ulps, and admissible cascades keep their exact-top-l
-guarantee under the kernel path. The residual ulps are not the kernels':
-XLA re-fuses the REFERENCE path's reduction per surrounding program
-(FMA contraction), so even two pure-jnp programs of the same formula can
-disagree by an ulp on CPU — the kernel body, compiled as an isolated
-computation inside the grid loop, is the more stable of the two.
+Exactness. The one-hot runs on the MXU in bfloat16 (0/1 are exact). A
+float32 table rides as three bfloat16 parts that sum back to it exactly
+(:func:`split_bf16`), each contracted against the same one-hot
+with f32 accumulation: every gathered value is 1.0 * part + exact zeros,
+so the gather is BITWISE the XLA gather's result, compiled or
+interpreted, at 3 MXU passes where a float32 contraction would take 6.
+
+The reductions use the reference engines' formulas (``lc.pour``,
+``lc.ict_pour``, the Algorithm-1 and masked-min expressions). What needs
+a sort or a cumulative sum — ICT's cost order, both pours' exclusive
+capacity prefix — depends only on the vocabulary row, so the wrappers
+(``kernels/ops``) compute it once per query over the (v, ...) table with
+the reference's own ``jnp`` ops and gather it like any other rung. The
+conformance contract (``tests/test_cand_kernels.py``): the gather is
+bitwise-exact and scores match the reference candidate engines to within
+a few ulps (the remaining ulps are summation order).
 
 Covers every candidate reduction in the registry:
   mode "pour"    — LC-ACT Phase 2/3 (iters >= 1) and the LC-RWMD
-                   masked-min dump (iters == 0), via ``lc.pour``.
-  mode "omr"     — LC-OMR Algorithm-1 top-2 reduction.
-  mode "rev_min" — reverse-RWMD masked (min,+) over the distance handoff.
-  mode "ict"     — LC-ICT full-ladder pour (``lc.ict_pour``; the
-                   remainder dump stays max-FINITE — see that docstring).
+                   masked-min dump (iters == 0); rows Z | W | prefix(W).
+  mode "omr"     — LC-OMR Algorithm-1 top-2 reduction; rows Z0, Z1, W0.
+  mode "rev_min" — reverse-RWMD masked (min,+) over the distance handoff;
+                   rows = query bins.
+  mode "ict"     — LC-ICT full-ladder pour; rows = sorted costs | sorted
+                   capacities | their exclusive prefix | the max-FINITE
+                   dump cost (never ``lc.PAD_DIST`` — see ``ict_pour``).
 """
 from __future__ import annotations
 
@@ -45,212 +56,225 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.lc import ict_pour, pour
-from repro.core.precision import pad_dist_for
+from repro.core.precision import matmul_precision, pad_dist_for
+from repro.kernels.tiling import compiler_params, round_up
 
-#: Modes whose ladder table stacks Z|W columns (Phase-1 ranked handoff).
+#: Modes whose table is built from the Phase-1 ranked (Z, W) handoff.
 POUR_MODES = ("pour", "omr")
-#: Modes that consume the (v, h) distance handoff plus the query weights.
+#: Modes whose table is built from the (v, h) distance handoff.
 DIST_MODES = ("rev_min", "ict")
+MODES = POUR_MODES + DIST_MODES
+
+#: Table rows of one segment are padded to the bf16 sublane tile, so
+#: every segment and bf16 part starts on a tile boundary. Slots (hmax)
+#: are padded to ``tiling.LANE``.
+ROW_TILE = 16
 
 
-def _gather_rows(flat_ids, table, block_v: int):
-    """In-kernel gather ``table[flat_ids]`` via chunked one-hot matmuls.
+def split_bf16(table: jax.Array) -> jax.Array:
+    """Exact bfloat16 decomposition of a float table: (..., w, v) ->
+    (..., P, w, v) with the P parts summing back to ``table`` bit-for-bit
+    in float32 (P = 1 for a bfloat16 table, 3 for float32: the top 8
+    significand bits, the next 8, and the last 8).
 
-    flat_ids: (r,) int32 row ids into ``table`` (vp, width); vp is a
-    ``block_v`` multiple (ops pads). Returns (r, width) float32, bitwise
-    equal to an XLA gather: each id matches exactly one chunk, the one-hot
-    contraction is 1.0 * row + exact zeros (table values are finite —
-    padding costs are the finite ``lc.PAD_DIST``, never inf, so the
-    0 * value products cannot produce NaN).
-    """
-    vp, width = table.shape
-    r = flat_ids.shape[0]
+    Each part is cut by clearing the low 16 bits of a float32 word, so it
+    is a bfloat16 value exactly and every conversion below is exact. The
+    cut is integer arithmetic on purpose: written as ``t - bf16(t)``, a
+    compiler that may keep excess precision (XLA on TPU does) folds the
+    round trip to ``t`` and the residue parts to zero."""
+    if table.dtype == jnp.bfloat16:
+        return table[..., None, :, :]
 
-    def chunk(u, acc):
-        blk = jax.lax.dynamic_slice_in_dim(table, u * block_v, block_v, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (r, block_v), 1)
-        # One-hot in the TABLE's dtype (0/1 are exact in any float dtype)
-        # so a bf16 storage table contracts without an f32 upcast copy;
-        # the MXU still accumulates into float32.
-        onehot = (flat_ids[:, None] - u * block_v == col).astype(blk.dtype)
-        return acc + jax.lax.dot_general(
-            onehot, blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def top(x):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
 
-    return jax.lax.fori_loop(0, vp // block_v, chunk,
-                             jnp.zeros((r, width), jnp.float32))
+    t = table.astype(jnp.float32)
+    hi = top(t)
+    r = t - hi                         # exact: the low 16 significand bits
+    mid = top(r)
+    return jnp.stack([hi, mid, r - mid], axis=-3).astype(jnp.bfloat16)
 
 
-def _cand_pour_kernel(idsg_ref, xg_ref, table_ref, t_ref, *, k: int,
-                      iters: int, mode: str, block_v: int):
-    """Grid = (nq, cand_blocks). One cell: gather this candidate block's
-    (bb, hmax, k [+iters]) ladder rows from the query's VMEM-resident
-    table, then run the reference reduction."""
-    ids = idsg_ref[0]                                    # (bb, hmax) int32
-    bb, hmax = ids.shape
-    g = _gather_rows(ids.reshape(-1), table_ref[0], block_v)
-    zg = g[:, :k].reshape(bb, hmax, k)
-    x = xg_ref[0].astype(jnp.float32)
+def segment_rows(w: int) -> int:
+    """Padded row count of a ``w``-row table segment."""
+    return round_up(w, ROW_TILE)
+
+
+def table_rows(mode: str, *, k: int = 1, iters: int = 0, qh: int = 1) -> int:
+    """Rows of one (unsplit) table of ``mode`` — the gathered ladder
+    height per candidate entry (see the module docstring)."""
     if mode == "pour":
-        wg = (g[:, k:].reshape(bb, hmax, iters) if iters
-              else zg[..., :0])                          # unused at iters=0
-        t = pour(x, zg, wg, iters)
-    else:                                                # "omr": k == 2
-        w0 = g[:, k].reshape(bb, hmax)
-        overlap = zg[..., 0] == 0.0
-        rest = x - jnp.minimum(x, w0)
-        per_entry = jnp.where(overlap, rest * zg[..., 1], x * zg[..., 0])
-        t = jnp.sum(per_entry, axis=-1)
-    t_ref[...] = t[None]
+        return segment_rows(k + 2 * iters)
+    if mode == "omr":
+        return segment_rows(3)
+    if mode == "rev_min":
+        return segment_rows(qh)
+    return 3 * segment_rows(qh) + ROW_TILE                 # "ict"
 
 
-def _cand_dist_kernel(idsg_ref, xg_ref, dq_ref, qw_ref, t_ref, acc_ref, *,
-                      mode: str):
-    """Grid = (nq, cand_blocks, vocab_blocks). The vocabulary axis is the
-    INNERMOST (fastest) grid dimension: each step sees one (block_v, h)
-    slab of the query's distance handoff, accumulates its one-hot-matmul
-    gather contribution into the persistent VMEM scratch ``acc_ref``, and
-    on the last slab reduces the completed (bb, hmax, h) cost tensor:
-    masked (min,+) . q_w ("rev_min") or the full sorted ladder ("ict").
+def gather_slab(tab_ref, ids, parts: int, rows: int, acc):
+    """Add one slab's contribution to one candidate row's gathered ladder.
 
-    Streaming keeps the per-launch dq residency at one ``block_v`` slab
-    instead of the full (vp, h) table, so paper-scale handoffs (20News:
-    vp ~ 70k, h = 500) fit the 16 MiB double-buffered VMEM budget. Each
-    entry id matches exactly one slab, so the running sum adds exact
-    zeros elsewhere and the gathered ladder stays BITWISE the XLA
-    gather's result (values are non-negative; +0 init is exact)."""
-    ids = idsg_ref[0]                                    # (bb, hmax)
-    bb, hmax = ids.shape
+    ``tab_ref``: (1, parts * rows, block_v) bf16 slab (part-major);
+    ``ids``: (1, hp) entry ids relative to the slab start; ``acc``:
+    (rows, hp) float32 running gather. Entries whose id falls in the slab
+    gain their table rows part by part, all others exact zeros; an entry
+    hits exactly one slab, so it accumulates 0 + its parts = its value."""
+    block_v = tab_ref.shape[-1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_v, ids.shape[-1]), 0)
+    onehot = (col == ids).astype(jnp.float32).astype(jnp.bfloat16)
+    for p in range(parts):
+        acc = acc + jax.lax.dot_general(
+            tab_ref[0, p * rows:(p + 1) * rows, :], onehot,
+            (((1,), (0,)), ((), ())),
+            precision=matmul_precision(jnp.bfloat16),
+            preferred_element_type=jnp.float32)
+    return acc
+
+
+def _sum_lanes(e):
+    return jnp.sum(e, axis=1, keepdims=True)                  # (1, 1)
+
+
+def _reduce_pour(g, x, *, k: int, iters: int):
+    """``lc.pour`` on one row's gathered ladder: rows Z[0:k],
+    W[k:k+iters], exclusive prefix of W[k+iters:k+2*iters]."""
+    if iters == 0:
+        return _sum_lanes(x * g[0:1])
+    poured = None
+    taken = None
+    for l in range(iters):
+        w = g[k + l:k + l + 1]
+        pre = g[k + iters + l:k + iters + l + 1]
+        r = jnp.clip(x - pre, 0.0, w)
+        rz = r * g[l:l + 1]
+        poured = rz if poured is None else poured + rz
+        taken = r if taken is None else taken + r
+    remainder = jnp.maximum(x - taken, 0.0)
+    return _sum_lanes(poured) + _sum_lanes(remainder * g[iters:iters + 1])
+
+
+def _reduce_omr(g, x):
+    """Algorithm 1 on rows Z0, Z1, W0."""
+    z0, z1, w0 = g[0:1], g[1:2], g[2:3]
+    rest = x - jnp.minimum(x, w0)
+    return _sum_lanes(jnp.where(z0 == 0.0, rest * z1, x * z0))
+
+
+def _reduce_rev_min(g, x, qw):
+    """Masked (min,+) . q_w on rows = query bins; ``qw`` (rows, 1).
+    Invalid slots mask to the f32 pad sentinel (reduced-precision
+    sentinels upcast to >= it, so every comparison stays strict)."""
+    big = pad_dist_for(jnp.float32)
+    cmin = jnp.min(jnp.where(x > 0.0, g, big), axis=1, keepdims=True)
+    return jnp.sum(cmin * qw, axis=0, keepdims=True)
+
+
+def _reduce_ict(g, x, *, hs: int):
+    """``lc.ict_pour`` on rows sorted costs | sorted capacities |
+    exclusive capacity prefix (``hs`` rows each) | dump cost."""
+    cost, cap, pre = g[0:hs], g[hs:2 * hs], g[2 * hs:3 * hs]
+    dump = g[3 * hs:3 * hs + 1]
+    r = jnp.clip(x - pre, 0.0, cap)                           # (hs, hp)
+    poured = jnp.sum(r * cost, axis=0, keepdims=True)         # (1, hp)
+    remainder = jnp.maximum(x - jnp.sum(r, axis=0, keepdims=True), 0.0)
+    return _sum_lanes(poured + remainder * dump)
+
+
+def _cand_kernel(*refs, mode: str, parts: int, rows: int, k: int,
+                 iters: int, hs: int):
+    """Grid = (nq, cand_blocks, vocab_slabs); the slab axis is innermost.
+    Each step adds its slab's one-hot gather into the persistent
+    (bb, rows, hp) accumulator; the last slab reduces row by row."""
+    ids_ref, x_ref, tab_ref = refs[:3]
+    qw_ref = refs[3] if mode == "rev_min" else None
+    t_ref, acc_ref = refs[-2:]
     u = pl.program_id(2)
-    blk = dq_ref[0]                                      # (block_v, h)
-    block_v = blk.shape[0]
-    r = bb * hmax
-    col = jax.lax.broadcasted_iota(jnp.int32, (r, block_v), 1)
-    # One-hot in the slab's dtype (see _gather_rows); f32 accumulation.
-    onehot = (ids.reshape(-1)[:, None] - u * block_v == col
-              ).astype(blk.dtype)
-    contrib = jax.lax.dot_general(onehot, blk, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+    bb = ids_ref.shape[1]
+    block_v = tab_ref.shape[-1]
 
     @pl.when(u == 0)
     def _init():
-        acc_ref[...] = contrib
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(u > 0)
-    def _accumulate():
-        acc_ref[...] = acc_ref[...] + contrib
+    def gather(i, carry):
+        ids = ids_ref[0, pl.ds(i, 1), :] - u * block_v           # (1, hp)
+        acc_ref[i] = gather_slab(tab_ref, ids, parts, rows, acc_ref[i])
+        return carry
+
+    jax.lax.fori_loop(0, bb, gather, 0)
 
     @pl.when(u == pl.num_programs(2) - 1)
     def _reduce():
-        qw = qw_ref[0].astype(jnp.float32)               # (h,)
-        C = acc_ref[...].reshape(bb, hmax, qw.shape[0])
-        x = xg_ref[0].astype(jnp.float32)
-        if mode == "rev_min":
-            # C is the f32 gather accumulator; reduced-precision dq
-            # sentinels upcast to >= the f32 pad, so masking here in the
-            # accumulator dtype keeps every sentinel comparison strict.
-            big = jnp.asarray(pad_dist_for(C.dtype), C.dtype)
-            Dg = jnp.where((x > 0.0)[..., None], C, big)
-            cmin = jnp.min(Dg, axis=1)                   # (bb, h)
-            # multiply + reduce, matching lc.rev_min_cand_blocked
-            # bit-for-bit (a dot op's accumulation varies with the
-            # tile's row count)
-            t = jnp.sum(cmin * qw[None, :], axis=-1)
-        else:                                            # "ict"
-            cap = jnp.broadcast_to(qw[None, None, :], C.shape)
-            t = ict_pour(x, cap, C)
-        t_ref[...] = t[None]
+        def reduce(i, carry):
+            g = acc_ref[i]                                       # (rows, hp)
+            x = x_ref[0, pl.ds(i, 1), :].astype(jnp.float32)     # (1, hp)
+            if mode == "pour":
+                t = _reduce_pour(g, x, k=k, iters=iters)
+            elif mode == "omr":
+                t = _reduce_omr(g, x)
+            elif mode == "rev_min":
+                t = _reduce_rev_min(g, x, qw_ref[0].astype(jnp.float32))
+            else:
+                t = _reduce_ict(g, x, hs=hs)
+            t_ref[0, pl.ds(i, 1), :] = t
+            return carry
+
+        jax.lax.fori_loop(0, bb, reduce, 0)
 
 
-def _check_cand(idsg, xg, block_n: int):
-    nq, b, hmax = idsg.shape
-    assert xg.shape == (nq, b, hmax), (xg.shape, idsg.shape)
-    assert b % block_n == 0, (b, block_n)
-    return nq, b, hmax
-
-
-@functools.partial(jax.jit, static_argnames=("k", "iters", "mode",
+@functools.partial(jax.jit, static_argnames=("mode", "k", "iters", "qh",
                                              "block_n", "block_v",
                                              "interpret"))
-def cand_pour_pallas(idsg: jax.Array, xg: jax.Array, table: jax.Array, *,
-                     k: int, iters: int, mode: str = "pour",
-                     block_n: int = 128, block_v: int = 256,
-                     interpret: bool = False) -> jax.Array:
-    """Fused candidate gather + pour/OMR reduction over a query batch.
+def cand_pallas(idsg: jax.Array, xg: jax.Array, table: jax.Array,
+                qw: jax.Array | None = None, *, mode: str, k: int = 1,
+                iters: int = 0, qh: int = 1, block_n: int = 128,
+                block_v: int = 256, interpret: bool = False) -> jax.Array:
+    """Fused candidate gather + reduction over a query batch.
 
     Args:
-      idsg:  (nq, b, hmax) int32 vocabulary ids of each query's candidate
-             rows (``corpus.ids[cand]``; padding slots/rows carry id 0
-             and weight 0, contributing exactly 0 cost).
-      xg:    (nq, b, hmax) residual weights (``corpus.w[cand]``).
-      table: (nq, vp, k [+ iters]) per-query Phase-1 ladder, Z columns
-             first then W ("pour" with iters >= 1) or W0 ("omr").
-    Returns t: (nq, b) scores at the candidate rows.
-    Caller guarantees b % block_n == 0 and vp % block_v == 0 (see ops.py).
+      idsg:  (nq, b, hp) int32 vocabulary ids of each query's candidate
+             rows (``corpus.ids[cand]``, slots padded to a lane multiple;
+             padding slots/rows carry id 0 and weight 0).
+      xg:    (nq, b, hp) residual weights (``corpus.w[cand]``).
+      table: (nq, P * rows, vp) bfloat16 parts of the per-query table
+             (:func:`split_bf16`, part-major; ``rows`` =
+             :func:`table_rows` of the mode).
+      qw:    (nq, rows, 1) query weights per bin ("rev_min" only; 0 at
+             padded bins).
+    Returns t: (nq, b, 1) scores at the candidate rows.
+    Caller guarantees b % block_n == 0 and vp % block_v == 0 (ops.py).
     """
-    assert mode in POUR_MODES, mode
-    nq, b, hmax = _check_cand(idsg, xg, block_n)
-    vp, width = table.shape[1], table.shape[2]
-    assert vp % block_v == 0 and width == k + (1 if mode == "omr" else iters)
-    kernel = functools.partial(_cand_pour_kernel, k=k, iters=iters,
-                               mode=mode, block_v=block_v)
-    return pl.pallas_call(
-        kernel,
-        grid=(nq, b // block_n),
-        in_specs=[
-            pl.BlockSpec((1, block_n, hmax), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, block_n, hmax), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, vp, width), lambda q, i: (q, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n), lambda q, i: (q, i)),
-        out_shape=jax.ShapeDtypeStruct((nq, b), jnp.float32),
-        interpret=interpret,
-    )(idsg, xg, table)
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "block_n", "block_v",
-                                             "interpret"))
-def cand_dist_pallas(idsg: jax.Array, xg: jax.Array, dq: jax.Array,
-                     qw: jax.Array, *, mode: str = "rev_min",
-                     block_n: int = 128, block_v: int = 256,
-                     interpret: bool = False) -> jax.Array:
-    """Fused candidate gather + distance-handoff reduction (rev_min/ict).
-
-    Args:
-      idsg: (nq, b, hmax) int32 candidate-row vocabulary ids.
-      xg:   (nq, b, hmax) residual weights (0 marks padding slots, which
-            "rev_min" masks to the finite ``lc.PAD_DIST``).
-      dq:   (nq, vp, h) query-major Phase-1 distance handoff (padded query
-            bins already carry ``lc.PAD_DIST``).
-      qw:   (nq, h) query weights (0 at padded bins).
-    Returns t: (nq, b) scores at the candidate rows.
-    Caller guarantees b % block_n == 0 and vp % block_v == 0 (see ops.py).
-
-    Unlike ``cand_pour_pallas`` (whose narrow Z|W table fits VMEM whole),
-    the (vp, h) distance handoff is streamed: the grid carries a third,
-    innermost vocabulary axis delivering one (block_v, h) slab per step,
-    with the gather accumulated in a VMEM scratch and the reduction run
-    once on the final slab. The output block's index map ignores the
-    vocab axis, so the (1, block_n) tile is written exactly once — on the
-    last slab, just before the candidate index advances.
-    """
-    assert mode in DIST_MODES, mode
-    nq, b, hmax = _check_cand(idsg, xg, block_n)
-    vp, h = dq.shape[1], dq.shape[2]
-    assert vp % block_v == 0 and qw.shape == (nq, h), (dq.shape, qw.shape)
-    kernel = functools.partial(_cand_dist_kernel, mode=mode)
+    assert mode in MODES, mode
+    nq, b, hp = idsg.shape
+    assert xg.shape == idsg.shape and b % block_n == 0, (xg.shape, block_n)
+    rows = table_rows(mode, k=k, iters=iters, qh=qh)
+    vp = table.shape[-1]
+    parts = table.shape[1] // rows
+    assert table.shape[1] == parts * rows and vp % block_v == 0, table.shape
+    kernel = functools.partial(_cand_kernel, mode=mode, parts=parts,
+                               rows=rows, k=k, iters=iters,
+                               hs=segment_rows(qh))
+    in_specs = [
+        pl.BlockSpec((1, block_n, hp), lambda q, i, u: (q, i, 0)),
+        pl.BlockSpec((1, block_n, hp), lambda q, i, u: (q, i, 0)),
+        pl.BlockSpec((1, parts * rows, block_v), lambda q, i, u: (q, 0, u)),
+    ]
+    args = [idsg, xg, table]
+    if mode == "rev_min":
+        assert qw.shape == (nq, rows, 1), (qw.shape, rows)
+        in_specs.append(pl.BlockSpec((1, rows, 1), lambda q, i, u: (q, 0, 0)))
+        args.append(qw)
     return pl.pallas_call(
         kernel,
         grid=(nq, b // block_n, vp // block_v),
-        in_specs=[
-            pl.BlockSpec((1, block_n, hmax), lambda q, i, u: (q, i, 0)),
-            pl.BlockSpec((1, block_n, hmax), lambda q, i, u: (q, i, 0)),
-            pl.BlockSpec((1, block_v, h), lambda q, i, u: (q, u, 0)),
-            pl.BlockSpec((1, h), lambda q, i, u: (q, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n), lambda q, i, u: (q, i)),
-        out_shape=jax.ShapeDtypeStruct((nq, b), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_n * hmax, h), jnp.float32)],
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_n, 1), lambda q, i, u: (q, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nq, b, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_n, rows, hp), jnp.float32)],
+        compiler_params=compiler_params("parallel", "parallel",
+                                        "arbitrary"),
         interpret=interpret,
-    )(idsg, xg, dq, qw)
+    )(*args)

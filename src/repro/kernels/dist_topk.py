@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.precision import pad_dist_for
+from repro.core.precision import matmul_precision, pad_dist_for
+from repro.kernels.tiling import compiler_params
 
 BIG = 1e30  # plain float: jnp scalars would be captured consts in the kernel
 
@@ -56,12 +57,15 @@ def _dist_topk_kernel(v_ref, q_ref, qmask_ref, z_ref, s_ref, *, k: int,
     # bitwise the historical BIG). All selection work stays float32.
     big = pad_dist_for(out_dtype)
 
-    vt = v_ref[...].astype(jnp.float32)                           # (bv, m)
-    qt = q_ref[0].astype(jnp.float32)                             # (bh, m)
+    vc, qc = v_ref[...], q_ref[0]          # compute dtype: (bv, m), (bh, m)
+    vt, qt = vc.astype(jnp.float32), qc.astype(jnp.float32)
     v2 = jnp.sum(vt * vt, axis=1, keepdims=True)                  # (bv, 1)
     q2 = jnp.sum(qt * qt, axis=1, keepdims=True).T                # (1, bh)
+    # the cross term contracts the compute-dtype operands at their
+    # precision (float32: HIGHEST, as the jnp engines) into float32
     d = v2 + q2 - 2.0 * jax.lax.dot_general(
-        vt, qt, (((1,), (1,)), ((), ())),
+        vc, qc, (((1,), (1,)), ((), ())),
+        precision=matmul_precision(vc.dtype),
         preferred_element_type=jnp.float32)                       # (bv, bh)
     d = jnp.maximum(d, 0.0)
     # relative ZERO_SNAP (see core/geometry.py): exact zeros are load-bearing
@@ -125,7 +129,9 @@ def dist_topk_pallas(coords: jax.Array, qc: jax.Array, qmask: jax.Array,
 
     Args:
       coords: (v, m) vocabulary embedding vectors, shared by all queries.
-      qc:     (nq, h, m) query-bin embedding vectors.
+      qc:     (nq, h, m) query-bin embedding vectors. Both in the
+        precision policy's compute dtype (same for both), which sets the
+        distance matmul's precision; norms and selection are float32.
       qmask:  (nq, 1, h) 1.0 for valid query bins, 0.0 for padding.
       k:      number of smallest distances to keep per vocabulary row.
       out_dtype: storage dtype of Z (a precision policy's storage role);
@@ -158,6 +164,8 @@ def dist_topk_pallas(coords: jax.Array, qc: jax.Array, qmask: jax.Array,
             jax.ShapeDtypeStruct((nq, v, k), jnp.dtype(out_dtype)),
             jax.ShapeDtypeStruct((nq, v, k), jnp.int32),
         ],
+        compiler_params=compiler_params("parallel", "parallel",
+                                        "arbitrary"),
         interpret=interpret,
     )(coords, qc, qmask)
     return z, s

@@ -1,8 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Handle padding to block multiples, backend selection (interpret=True on
-CPU — the container has no TPU; the kernels are written for TPU BlockSpec
-tiling and validated in interpret mode), and shape restoration.
+Handle padding to block multiples, backend selection and shape
+restoration. The kernels compile with Mosaic on a TPU; on the CPU
+platform (the test suites) they run in Pallas interpret mode. Any other
+platform compiles too — and fails loudly if it cannot — rather than
+silently interpreting.
 
 ``JAX_PALLAS_INTERPRET=1`` forces interpret mode on every backend — the
 CI kernel-conformance job sets it so the suite pins the interpreted
@@ -12,14 +14,16 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 import os
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.act_phase2 import act_phase2_cand_pallas, act_phase2_pallas
-from repro.kernels.cand_pour import cand_dist_pallas, cand_pour_pallas
+from repro.core.precision import pad_dist_for
+from repro.kernels.cand_pour import (DIST_MODES, POUR_MODES, cand_pallas,
+                                     segment_rows, split_bf16, table_rows)
+from repro.kernels import tiling
+from repro.kernels.act_phase2 import act_phase2_pallas
 from repro.kernels.dist_topk import dist_topk_pallas
 
 
@@ -31,11 +35,10 @@ _FORCE_INTERPRET = os.environ.get("JAX_PALLAS_INTERPRET", "") not in ("",
 
 
 def _interpret_default() -> bool:
-    return _FORCE_INTERPRET or jax.default_backend() != "tpu"
+    return _FORCE_INTERPRET or jax.default_backend() == "cpu"
 
 
-def _round_up(x: int, b: int) -> int:
-    return -(-x // b) * b
+_round_up = tiling.round_up
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_v", "block_h",
@@ -92,16 +95,16 @@ def act_phase2_batched(x: jax.Array, zg: jax.Array, wg: jax.Array, *,
                        block_n: int = 256, block_h: int = 256) -> jax.Array:
     """Fused Phase-2/3 pour for a query batch in one kernel launch.
 
-    x (n, hmax) shared residual weights; zg (nq, n, hmax, k) and
-    wg (nq, n, hmax, k-1) per-query ladders -> t (nq, n). Padding
-    rows/slots must carry zero weight (they do, by the Corpus
+    x (n, hmax) shared residual weights; zg (nq, k, n, hmax) and
+    wg (nq, k-1, n, hmax) per-query rung-major ladders -> t (nq, n).
+    Padding rows/slots must carry zero weight (they do, by the Corpus
     construction), so block padding contributes exactly 0 cost."""
     n, hmax = x.shape
     block_n = min(block_n, _round_up(n, 8))
     block_h = min(block_h, _round_up(hmax, 8))
     np_, hp = _round_up(n, block_n), _round_up(hmax, block_h)
     pad2 = ((0, np_ - n), (0, hp - hmax))
-    pad4 = ((0, 0),) + pad2 + ((0, 0),)
+    pad4 = ((0, 0), (0, 0)) + pad2
     t = act_phase2_pallas(jnp.pad(x, pad2), jnp.pad(zg, pad4),
                           jnp.pad(wg, pad4), block_n=block_n,
                           block_h=block_h, interpret=_interpret_default())
@@ -111,32 +114,11 @@ def act_phase2_batched(x: jax.Array, zg: jax.Array, wg: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("block_n", "block_h"))
 def act_phase2(x: jax.Array, zg: jax.Array, wg: jax.Array, *,
                block_n: int = 256, block_h: int = 256) -> jax.Array:
-    """Fused Phase-2/3 pour. x (n, hmax), zg (n, hmax, k), wg (n, hmax, k-1)
-    -> t (n,). Single-query view of ``act_phase2_batched``."""
+    """Fused Phase-2/3 pour. x (n, hmax), zg (k, n, hmax),
+    wg (k-1, n, hmax) -> t (n,). Single-query view of
+    ``act_phase2_batched``."""
     return act_phase2_batched(x, zg[None], wg[None], block_n=block_n,
                               block_h=block_h)[0]
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "block_h"))
-def act_phase2_cand(xg: jax.Array, zg: jax.Array, wg: jax.Array, *,
-                    block_n: int = 256, block_h: int = 256) -> jax.Array:
-    """Candidate-grid Phase-2/3 pour: per-query residuals.
-
-    xg (nq, b, hmax) per-query candidate weights; zg (nq, b, hmax, k) /
-    wg (nq, b, hmax, k-1) pre-gathered ladders -> t (nq, b). The unfused
-    schedule for callers already holding gathered ladders; the ``cand_*``
-    wrappers below fuse the gather into the same launch."""
-    nq, b, hmax = xg.shape
-    block_n = min(block_n, _round_up(b, 8))
-    block_h = min(block_h, _round_up(hmax, 8))
-    bp, hp = _round_up(b, block_n), _round_up(hmax, block_h)
-    pad3 = ((0, 0), (0, bp - b), (0, hp - hmax))
-    pad4 = pad3 + ((0, 0),)
-    t = act_phase2_cand_pallas(jnp.pad(xg, pad3), jnp.pad(zg, pad4),
-                               jnp.pad(wg, pad4), block_n=block_n,
-                               block_h=block_h,
-                               interpret=_interpret_default())
-    return t[:, :b, 0]
 
 
 # ------------------------------------------------------ candidate kernels
@@ -145,25 +127,39 @@ def act_phase2_cand(xg: jax.Array, zg: jax.Array, wg: jax.Array, *,
 # Shapes: idsg/xg (nq, b, hmax) are the candidate sub-corpus
 # (corpus.ids[cand] / corpus.w[cand] — already compacted, k+ times smaller
 # than the ladder gathers these kernels avoid); the Phase-1 handoff rides
-# in per-query tables. Padding added here (candidate rows to a block_n
-# multiple, vocabulary rows to a block_v multiple) contributes exactly
+# in per-query tables, laid out row-major (rows, v) and split into exact
+# bfloat16 parts here (``kernels/cand_pour``). Padding added here
+# (candidate rows to a block_n multiple, slots to a lane multiple, table
+# rows to a tile, vocabulary to a block_v multiple) contributes exactly
 # zero cost and is sliced off.
 
 
-def _cand_blocking(idsg, xg, table, block_n: int, block_v: int):
-    """Shared blocking for the fused candidate wrappers: clamp the tiles
-    to the (8-rounded) data sizes, zero-pad the candidate axis to a
-    block_n multiple and the table's vocabulary axis to a block_v
-    multiple. Returns (idsg, xg, table, block_n, block_v, b) with ``b``
-    the original candidate count to slice the output back to."""
+def _cand_launch(idsg, xg, table, mode: str, *, qw=None, k: int = 1,
+                 iters: int = 0, qh: int = 1, block_n: int, block_v: int):
+    """Pad, split and launch one fused candidate kernel. ``table``:
+    (nq, w, v) row-major per-query table (w <= the mode's rows)."""
     nq, b, hmax = idsg.shape
-    v = table.shape[1]
+    v = table.shape[-1]
+    rows = table_rows(mode, k=k, iters=iters, qh=qh)
     block_n = min(block_n, _round_up(b, 8))
     block_v = min(block_v, _round_up(v, 8))
-    padb = ((0, 0), (0, _round_up(b, block_n) - b), (0, 0))
-    table = jnp.pad(table, ((0, 0), (0, _round_up(v, block_v) - v), (0, 0)))
-    return (jnp.pad(idsg, padb), jnp.pad(xg, padb), table, block_n,
-            block_v, b)
+    bp, vp = _round_up(b, block_n), _round_up(v, block_v)
+    hp = _round_up(hmax, tiling.LANE)
+    pad = ((0, 0), (0, bp - b), (0, hp - hmax))
+    table = jnp.pad(table, ((0, 0), (0, rows - table.shape[1]), (0, vp - v)))
+    parts = split_bf16(table).reshape(nq, -1, vp)
+    if qw is not None:
+        qw = jnp.pad(qw.astype(jnp.float32),
+                     ((0, 0), (0, rows - qh)))[..., None]
+    t = cand_pallas(jnp.pad(idsg, pad), jnp.pad(xg, pad), parts, qw,
+                    mode=mode, k=k, iters=iters, qh=qh, block_n=block_n,
+                    block_v=block_v, interpret=_interpret_default())
+    return t[:, :b, 0]
+
+
+def _rows(*cols):
+    """(nq, v) columns -> the (nq, len(cols), v) row-major table."""
+    return jnp.stack(cols, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "block_n", "block_v"))
@@ -180,14 +176,17 @@ def cand_pour(idsg: jax.Array, xg: jax.Array, Z: jax.Array,
     see ``kernels/cand_pour``'s conformance notes).
     """
     k = iters + 1
-    table = Z[..., :k] if iters == 0 else \
-        jnp.concatenate([Z[..., :k], W[..., :iters]], axis=-1)
-    idsg, xg, table, block_n, block_v, b = _cand_blocking(
-        idsg, xg, table, block_n, block_v)
-    t = cand_pour_pallas(idsg, xg, table, k=k, iters=iters, mode="pour",
-                         block_n=block_n, block_v=block_v,
-                         interpret=_interpret_default())
-    return t[:, :b]
+    if iters == 0:
+        return _cand_launch(idsg, xg, _rows(Z[..., 0]), "pour", k=1,
+                            block_n=block_n, block_v=block_v)
+    # the pour's capacity prefix, taken per vocabulary row exactly as
+    # ``lc.pour`` takes it per gathered entry (f32 accumulator)
+    Wf = W[..., :iters].astype(jnp.float32)
+    prefix = jnp.cumsum(Wf, axis=-1) - Wf
+    table = jnp.concatenate([Z[..., :k].astype(jnp.float32), Wf, prefix],
+                            axis=-1)
+    return _cand_launch(idsg, xg, jnp.swapaxes(table, 1, 2), "pour", k=k,
+                        iters=iters, block_n=block_n, block_v=block_v)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_v"))
@@ -198,21 +197,9 @@ def cand_omr(idsg: jax.Array, xg: jax.Array, Z: jax.Array, W0: jax.Array,
     idsg/xg (nq, b, hmax); Z (nq, v, 2) top-2 costs; W0 (nq, v) first
     capacities -> (nq, b) scores.
     """
-    table = jnp.concatenate([Z[..., :2], W0[..., None]], axis=-1)
-    idsg, xg, table, block_n, block_v, b = _cand_blocking(
-        idsg, xg, table, block_n, block_v)
-    t = cand_pour_pallas(idsg, xg, table, k=2, iters=1, mode="omr",
-                         block_n=block_n, block_v=block_v,
-                         interpret=_interpret_default())
-    return t[:, :b]
-
-
-def _cand_dist(idsg, xg, Dq, qw, mode, block_n, block_v):
-    idsg, xg, dq, block_n, block_v, b = _cand_blocking(
-        idsg, xg, Dq, block_n, block_v)
-    t = cand_dist_pallas(idsg, xg, dq, qw, mode=mode, block_n=block_n,
-                         block_v=block_v, interpret=_interpret_default())
-    return t[:, :b]
+    table = _rows(Z[..., 0], Z[..., 1], W0.astype(Z.dtype))
+    return _cand_launch(idsg, xg, table, "omr", block_n=block_n,
+                        block_v=block_v)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_v"))
@@ -225,7 +212,9 @@ def cand_rev_min(idsg: jax.Array, xg: jax.Array, Dq: jax.Array,
     query weights -> (nq, b) scores (invalid slots mask to the finite
     ``lc.PAD_DIST``, matching ``lc.rev_min_cand_blocked``).
     """
-    return _cand_dist(idsg, xg, Dq, qw, "rev_min", block_n, block_v)
+    h = Dq.shape[-1]
+    return _cand_launch(idsg, xg, jnp.swapaxes(Dq, 1, 2), "rev_min", qw=qw,
+                        qh=h, block_n=block_n, block_v=block_v)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_v"))
@@ -235,11 +224,29 @@ def cand_ict(idsg: jax.Array, xg: jax.Array, Dq: jax.Array,
     """Fused candidate gather + LC-ICT full-ladder pour (Algorithm 2).
 
     idsg/xg (nq, b, hmax); Dq (nq, v, h); qw (nq, h) -> (nq, b) scores.
-    Runs ``lc.ict_pour`` on the gathered tile, so the remainder dump
-    stays at the max FINITE gathered cost — never ``lc.PAD_DIST``, where
-    a ~1e-7 cumsum residue would explode to ~1e23.
+    The cost order, capacity prefix and dump cost of ``lc.ict_pour``
+    depend only on the vocabulary row, so they are taken here once per
+    (query, row) with ``ict_pour``'s own ops and gathered in-kernel. The
+    remainder dump stays at the max FINITE gathered cost — never
+    ``lc.PAD_DIST``, where a ~1e-7 cumsum residue would explode to ~1e23.
     """
-    return _cand_dist(idsg, xg, Dq, qw, "ict", block_n, block_v)
+    h = Dq.shape[-1]
+    C = Dq.astype(jnp.float32)
+    order = jnp.argsort(C, axis=-1)
+    cost = jnp.take_along_axis(C, order, axis=-1)
+    cap = jnp.take_along_axis(
+        jnp.broadcast_to(qw.astype(jnp.float32)[:, None, :], C.shape),
+        order, axis=-1)
+    prefix = jnp.cumsum(cap, axis=-1) - cap
+    dump = jnp.max(jnp.where(C < pad_dist_for(jnp.float32), C, 0.0), axis=-1)
+    hs = segment_rows(h)
+
+    def seg(a):                                          # (nq, v, h) -> rows
+        return jnp.pad(jnp.swapaxes(a, 1, 2), ((0, 0), (0, hs - h), (0, 0)))
+    table = jnp.concatenate([seg(cost), seg(cap), seg(prefix),
+                             dump[:, None, :]], axis=1)
+    return _cand_launch(idsg, xg, table, "ict", qh=h, block_n=block_n,
+                        block_v=block_v)
 
 
 # --------------------------------------------------- static block metadata
@@ -261,24 +268,34 @@ class BlockBuffer:
     """One VMEM-resident buffer of a kernel grid cell.
 
     role: ``in`` / ``out`` blocks are pipelined by Pallas (double-buffered
-    while the grid streams, so they count twice in the footprint);
-    ``scratch`` covers the kernel body's dominant temporaries (single
-    copy). The scratch entries are a documented lower-ish bound — Mosaic
-    may materialize more registers — which is why the VMEM budget the
-    checker enforces leaves headroom below the hardware's ~16 MB.
+    while the grid streams, so they count twice in the footprint) and
+    carry the ``array`` they tile, against which Mosaic's block rule is
+    checked; ``scratch`` covers the kernel body's dominant temporaries
+    (single copy). The scratch entries are a documented lower-ish bound —
+    Mosaic may materialize more registers.
     """
     name: str
     shape: tuple[int, ...]
     dtype: str = "float32"
     role: str = "in"
+    array: tuple[int, ...] | None = None
 
     def __post_init__(self) -> None:
         assert self.role in ("in", "out", "scratch"), self.role
         assert self.dtype in _DTYPE_BYTES, self.dtype
+        assert (self.array is None) == (self.role == "scratch"), self.name
 
     @property
     def nbytes(self) -> int:
-        return math.prod(self.shape) * _DTYPE_BYTES[self.dtype]
+        """Tile-padded VMEM bytes (``tiling.padded_bytes``)."""
+        return tiling.padded_bytes(self.shape, _DTYPE_BYTES[self.dtype])
+
+    @property
+    def tiling_error(self) -> str | None:
+        """Why Mosaic would refuse this block (None for scratch / legal)."""
+        if self.array is None:
+            return None
+        return tiling.block_tiling_error(self.shape, self.array)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -322,13 +339,13 @@ def _dist_topk_layout(*, nq: int, v: int, h: int, m: int, k: int,
         family="dist_topk",
         grid=(nq, vp // block_v, hp // block_h),
         buffers=(
-            BlockBuffer("coords", (block_v, m)),
-            BlockBuffer("qcs", (1, block_h, m)),
-            BlockBuffer("qmask", (1, 1, block_h)),
+            BlockBuffer("coords", (block_v, m), array=(vp, m)),
+            BlockBuffer("qcs", (1, block_h, m), array=(nq, hp, m)),
+            BlockBuffer("qmask", (1, 1, block_h), array=(nq, 1, hp)),
             # z is the Z-ladder STORAGE block (``dtype`` = the precision
             # policy's storage role — the axis that shrinks under bf16)
-            BlockBuffer("z", (1, block_v, k), dtype, "out"),
-            BlockBuffer("s", (1, block_v, k), "int32", "out"),
+            BlockBuffer("z", (1, block_v, k), dtype, "out", (nq, vp, k)),
+            BlockBuffer("s", (1, block_v, k), "int32", "out", (nq, vp, k)),
             # the (bv, bh) distance tile + its global column ids — the
             # body's working set that never leaves VMEM
             BlockBuffer("dist_tile", (block_v, block_h), role="scratch"),
@@ -338,7 +355,6 @@ def _dist_topk_layout(*, nq: int, v: int, h: int, m: int, k: int,
 
 def _act_phase2_layout(*, nq: int, n: int, h: int, iters: int,
                        block_n: int = 256, block_h: int = 256,
-                       per_query_x: bool = False,
                        dtype: str = "float32") -> KernelBlocks:
     _positive(nq=nq, n=n, h=h, block_n=block_n, block_h=block_h)
     if iters < 0:
@@ -346,95 +362,80 @@ def _act_phase2_layout(*, nq: int, n: int, h: int, iters: int,
     block_n = min(block_n, _round_up(n, 8))
     block_h = min(block_h, _round_up(h, 8))
     np_, hp = _round_up(n, block_n), _round_up(h, block_h)
-    x_shape = (1, block_n, block_h) if per_query_x else (block_n, block_h)
     return KernelBlocks(
-        family="act_phase2_cand" if per_query_x else "act_phase2",
+        family="act_phase2",
         grid=(nq, np_ // block_n, hp // block_h),
         buffers=(
-            BlockBuffer("x", x_shape),
-            # the gathered Phase-1 ladders ride in storage dtype; the
-            # pour itself upcasts slice-by-slice to float32 scratch
-            BlockBuffer("zg", (1, block_n, block_h, iters + 1), dtype),
-            BlockBuffer("wg", (1, block_n, block_h, iters), dtype),
-            BlockBuffer("t", (1, block_n, 1), role="out"),
+            BlockBuffer("x", (block_n, block_h), array=(np_, hp)),
+            # the gathered rung-major Phase-1 ladders ride in storage
+            # dtype; the pour itself upcasts rung-by-rung to float32
+            BlockBuffer("zg", (1, iters + 1, block_n, block_h), dtype,
+                        array=(nq, iters + 1, np_, hp)),
+            BlockBuffer("wg", (1, iters, block_n, block_h), dtype,
+                        array=(nq, iters, np_, hp)),
+            BlockBuffer("t", (1, block_n, 1), role="out",
+                        array=(nq, np_, 1)),
             # pour temporaries: acc / prefix / poured / r, each (bn, bh)
             BlockBuffer("pour_tmp", (4, block_n, block_h), role="scratch"),
         ))
 
 
-def _cand_table_width(mode: str, k: int, iters: int) -> int:
-    if mode == "omr":
-        return 3                                   # Z top-2 + W0
-    return k + iters                               # Z ladder + W ladder
+def _cand_layout(family: str, *, nq: int, b: int, h: int, v: int,
+                 mode: str, k: int = 1, iters: int = 0, qh: int = 1,
+                 block_n: int = 128, block_v: int = 256,
+                 dtype: str = "float32") -> KernelBlocks:
+    """Both candidate families: one gather-accumulate grid per mode
+    (``kernels/cand_pour``). The table rides as exact bf16 parts — one
+    for a bf16 storage table, three for float32 (the pour's capacity
+    prefix and ICT's ladders are always float32)."""
+    _positive(nq=nq, b=b, h=h, v=v, k=k, qh=qh, block_n=block_n,
+              block_v=block_v)
+    rows = table_rows(mode, k=k, iters=iters, qh=qh)
+    f32_table = (dtype != "bfloat16" or mode == "ict"
+                 or (mode == "pour" and iters > 0))
+    tab_rows = (3 if f32_table else 1) * rows
+    block_n = min(block_n, _round_up(b, 8))
+    block_v = min(block_v, _round_up(v, 8))
+    bp, vp = _round_up(b, block_n), _round_up(v, block_v)
+    hp = _round_up(h, tiling.LANE)
+    reduce_tmp = {"rev_min": (rows, hp),
+                  "ict": (3, segment_rows(qh), hp)}.get(mode, (8, hp))
+    qw = ((BlockBuffer("qw", (1, rows, 1), array=(nq, rows, 1)),)
+          if mode == "rev_min" else ())
+    return KernelBlocks(
+        family=family,
+        grid=(nq, bp // block_n, vp // block_v),
+        buffers=(
+            BlockBuffer("idsg", (1, block_n, hp), "int32",
+                        array=(nq, bp, hp)),
+            BlockBuffer("xg", (1, block_n, hp), array=(nq, bp, hp)),
+            # one streamed vocabulary slab of the query's table per step
+            BlockBuffer("table", (1, tab_rows, block_v), "bfloat16",
+                        array=(nq, tab_rows, vp)),
+            *qw,
+            BlockBuffer("t", (1, block_n, 1), role="out",
+                        array=(nq, bp, 1)),
+            # the persistent per-row gather accumulator
+            BlockBuffer("acc", (block_n, rows, hp), role="scratch"),
+            BlockBuffer("onehot", (block_v, hp), "bfloat16", "scratch"),
+            BlockBuffer("slab", (rows, hp), role="scratch"),
+            BlockBuffer("reduce_tmp", reduce_tmp, role="scratch"),
+        ))
 
 
 def _cand_pour_layout(*, nq: int, b: int, h: int, v: int, k: int,
-                      iters: int, mode: str = "pour", block_n: int = 128,
-                      block_v: int = 256,
-                      dtype: str = "float32") -> KernelBlocks:
-    from repro.kernels.cand_pour import POUR_MODES
+                      iters: int, mode: str = "pour", **blocks
+                      ) -> KernelBlocks:
     assert mode in POUR_MODES, mode
-    _positive(nq=nq, b=b, h=h, v=v, k=k, block_n=block_n, block_v=block_v)
-    width = _cand_table_width(mode, k, iters)
-    block_n = min(block_n, _round_up(b, 8))
-    block_v = min(block_v, _round_up(v, 8))
-    bp, vp = _round_up(b, block_n), _round_up(v, block_v)
-    r = block_n * h
-    return KernelBlocks(
-        family="cand_pour",
-        grid=(nq, bp // block_n),
-        buffers=(
-            BlockBuffer("idsg", (1, block_n, h), "int32"),
-            BlockBuffer("xg", (1, block_n, h)),
-            # the query's FULL padded Phase-1 ladder rides in every cell
-            # in storage dtype — the dominant slab bf16 halves
-            BlockBuffer("table", (1, vp, width), dtype),
-            BlockBuffer("t", (1, block_n), role="out"),
-            # the one-hot gather matmul runs in the table's dtype (0/1
-            # are exact in any float dtype); accumulation is f32
-            BlockBuffer("onehot", (r, block_v), dtype, "scratch"),
-            BlockBuffer("gathered", (r, width), role="scratch"),
-            BlockBuffer("chunk", (block_v, width), dtype, "scratch"),
-        ))
+    return _cand_layout("cand_pour", nq=nq, b=b, h=h, v=v, mode=mode, k=k,
+                        iters=iters, **blocks)
 
 
 def _cand_dist_layout(*, nq: int, b: int, h: int, v: int, qh: int,
-                      mode: str = "rev_min", block_n: int = 128,
-                      block_v: int = 256,
-                      dtype: str = "float32") -> KernelBlocks:
-    from repro.kernels.cand_pour import DIST_MODES
+                      mode: str = "rev_min", **blocks) -> KernelBlocks:
     assert mode in DIST_MODES, mode
-    _positive(nq=nq, b=b, h=h, v=v, qh=qh, block_n=block_n, block_v=block_v)
-    block_n = min(block_n, _round_up(b, 8))
-    block_v = min(block_v, _round_up(v, 8))
-    bp, vp = _round_up(b, block_n), _round_up(v, block_v)
-    r = block_n * h
-    scratch = [
-        BlockBuffer("onehot", (r, block_v), dtype, "scratch"),
-        # the running gather accumulator: persists across the streamed
-        # vocabulary slabs, holds the completed (r, qh) cost tensor on
-        # the last one
-        BlockBuffer("acc", (r, qh), role="scratch"),
-        # rev_min: the PAD_DIST-masked copy; ict: ict_pour's sorted
-        # ladder + cumsum, ~2 extra copies of the gathered cost tile
-        BlockBuffer("reduce_tmp",
-                    ((1 if mode == "rev_min" else 2) * r, qh),
-                    role="scratch"),
-    ]
-    return KernelBlocks(
-        family="cand_dist",
-        grid=(nq, bp // block_n, vp // block_v),
-        buffers=(
-            BlockBuffer("idsg", (1, block_n, h), "int32"),
-            BlockBuffer("xg", (1, block_n, h)),
-            # one streamed slab per grid step — NOT the full (vp, qh)
-            # handoff; this is what fits cand_dist at 20News dims.
-            # Rides in storage dtype; the gather accumulates into f32.
-            BlockBuffer("dq", (1, block_v, qh), dtype),
-            BlockBuffer("qw", (1, qh)),
-            BlockBuffer("t", (1, block_n), role="out"),
-            *scratch,
-        ))
+    return _cand_layout("cand_dist", nq=nq, b=b, h=h, v=v, mode=mode, qh=qh,
+                        **blocks)
 
 
 #: family name -> layout function. The enumerable surface
@@ -444,8 +445,6 @@ def _cand_dist_layout(*, nq: int, b: int, h: int, v: int, qh: int,
 KERNEL_FAMILIES = {
     "dist_topk": _dist_topk_layout,
     "act_phase2": _act_phase2_layout,
-    "act_phase2_cand": functools.partial(_act_phase2_layout,
-                                         per_query_x=True),
     "cand_pour": _cand_pour_layout,
     "cand_dist": _cand_dist_layout,
 }

@@ -19,10 +19,11 @@ share one trace.
 Partitioning per kernel family:
 
 * ``dist_topk`` (Phase 1) — queries over DP, vocabulary rows over
-  "model". Each (vocab-shard, query-shard) cell computes its own
-  distance tile and per-row top-k; the selection indexes the query's
-  histogram slots (h, unsharded), so the per-row result never crosses
-  shards. The W capacity gather runs inside the shard (``Q_w`` is
+  "model" (padded to a multiple of the axis; the pad rows are sliced off
+  the ladders, no corpus id reaches them). Each (vocab-shard,
+  query-shard) cell computes its own distance tile and per-row top-k;
+  the selection indexes the query's histogram slots (h, unsharded), so
+  the per-row result never crosses shards. The W capacity gather runs inside the shard (``Q_w`` is
   DP-local, S indexes h). Downstream, the caller re-pins the (nq, v, k)
   ladders to the ``annotate.emd_ladder`` layout — the same replication
   all-gather the jnp pipeline performs.
@@ -41,12 +42,14 @@ Partitioning per kernel family:
   scaling guard forbids), while outside, XLA's partitioned gather moves
   only the (nq, b, hmax) candidate rows. The model axis is unmentioned
   in the specs: inputs are replicated over it and every model shard
-  computes the same (nq/dp, b) block (``check_rep=False`` skips the
+  computes the same (nq/dp, b) block (``check_vma=False`` skips the
   replication proof current shard_map cannot do for these bodies).
 
 Every shim has a divisibility precondition (``queries_shardable`` and
-friends); callers fall back to the non-shard_map kernel path when a dim
-does not split — still correct everywhere interpret mode runs.
+``rows_shardable``); callers fall back to the non-shard_map kernel path
+when a dim does not split. That fallback is an interpret-mode (host
+mesh) path only: the TPU compiler refuses a Mosaic kernel it would have
+to partition itself, so on a chip mesh it fails loudly instead.
 """
 from __future__ import annotations
 
@@ -54,17 +57,15 @@ import functools
 import math
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import lc
 from repro.kernels import ops as kops
 from repro.launch.mesh import data_axes, model_axis_size
 
-if hasattr(jax, "shard_map"):                            # jax >= 0.6
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _sm
-    _shard_map = functools.partial(_sm, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _dp(mesh):
@@ -77,16 +78,26 @@ def _dp_size(mesh) -> int:
     return math.prod(mesh.shape[a] for a in data_axes(mesh))
 
 
+def _handoff_layout(mesh, a):
+    """Pin a (nq, v, k) Phase-1 handoff to the ``annotate.emd_ladder``
+    layout — queries on their DP shards, the rest replicated — on the
+    explicit ``mesh``, which the engines may reach without an ambient
+    one. Left vocabulary-sharded, its consumers' gathers would partition
+    into per-shard partial sums. Reduced-precision ladders cross as
+    same-width unsigned integers, as in ``emd_ladder``."""
+    sh = NamedSharding(mesh, P(_dp(mesh), *([None] * (a.ndim - 1))))
+    if a.dtype == jnp.float32:
+        return jax.lax.with_sharding_constraint(a, sh)
+    u = jax.lax.bitcast_convert_type(
+        a, jnp.dtype(f"uint{a.dtype.itemsize * 8}"))
+    return jax.lax.bitcast_convert_type(
+        jax.lax.with_sharding_constraint(u, sh), a.dtype)
+
+
 def queries_shardable(mesh, nq: int) -> bool:
     """True when the query batch splits evenly over the mesh's DP axes —
     the precondition of every shim here."""
     return nq % _dp_size(mesh) == 0
-
-
-def phase1_shardable(mesh, nq: int, v: int) -> bool:
-    """Precondition of :func:`dist_topk_sharded`: queries split over DP
-    and vocabulary rows over "model"."""
-    return queries_shardable(mesh, nq) and v % model_axis_size(mesh) == 0
 
 
 def rows_shardable(mesh, nq: int, n: int) -> bool:
@@ -99,23 +110,28 @@ def dist_topk_sharded(mesh, coords, qcs, Q_w, k: int, *,
                       block_v: int = 256, block_h: int = 256,
                       out_dtype: str = "float32"):
     """Phase-1 kernel on the mesh: coords (v, m) sharded over "model",
-    qcs (nq, h, m) / Q_w (nq, h) over DP -> Z, W each (nq, v, k) on the
-    (DP, "model") grid, in ``out_dtype`` (a precision policy's storage
-    role — this is the handoff whose replication all-gather the policy
-    halves). Caller re-pins to the emd_ladder layout."""
+    qcs (nq, h, m) / Q_w (nq, h) over DP -> Z (nq, v, k) and the
+    capacities of its first k-1 rungs W (nq, v, k-1) — the last rung
+    takes only the Phase-3 dump, whose capacity no reduction reads — in
+    ``out_dtype`` (a precision policy's storage role — this is the
+    handoff whose replication all-gather the policy halves), on the
+    emd_ladder layout. Precondition: :func:`queries_shardable`."""
+    v = coords.shape[0]
+    coords = jnp.pad(coords, ((0, -v % model_axis_size(mesh)), (0, 0)))
     def body(coords_l, qcs_l, qw_l):
         Z, S = kops.dist_topk_batched(coords_l, qcs_l, k,
                                       qmask=(qw_l > 0.0), block_v=block_v,
                                       block_h=block_h, out_dtype=out_dtype)
-        W = jax.vmap(lambda w, s: w[s])(qw_l, S).astype(out_dtype)
+        W = lc.take_bins(qw_l, S[..., :k - 1]).astype(out_dtype)
         return Z, W
 
     dp = _dp(mesh)
-    return _shard_map(
+    Z, W = _shard_map(
         body, mesh=mesh,
         in_specs=(P("model", None), P(dp, None, None), P(dp, None)),
         out_specs=(P(dp, "model", None), P(dp, "model", None)),
     )(coords, qcs, Q_w)
+    return _handoff_layout(mesh, Z[:, :v]), _handoff_layout(mesh, W[:, :v])
 
 
 def act_pour_sharded(mesh, ids, w, Z, W, iters: int, *, block_q: int = 8,
@@ -148,8 +164,8 @@ def act_pour_sharded(mesh, ids, w, Z, W, iters: int, *, block_q: int = 8,
                else jax.lax.bitcast_convert_type(W_l, wdt))
 
         def blk(Zb, Wb):
-            Zg = Zb[:, ids_l]                            # (bq, n/sh, hmax, k)
-            Wg = Wb[:, ids_l]
+            Zg = jnp.swapaxes(Zb, 1, 2)[:, :, ids_l]     # (bq, k, n/sh, hmax)
+            Wg = jnp.swapaxes(Wb, 1, 2)[:, :, ids_l]
             return kops.act_phase2_batched(w_l, Zg, Wg, block_n=block_n,
                                            block_h=block_h)
         return lc._map_query_blocks(blk, (Z_l, W_l), Z_l.shape[0], block_q)

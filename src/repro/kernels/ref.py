@@ -53,13 +53,6 @@ def act_phase2_batched_ref(x: jax.Array, zg: jax.Array,
     return jax.vmap(lambda z, w: act_phase2_ref(x, z, w)[:, 0])(zg, wg)
 
 
-def act_phase2_cand_ref(xg: jax.Array, zg: jax.Array,
-                        wg: jax.Array) -> jax.Array:
-    """Per-query loop with per-query residuals: the (nq, b) oracle for
-    the candidate-grid pour (each query pours its own sub-corpus)."""
-    return jax.vmap(lambda x, z, w: act_phase2_ref(x, z, w)[:, 0])(xg, zg, wg)
-
-
 def cand_pour_ref(idsg: jax.Array, xg: jax.Array, Z: jax.Array,
                   W: jax.Array | None, iters: int) -> jax.Array:
     """XLA-gather oracle for ``cand_pour``: per-query ladder gather at the

@@ -149,8 +149,27 @@ def test_vmem_rejects_over_budget_blocks():
     out = vmem.check_launch(
         "seeded", "cand_pour",
         dict(nq=8, b=4096, h=500, v=69_682, k=8, iters=7,
-             block_n=256, block_v=256))
+             block_n=4096, block_v=256))
     assert any("exceeds" in v.message for v in out)
+
+
+def test_vmem_rejects_blocks_mosaic_refuses():
+    """A row tile that is neither a multiple of 8 nor the whole padded
+    candidate axis is refused, as the chip's compiler refuses it — even
+    though it fits the budget and interpret mode would run it."""
+    dims = dict(nq=8, b=512, h=500, v=69_682, qh=500, mode="rev_min")
+    out = vmem.check_launch("seeded", "cand_dist", {**dims, "block_n": 2})
+    assert any("(8, 128)-or-full" in v.message for v in out)
+    assert vmem.check_launch("seeded", "cand_dist",
+                             {**dims, "block_n": 8}) == []
+    # a vocabulary slab must be a lane multiple unless it spans the table
+    out = vmem.check_launch("seeded", "cand_pour",
+                            dict(nq=8, b=64, h=16, v=4096, k=4, iters=3,
+                                 block_v=64))
+    assert any("(8, 128)-or-full" in v.message for v in out)
+    assert vmem.check_launch("seeded", "cand_pour",
+                             dict(nq=8, b=64, h=16, v=40, k=4, iters=3,
+                                  block_v=64)) == []
 
 
 def test_vmem_rejects_invalid_config():
@@ -179,10 +198,9 @@ def test_block_layout_mirrors_wrapper_clamps():
 
 def test_block_layout_act_ladder_widths():
     layout = ops.block_layout("act_phase2", nq=2, n=64, h=32, iters=3)
-    assert layout.buffer("zg").shape[-1] == 4          # iters + 1
-    assert layout.buffer("wg").shape[-1] == 3          # iters
-    cand = ops.block_layout("act_phase2_cand", nq=2, n=64, h=32, iters=3)
-    assert cand.buffer("x").shape == (1, 64, 32)       # per-query gather
+    assert layout.buffer("zg").shape[1] == 4           # iters + 1 rungs
+    assert layout.buffer("wg").shape[1] == 3           # iters
+    assert layout.buffer("x").shape == (64, 32)        # shared over queries
 
 
 def test_vmem_counts_pipelined_buffers_twice():
@@ -251,6 +269,49 @@ def test_while_without_recovered_trip_count_counts_once():
     hlo = _WHILE_HLO.replace("constant(5)", "parameter(1)")
     got = collective_bytes(hlo, 4)
     assert got["all-gather"] == 192
+
+
+#: The CPU emulation of a bf16 all-gather: a fusion upcasts to f32, the
+#: collective runs at f32. ``{src}`` is the convert's operand as each XLA
+#: text format prints it: typed inline, or a bare reference.
+_WIDENED_HLO = """\
+HloModule widened
+
+%fused (param_0: {dt}[4]) -> f32[4] {{
+  %param_0 = {dt}[4]{{0}} parameter(0)
+  ROOT %convert.1 = f32[4]{{0}} convert({src})
+}}
+
+ENTRY %main (p0: {dt}[4]) -> f32[16] {{
+  %p0 = {dt}[4]{{0}} parameter(0)
+  %convert_fusion = f32[4]{{0}} fusion(%p0), kind=kLoop, calls=%fused
+  ROOT %ag = f32[16]{{0}} all-gather(%convert_fusion), replica_groups=[1,4], dimensions={{0}}
+}}
+"""
+
+
+@pytest.mark.parametrize("inline", [True, False], ids=["typed", "bare"])
+@pytest.mark.parametrize("dt,width", [("bf16", 2), ("f32", 4)])
+def test_widened_collective_charged_at_semantic_width(inline, dt, width):
+    src = f"{dt}[4]{{0}} %param_0" if inline else "%param_0"
+    got = collective_bytes(_WIDENED_HLO.format(dt=dt, src=src), 4)
+    # 16 elements of the narrow source width, g=4, one group
+    assert got["all-gather"] == 16 * width * 3
+
+
+def test_manifest_bf16_steps_gather_fewer_bytes_than_f32():
+    """The pinned profile of every bf16 mesh step undercuts its float32
+    twin's all-gather bytes (the narrowing contract of the collectives
+    pass), and a seeded full-width bf16 profile is rejected."""
+    from repro.analysis import collectives_check as CC
+    steps = CC.load_manifest()["steps"]
+    cases = S.step_cases()
+    assert [c for c in cases if c.precision == "bf16"]
+    assert CC.check_narrowing(steps, cases) == []
+    seeded = dict(steps)
+    seeded["scores:act:dist:kernels:bf16"] = steps["scores:act:dist:kernels"]
+    out = CC.check_narrowing(seeded, cases)
+    assert [v.subject for v in out] == ["scores:act:dist:kernels:bf16"]
 
 
 # ------------------------------------------------------------ jaxpr walk

@@ -53,14 +53,17 @@ def test_enumeration_is_deterministic_and_deduped():
 
 def test_paper_scale_cand_dist_admits_small_block_n():
     """The acceptance shape: blocked-vocab cand_dist at the 20News paper
-    profile (hmax = qh = 500, vocab ~ 69682) must fit the 16 MiB budget
-    — and only fits with small row tiles, which therefore must be in
-    the candidate set."""
+    profile (hmax = qh = 500, vocab ~ 69682) must fit the scoped-VMEM
+    budget — and only fits with small row tiles, the smallest legal one
+    (8 rows) among them; no sub-8 tile is offered, since Mosaic refuses
+    those."""
     dims = dict(nq=8, b=256, h=500, v=69682, qh=500, mode="ict")
     cfgs = autotune.admissible_configs("cand_dist", dims)
     assert cfgs, "nothing admissible at the paper profile"
-    assert all(c["block_n"] <= 4 for c in cfgs)
-    assert any(c["block_n"] == 2 for c in cfgs)
+    assert all(c["block_n"] <= 16 for c in cfgs)
+    assert any(c["block_n"] == 8 for c in cfgs)
+    assert all(c["block_n"] % 8 == 0 and c["block_v"] % 128 == 0
+               for c in cfgs)
 
 
 def test_admissible_configs_hypothesis_property():

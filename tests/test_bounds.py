@@ -75,8 +75,13 @@ def test_ict_optimal_for_relaxation(data):
 
 @given(st.data())
 def test_sinkhorn_upper_bounds_relaxations(data):
+    """A converged Sinkhorn plan is feasible, so it costs at least EMD >=
+    ICT. Convergence is the premise: some two-bin histograms stall for
+    over 2,000 iterations at lam=50 with the row marginals still off, and
+    such a plan can cost less than ICT. At 4,000 iterations the bound
+    held on every one of 7,000 sampled pairs, two-bin ones included."""
     p, q, C = _histo_pair(data.draw, overlap=False)
-    sk = float(sinkhorn_cost(p, q, C, lam=50.0, n_iters=400))
+    sk = float(sinkhorn_cost(p, q, C, lam=50.0, n_iters=4000))
     assert float(ict(p, q, C)) <= sk + 5e-3
 
 

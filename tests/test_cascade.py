@@ -334,8 +334,10 @@ def _check_admissible_exactness(rescorer: str, seed: int,
 def test_admissible_cascade_exact_fixed_seeds(rescorer, use_kernels):
     """The acceptance property on pinned seeds (always runs, even where
     hypothesis is unavailable) — every registered rescorer, on the
-    reference path AND composed with the fused candidate kernels."""
-    for seed in (3, 17):
+    reference path AND composed with the fused candidate kernels. Seed
+    59 ties two bow rescores at the top-l boundary: the cascade must
+    break the tie by row id, as full-corpus search does."""
+    for seed in (3, 17, 59):
         _check_admissible_exactness(rescorer, seed, use_kernels)
 
 

@@ -18,7 +18,7 @@ _XLA_FLAGS = " ".join(
 _ENV = dict(os.environ,
             XLA_FLAGS=(_XLA_FLAGS
                        + " --xla_force_host_platform_device_count=8").strip(),
-            PYTHONPATH="src")
+            PYTHONPATH="src", JAX_PLATFORMS="cpu")
 
 
 def _run(script: str):
@@ -51,9 +51,7 @@ params = M.init(jax.random.PRNGKey(0), cfg)
 opt = adamw.init(params, cfg.opt_state_dtype)
 dc = DataConfig(vocab=cfg.vocab, seq_len=32, global_batch=8, seed=0)
 losses = []
-import contextlib
-ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else contextlib.nullcontext()
-with ctx:
+with jax.set_mesh(mesh):
     for s in range(30):
         b = {k: jnp.asarray(v) for k, v in global_batch(dc, s).items()}
         params, opt, m = step(params, opt, b)
@@ -80,8 +78,9 @@ params = M.init(jax.random.PRNGKey(0), cfg)
 d = tempfile.mkdtemp()
 store.save(d, 5, params)
 
-mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh8 = make_mesh((4, 2), ("data", "model"))
+mesh4 = make_mesh((2, 2), ("data", "model"))
 p8 = elastic.restore_on_mesh(d, 5, params, mesh8)
 p4 = elastic.restore_on_mesh(d, 5, params, mesh4)
 for a, b, c in zip(jax.tree.leaves(params), jax.tree.leaves(p8),
@@ -105,12 +104,9 @@ from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.optim.grad_utils import compressed_psum_tree
 
-mesh = jax.make_mesh((8,), ("pod",))
-if hasattr(jax, "shard_map"):
-    shard_map = partial(jax.shard_map, check_vma=False)
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _sm
-    shard_map = partial(_sm, check_rep=False)
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("pod",))
+shard_map = partial(jax.shard_map, check_vma=False)
 
 @partial(shard_map, mesh=mesh, in_specs=(P("pod"), P()),
          out_specs=P("pod"))
@@ -119,9 +115,7 @@ def reduce_grads(g, key):
 
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
 key = jax.random.PRNGKey(1)
-import contextlib
-ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else contextlib.nullcontext()
-with ctx:
+with jax.set_mesh(mesh):
     out = reduce_grads(g, key)
 exact = jnp.broadcast_to(jnp.sum(g, 0, keepdims=True), g.shape)
 rel = float(jnp.max(jnp.abs(out - exact)) / jnp.max(jnp.abs(exact)))
@@ -134,20 +128,20 @@ print("COMPRESSED PSUM OK", rel)
 @pytest.mark.slow
 def test_distributed_search_matches_reference():
     out = _run("""
-import jax, jax.numpy as jnp, numpy as np, contextlib
+import jax, jax.numpy as jnp, numpy as np
 from repro.data.synth import make_text_like
 from repro.launch.search import make_search_step, search_shardings, jit_search_step
 from repro.core import lc
 from repro.configs.emd_20news import EMDWorkload
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_text_like(n_docs=16, vocab=64, m=8, doc_len=24, hmax=16)
 w = EMDWorkload(name="t", n_db=16, vocab=64, dim=8, hmax=16, iters=2,
                 queries=8)
 step = jit_search_step(w, mesh, top_l=4)
 q_ids, q_w = corpus.ids[:8], corpus.w[:8]
-ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else contextlib.nullcontext()
-with ctx:
+with jax.set_mesh(mesh):
     scores, idx = step(corpus.ids, corpus.w, corpus.coords, q_ids, q_w)
 # reference: single-device engine
 for u in range(8):
@@ -174,7 +168,8 @@ from repro.api import EmdIndex, EngineConfig
 from repro.core.retrieval import METHODS
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_text_like(n_docs=24, vocab=64, m=8, doc_len=10, hmax=16)
 q_ids, q_w = corpus.ids[:5], corpus.w[:5]       # odd nq: padded to the mesh
 assert bool((np.asarray(q_w) == 0.0).any())     # query-side padding in play
@@ -217,7 +212,8 @@ from repro.api import EmdIndex, EngineConfig
 from repro.core import lc
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_text_like(n_docs=24, n_classes=4, vocab=40, m=6,
                            doc_len=30, hmax=16)
 assert corpus.n * corpus.hmax >= lc.DEDUP_STACK_RATIO * corpus.v
@@ -242,7 +238,8 @@ import dataclasses, jax, numpy as np
 from repro.api import EmdIndex, EngineConfig
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 corpus, _ = make_text_like(n_docs=16, vocab=64, m=8, doc_len=24, hmax=16)
 cfg = EngineConfig(method="act", iters=2, backend="distributed",
                    pad_multiple=8)
@@ -276,7 +273,8 @@ from repro.cascade import CascadeSpec, CascadeStage, rescore
 from repro.core import retrieval
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_text_like(n_docs=24, n_classes=4, vocab=64, m=8,
                            doc_len=10, hmax=16, seed=5)
 nq, top_l = 5, 3
@@ -345,7 +343,7 @@ def test_distributed_cascade_kernel_conformance():
     (b) full-corpus rescoring — the acceptance criterion's mesh half.
     Budgets cover the true neighbors' stage ranks under both paths."""
     out = _run("""
-import contextlib, jax, numpy as np
+import jax, numpy as np
 import jax.numpy as jnp
 from repro.cascade import CascadeSpec, CascadeStage, rescore
 from repro.configs.emd_20news import EMDWorkload
@@ -354,7 +352,8 @@ from repro.core.lc import Corpus
 from repro.data.synth import make_text_like
 from repro.launch import search as Sx
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_text_like(n_docs=24, n_classes=4, vocab=64, m=8,
                            doc_len=10, hmax=16, seed=5)
 nq, top_l, iters = 5, 3, 2
@@ -404,10 +403,8 @@ coords = jax.device_put(padded.coords, in_sh[2])
 qi = jnp.pad(q_ids, ((0, 8 - nq), (0, 0)))      # data axis = 4: pad to 8
 qw = jnp.pad(q_w, ((0, 8 - nq), (0, 0)))
 
-set_mesh = getattr(jax, "set_mesh", None)
-ctx = set_mesh(mesh) if set_mesh else contextlib.nullcontext()
 results = {}
-with ctx:
+with jax.set_mesh(mesh):
     for uk in (False, True):
         step = Sx.jit_cascade_search_step(workload, mesh, spec,
                                           top_l=top_l, pad_multiple=16,
@@ -445,7 +442,8 @@ import jax, numpy as np
 from repro.api import EmdIndex, EngineConfig
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 corpus, _ = make_text_like(n_docs=64, n_classes=4, vocab=96, m=8,
                            doc_len=12, hmax=16, seed=7)
 nq, top_l = 16, 4
@@ -480,7 +478,8 @@ import jax, numpy as np
 from repro.api import EmdIndex, EngineConfig
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_text_like(n_docs=24, vocab=64, m=8, doc_len=24, hmax=16)
 ref = EmdIndex.build(corpus, EngineConfig(method="act", iters=2, top_l=4))
 dst = EmdIndex.build(corpus, EngineConfig(method="act", iters=2, top_l=4,
@@ -576,8 +575,9 @@ from repro.runtime import elastic
 corpus, _ = make_text_like(n_docs=24, vocab=64, m=8, doc_len=10, hmax=16)
 cfg = EngineConfig(method="act", iters=2, top_l=4, backend="distributed",
                    pad_multiple=8)
-mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh8 = make_mesh((4, 2), ("data", "model"))
+mesh4 = make_mesh((2, 2), ("data", "model"))
 idx8 = EmdIndex.build(corpus, cfg, mesh=mesh8)
 q_ids, q_w = corpus.ids[:5], corpus.w[:5]
 s8, i8 = idx8.search(q_ids, q_w)
@@ -632,7 +632,8 @@ from repro.candidates import CentroidLSHSpec, ClusterTreeSpec
 from repro.cascade import CascadeSpec, CascadeStage
 from repro.data.synth import make_clustered_text
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_clustered_text(90, n_topics=4, vocab=128, m=8, hmax=16,
                                 min_len=8, seed=3)
 q_ids, q_w = corpus.ids[:5], corpus.w[:5]       # odd nq: padded to the mesh
@@ -705,8 +706,9 @@ from repro.serving import EmdServer, ServingPolicy
 corpus, _ = make_text_like(n_docs=24, vocab=64, m=8, doc_len=10, hmax=16)
 cfg = EngineConfig(method="act", iters=2, top_l=4, backend="distributed",
                    pad_multiple=8)
-mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh8 = make_mesh((4, 2), ("data", "model"))
+mesh4 = make_mesh((2, 2), ("data", "model"))
 index = EmdIndex.build(corpus, cfg, mesh=mesh8)
 policy = ServingPolicy(ladder=("primary", "wcd"), max_batch=4,
                        flush_ms=5.0, backoff_ms=0.0, deadline_ms=60_000)

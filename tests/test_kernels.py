@@ -39,7 +39,9 @@ def test_act_phase2_matches_ref(n, hmax, iters, rng):
     zg = jnp.asarray(np.sort(rng.uniform(size=(n, hmax, iters + 1)), axis=-1),
                      jnp.float32)
     wg = jnp.asarray(rng.uniform(size=(n, hmax, iters)) * 0.3, jnp.float32)
-    t = ops.act_phase2(x, zg, wg, block_n=16, block_h=8)
+    # the kernel takes rung-major ladders; the oracle rung-last ones
+    t = ops.act_phase2(x, jnp.moveaxis(zg, -1, 0), jnp.moveaxis(wg, -1, 0),
+                       block_n=16, block_h=8)
     tr = ref.act_phase2_ref(x, zg, wg)
     np.testing.assert_allclose(np.asarray(t), np.asarray(tr)[:, 0],
                                rtol=1e-5, atol=1e-6)
@@ -52,7 +54,7 @@ def test_act_phase2_conserves_mass_cost_bound(rng):
     zg = jnp.asarray(np.sort(rng.uniform(size=(n, hmax, it + 1)), axis=-1),
                      jnp.float32)
     wg = jnp.asarray(rng.uniform(size=(n, hmax, it)), jnp.float32)
-    t = ops.act_phase2(x, zg, wg)
+    t = ops.act_phase2(x, jnp.moveaxis(zg, -1, 0), jnp.moveaxis(wg, -1, 0))
     bound = np.asarray(jnp.sum(x, axis=1)) * float(zg.max())
     assert (np.asarray(t) <= bound + 1e-5).all()
     assert (np.asarray(t) >= 0).all()
@@ -88,7 +90,8 @@ def test_act_phase2_batched_matches_ref(nq, n, hmax, iters, rng):
                      jnp.float32)
     wg = jnp.asarray(rng.uniform(size=(nq, n, hmax, iters)) * 0.3,
                      jnp.float32)
-    t = ops.act_phase2_batched(x, zg, wg, block_n=16, block_h=8)
+    t = ops.act_phase2_batched(x, jnp.moveaxis(zg, -1, 1),
+                               jnp.moveaxis(wg, -1, 1), block_n=16, block_h=8)
     tr = ref.act_phase2_batched_ref(x, zg, wg)
     assert t.shape == (nq, n)
     np.testing.assert_allclose(np.asarray(t), np.asarray(tr), rtol=1e-5,

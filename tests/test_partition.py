@@ -22,7 +22,7 @@ _XLA_FLAGS = " ".join(
 _ENV = dict(os.environ,
             XLA_FLAGS=(_XLA_FLAGS
                        + " --xla_force_host_platform_device_count=8").strip(),
-            PYTHONPATH="src")
+            PYTHONPATH="src", JAX_PLATFORMS="cpu")
 
 
 def _run(script: str):
@@ -45,7 +45,8 @@ import jax.numpy as jnp
 from repro.core import retrieval
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 corpus, _ = make_text_like(n_docs=64, n_classes=4, vocab=96, m=8,
                            doc_len=12, hmax=16, seed=7)
 nq = 16
@@ -77,10 +78,11 @@ print("SHIM PARITY OK")
 
 @pytest.mark.slow
 def test_shim_divisibility_fallback():
-    """Shapes the mesh axes don't divide (odd query count; vocab not a
+    """Shapes the mesh axes don't divide (odd query count; rows not a
     multiple of the model axis) fall back to the non-shim kernel path
     instead of crashing — still bitwise equal to the single-host
-    launch."""
+    launch. A vocabulary the model axis does not divide is padded and
+    sharded."""
     out = _run("""
 import jax, numpy as np
 import jax.numpy as jnp
@@ -88,12 +90,12 @@ from repro.core import retrieval
 from repro.data.synth import make_text_like
 from repro.kernels import partition
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 corpus, _ = make_text_like(n_docs=63, n_classes=4, vocab=90, m=8,
                            doc_len=12, hmax=16, seed=7)
 nq = 5                       # 5 % 2 != 0 -> queries not shardable
 assert not partition.queries_shardable(mesh, nq)
-assert not partition.phase1_shardable(mesh, nq, corpus.v)
 assert not partition.rows_shardable(mesh, nq, corpus.n)
 q_ids, q_w = corpus.ids[:nq], corpus.w[:nq]
 host = np.asarray(retrieval.batch_scores(
@@ -103,11 +105,14 @@ shim = np.asarray(retrieval.batch_scores(
     mesh=mesh))
 np.testing.assert_array_equal(host, shim)
 
-# divisible queries but indivisible vocab/rows: Phase 1 and the pour
-# fall back independently while the candidate shims still shard
-nq = 4
+# divisible queries but indivisible rows: the pour falls back while
+# Phase 1 (padded vocab) and the candidate shims still shard. Eight
+# queries keep each data shard's stacked Phase-1 matmul at 64 columns:
+# XLA:CPU rounds narrower matmuls differently, which would show up
+# here as a 1-ulp mesh-vs-host difference unrelated to the fallback.
+nq = 8
 assert partition.queries_shardable(mesh, nq)
-assert not partition.phase1_shardable(mesh, nq, corpus.v)
+assert not partition.rows_shardable(mesh, nq, corpus.n)
 q_ids, q_w = corpus.ids[:nq], corpus.w[:nq]
 rng = np.random.default_rng(1)
 cand = jnp.asarray(rng.integers(0, corpus.n, size=(nq, 12)), jnp.int32)

@@ -90,21 +90,20 @@ def test_moe_shard_map_matches_constraint_path():
     import subprocess
     import sys
     env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH="src")
+               PYTHONPATH="src", JAX_PLATFORMS="cpu")
     script = """
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs import smoke_config
 from repro.models import model as M
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = smoke_config("mixtral-8x22b")          # E=4 experts over model=4
 cfg_sm = dataclasses.replace(cfg, moe_shard_map=True)
 params = M.init(jax.random.PRNGKey(0), cfg)
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                       cfg.vocab)}
 y_ref, _, _ = M.forward(params, batch, cfg)
-import contextlib
-ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else contextlib.nullcontext()
-with ctx:
+with jax.set_mesh(mesh):
     y_sm = jax.jit(lambda p, b: M.forward(p, b, cfg_sm)[0])(params, batch)
 err = float(jnp.max(jnp.abs(y_ref - y_sm)))
 assert err < 1e-3, err

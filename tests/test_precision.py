@@ -208,9 +208,10 @@ def test_bf16_policy_preserves_topk_agreement(corpus):
 # -------------------------------------------------- VMEM layout halving
 
 def test_block_layouts_halve_storage_slabs_under_bf16():
-    """The static VMEM model reflects the policy: storage-role buffers
-    (Z ladder, gathered ladders, candidate distance table) are exactly
-    half as large under bf16, while index/accumulator buffers hold."""
+    """The static VMEM model reflects the policy: the Z-ladder storage
+    block is exactly half as large under bf16, and a candidate table in
+    storage dtype rides as one exact bf16 part instead of float32's
+    three, while index/accumulator buffers hold."""
     dims = dict(nq=8, v=2048, h=64, m=32, k=8)
     f32 = kops.block_layout("dist_topk", **dims)
     bf = kops.block_layout("dist_topk", **dims, dtype="bfloat16")
@@ -218,10 +219,12 @@ def test_block_layouts_halve_storage_slabs_under_bf16():
     assert bf.buffer("s").nbytes == f32.buffer("s").nbytes
     assert bf.vmem_bytes() < f32.vmem_bytes()
 
-    cdims = dict(nq=8, b=256, h=64, v=2048, k=8, iters=3, block_n=64)
-    f32 = kops.block_layout("cand_pour", **cdims)
-    bf = kops.block_layout("cand_pour", **cdims, dtype="bfloat16")
-    assert bf.buffer("table").nbytes * 2 == f32.buffer("table").nbytes
+    cdims = dict(nq=8, b=256, h=64, v=2048, qh=64, mode="rev_min",
+                 block_n=64)
+    f32 = kops.block_layout("cand_dist", **cdims)
+    bf = kops.block_layout("cand_dist", **cdims, dtype="bfloat16")
+    assert bf.buffer("table").nbytes * 3 == f32.buffer("table").nbytes
+    assert bf.buffer("acc").nbytes == f32.buffer("acc").nbytes
     assert bf.vmem_bytes() < f32.vmem_bytes()
 
 
@@ -355,7 +358,7 @@ def test_distributed_backend_policy_parity(policy, atol):
     xla = " ".join(
         f for f in os.environ.get("XLA_FLAGS", "").split()
         if not f.startswith("--xla_force_host_platform_device_count"))
-    env = dict(os.environ, PYTHONPATH="src",
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
                XLA_FLAGS=(xla
                           + " --xla_force_host_platform_device_count=8")
                .strip())
@@ -364,7 +367,8 @@ import dataclasses, jax, numpy as np
 from repro.api import EmdIndex, EngineConfig
 from repro.data.synth import make_text_like
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 corpus, _ = make_text_like(n_docs=24, vocab=64, m=8, doc_len=10, hmax=16)
 q_ids, q_w = corpus.ids[:5], corpus.w[:5]
 cfg = EngineConfig(method="act", iters=2, backend="distributed",

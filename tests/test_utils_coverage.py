@@ -88,3 +88,23 @@ def test_search_step_single_device_matches_engine():
         np.testing.assert_allclose(np.asarray(scores[u]), np.asarray(-neg),
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_array_equal(np.asarray(idx[u]), np.asarray(ridx))
+
+
+def test_compile_cache_placement(tmp_path, monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    otherwise the cache goes to the fixed ``.jax_cache`` under the root."""
+    import jax
+
+    from repro.launch.compile_cache import CACHE_DIRNAME, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "op"))
+        assert enable_compile_cache(tmp_path) == str(tmp_path / "op")
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(tmp_path.resolve() / CACHE_DIRNAME)
+        assert enable_compile_cache(tmp_path) == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
