@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api.config import EngineConfig
-from repro.core import lc, retrieval
+from repro.core import lc, retrieval, scopes
 from repro.core.lc import Corpus
 
 Array = jax.Array
@@ -211,7 +211,10 @@ class EmdIndex:
         ``(nq, h)`` -> ``(nq, n)``, uniformly across backends. Lower =
         more similar.
         """
-        qi, qw, single = self._check_queries(q_ids, q_w)
+        return self._scores(*self._check_queries(q_ids, q_w))
+
+    def _scores(self, qi: Array, qw: Array, single: bool) -> Array:
+        """:meth:`scores` of checked queries (:meth:`_check_queries`)."""
         if self.config.backend == "distributed":
             s = self._run_dist_step(self._scores_step, qi, qw)
             s = s[:qi.shape[0], :self.n]   # drop pad queries and pad rows
@@ -239,14 +242,25 @@ class EmdIndex:
         survived every pruning stage. On ``backend="distributed"`` the
         mesh cascade step is baked at build time from the config, so the
         spec and ``top_l`` cannot be changed per call there.
+
+        Profiler spans (``core.scopes``): ``emd.search`` around the call,
+        ``emd.search.check`` (input checks), ``emd.search.score`` (the
+        dispatch of the scoring or cascade program) and
+        ``emd.search.topl``; the top-l's device work is ``emd.topl``.
         """
-        top_l = self.config.top_l if top_l is None else top_l
-        cascade = self.config.cascade if cascade is None else cascade
-        if cascade is None:
-            s = self.scores(q_ids, q_w)
-            neg, idx = jax.lax.top_k(-s, top_l)
-            return -neg, idx
-        return self._cascade(q_ids, q_w, top_l, cascade)
+        with jax.profiler.TraceAnnotation(scopes.SEARCH):
+            top_l = self.config.top_l if top_l is None else top_l
+            cascade = self.config.cascade if cascade is None else cascade
+            if cascade is not None:
+                return self._cascade(q_ids, q_w, top_l, cascade)
+            with jax.profiler.TraceAnnotation(scopes.SEARCH + ".check"):
+                checked = self._check_queries(q_ids, q_w)
+            with jax.profiler.TraceAnnotation(scopes.SEARCH + ".score"):
+                s = self._scores(*checked)
+            with (jax.profiler.TraceAnnotation(scopes.SEARCH + ".topl"),
+                  jax.named_scope(scopes.TOPL)):
+                neg, idx = jax.lax.top_k(-s, top_l)
+                return -neg, idx
 
     def _cascade(self, q_ids: Array, q_w: Array, top_l: int,
                  cascade) -> tuple[Array, Array]:
@@ -257,32 +271,35 @@ class EmdIndex:
                 "cascade search scores directionally; this index is "
                 "configured symmetric=True (same rule EngineConfig "
                 "enforces for cascade-in-config)")
-        spec = cascade_mod.resolve_spec(cascade)
-        qi, qw, single = self._check_queries(q_ids, q_w)
-        if self.config.backend == "distributed":
-            if spec != self.config.cascade_spec:
+        distributed = self.config.backend == "distributed"
+        with jax.profiler.TraceAnnotation(scopes.SEARCH + ".check"):
+            spec = cascade_mod.resolve_spec(cascade)
+            qi, qw, single = self._check_queries(q_ids, q_w)
+            if distributed and spec != self.config.cascade_spec:
                 raise ValueError(
                     "the distributed cascade step is baked at build time; "
                     "rebuild with EngineConfig(cascade=...) to change the "
                     "spec")
-            if top_l != self.config.top_l:
+            if distributed and top_l != self.config.top_l:
                 raise ValueError(
                     "the distributed cascade step is jitted for "
                     f"top_l={self.config.top_l}; rebuild with "
                     "EngineConfig(top_l=...) to change it")
-            nq = qi.shape[0]
-            leaves = (jax.tree_util.tree_leaves(self._source)
-                      if self._source is not None else ())
-            scores, idx = self._run_dist_step(self._cascade_step, qi, qw,
-                                              *leaves)
-            scores, idx = scores[:nq], idx[:nq]
-        else:
-            res = cascade_mod.cascade_search(
-                self.corpus, qi, qw, spec, top_l,
-                engine=self.config.batch_engine,
-                source=self._source if spec.sourced else None,
-                **self.config.cascade_knobs())
-            scores, idx = res.scores, res.indices
+        with jax.profiler.TraceAnnotation(scopes.SEARCH + ".score"):
+            if distributed:
+                nq = qi.shape[0]
+                leaves = (jax.tree_util.tree_leaves(self._source)
+                          if self._source is not None else ())
+                scores, idx = self._run_dist_step(self._cascade_step, qi,
+                                                  qw, *leaves)
+                scores, idx = scores[:nq], idx[:nq]
+            else:
+                res = cascade_mod.cascade_search(
+                    self.corpus, qi, qw, spec, top_l,
+                    engine=self.config.batch_engine,
+                    source=self._source if spec.sourced else None,
+                    **self.config.cascade_knobs())
+                scores, idx = res.scores, res.indices
         return (scores[0], idx[0]) if single else (scores, idx)
 
     def all_pairs(self) -> Array:

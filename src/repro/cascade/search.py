@@ -34,7 +34,7 @@ import numpy as np
 
 from repro.cascade import rescore
 from repro.cascade.spec import CascadeSpec, resolve_spec
-from repro.core import lc, retrieval
+from repro.core import lc, retrieval, scopes
 from repro.sharding import annotate
 
 Array = jax.Array
@@ -106,6 +106,15 @@ def _source_budgets(spec: CascadeSpec, budgets: tuple[int, ...],
     return tuple(min(b, width) for b in budgets)
 
 
+def stage_keys(spec: CascadeSpec) -> tuple[str, ...]:
+    """``stage<i>.<method>`` of each pruning stage, then
+    ``rescore.<rescorer>``: the keys of :func:`stage_rows`, and under
+    ``emd.cascade.`` the profiler scope of each stage."""
+    return tuple(f"stage{i + 1}.{s.method}"
+                 for i, s in enumerate(spec.stages)) + (
+        f"rescore.{spec.rescorer}",)
+
+
 def stage_rows(spec: CascadeSpec, n: int, top_l: int) -> dict[str, int]:
     """Rows scored per query by each stage of ``spec`` on an ``n``-row
     corpus: stage 1 reads the full corpus — or, sourced, only the
@@ -118,12 +127,7 @@ def stage_rows(spec: CascadeSpec, n: int, top_l: int) -> dict[str, int]:
         if width is not None:
             prev = min(width, n)
             budgets = _source_budgets(spec, budgets, prev, top_l)
-    rows = {}
-    for i, s in enumerate(spec.stages):
-        rows[f"stage{i + 1}.{s.method}"] = prev
-        prev = budgets[i]
-    rows[f"rescore.{spec.rescorer}"] = prev
-    return rows
+    return dict(zip(stage_keys(spec), (prev, *budgets), strict=True))
 
 
 def _row_order(cand: Array, cmask: Array | None):
@@ -156,34 +160,39 @@ def _prune(corpus: lc.Corpus, Q_ids: Array, Q_w: Array, spec: CascadeSpec,
     than the final budget.
     """
     first = spec.stages[0]
-    if source is None or source.spec.full_scan:
-        s = retrieval.batch_scores(corpus, Q_ids, Q_w, method=first.method,
-                                   iters=first.iters, engine=engine,
-                                   **knobs)
-        _, cand = topk_smallest(lc.mask_pad_rows(s, n_valid), budgets[0],
-                                topk_blocks)
-        cmask = None
-    else:
-        cand, cmask = source.candidates(corpus, Q_ids, Q_w)
-        sc = retrieval.cand_scores(corpus, Q_ids, Q_w, cand,
-                                   method=first.method, iters=first.iters,
-                                   **knobs)
-        sc = jnp.where(cmask, sc, lc.PAD_DIST)
-        _, pos = topk_smallest(sc, budgets[0])
-        cand = jnp.take_along_axis(cand, pos, axis=1)
-        cmask = jnp.take_along_axis(cmask, pos, axis=1)
-    cand, cmask = _row_order(cand, cmask)
-    for stage, b in zip(spec.stages[1:], budgets[1:], strict=True):
-        sc = retrieval.cand_scores(corpus, Q_ids, Q_w, cand,
-                                   method=stage.method, iters=stage.iters,
-                                   **knobs)
-        if cmask is not None:
+    keys = stage_keys(spec)
+    with jax.named_scope(scopes.cascade(keys[0])):
+        if source is None or source.spec.full_scan:
+            s = retrieval.batch_scores(corpus, Q_ids, Q_w,
+                                       method=first.method,
+                                       iters=first.iters, engine=engine,
+                                       **knobs)
+            _, cand = topk_smallest(lc.mask_pad_rows(s, n_valid),
+                                    budgets[0], topk_blocks)
+            cmask = None
+        else:
+            cand, cmask = source.candidates(corpus, Q_ids, Q_w)
+            sc = retrieval.cand_scores(corpus, Q_ids, Q_w, cand,
+                                       method=first.method,
+                                       iters=first.iters, **knobs)
             sc = jnp.where(cmask, sc, lc.PAD_DIST)
-        _, pos = topk_smallest(sc, b)
-        cand = jnp.take_along_axis(cand, pos, axis=1)
-        if cmask is not None:
+            _, pos = topk_smallest(sc, budgets[0])
+            cand = jnp.take_along_axis(cand, pos, axis=1)
             cmask = jnp.take_along_axis(cmask, pos, axis=1)
         cand, cmask = _row_order(cand, cmask)
+    for key, stage, b in zip(keys[1:-1], spec.stages[1:], budgets[1:],
+                             strict=True):
+        with jax.named_scope(scopes.cascade(key)):
+            sc = retrieval.cand_scores(corpus, Q_ids, Q_w, cand,
+                                       method=stage.method,
+                                       iters=stage.iters, **knobs)
+            if cmask is not None:
+                sc = jnp.where(cmask, sc, lc.PAD_DIST)
+            _, pos = topk_smallest(sc, b)
+            cand = jnp.take_along_axis(cand, pos, axis=1)
+            if cmask is not None:
+                cmask = jnp.take_along_axis(cmask, pos, axis=1)
+            cand, cmask = _row_order(cand, cmask)
     return cand, cmask
 
 
@@ -213,12 +222,13 @@ def _cascade_device(corpus: lc.Corpus, Q_ids: Array, Q_w: Array,
                          n_valid=n_valid, topk_blocks=topk_blocks,
                          engine=engine, source=source, **knobs)
     fn = rescore.resolve(spec.rescorer).fn
-    rescored = fn(corpus, Q_ids, Q_w, cand, iters=spec.rescorer_iters,
-                  **knobs)
-    if cmask is not None:
-        rescored = jnp.where(cmask, rescored, lc.PAD_DIST)
-    vals, pos = topk_smallest(rescored, top_l)
-    return CascadeResult(vals, jnp.take_along_axis(cand, pos, axis=1))
+    with jax.named_scope(scopes.cascade(stage_keys(spec)[-1])):
+        rescored = fn(corpus, Q_ids, Q_w, cand, iters=spec.rescorer_iters,
+                      **knobs)
+        if cmask is not None:
+            rescored = jnp.where(cmask, rescored, lc.PAD_DIST)
+        vals, pos = topk_smallest(rescored, top_l)
+        return CascadeResult(vals, jnp.take_along_axis(cand, pos, axis=1))
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "top_l", "n_valid",

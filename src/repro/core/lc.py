@@ -36,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.core.geometry import pairwise_dist
 from repro.core.precision import (matmul_precision, pad_dist_for,
                                   resolve as resolve_precision)
@@ -212,6 +213,7 @@ def take_bins(q_w: Array, S: Array) -> Array:
     return jnp.sum(jnp.where(hit, q_w[..., None, None, :], 0), axis=-1)
 
 
+@scopes.scoped(scopes.PHASE1)
 def phase1(coords: Array, q_ids: Array, q_w: Array, k: int):
     """Phase 1: fused distance + row-top-k against the query.
 
@@ -258,6 +260,7 @@ def stack_query_bins(coords: Array, Q_ids: Array):
     return coords[uniq], inv.reshape(-1)
 
 
+@scopes.scoped(scopes.PHASE1)
 def phase1_stacked_dist(coords: Array, Q_ids: Array, Q_w: Array,
                         precision: str = "f32") -> Array:
     """Stacked Phase-1 distance tensor for the WHOLE query batch: one
@@ -289,6 +292,7 @@ def phase1_stacked_dist(coords: Array, Q_ids: Array, Q_w: Array,
     return D.astype(policy.storage)
 
 
+@scopes.scoped(scopes.PHASE1)
 def phase1_batched(coords: Array, Q_ids: Array, Q_w: Array, k: int,
                    precision: str = "f32"):
     """Batched Phase 1: stacked distance tensor + single-pass top-k.
@@ -315,6 +319,7 @@ def phase1_batched(coords: Array, Q_ids: Array, Q_w: Array, k: int,
     return Zq, W
 
 
+@scopes.scoped(scopes.PHASE1)
 def _min_handoff(D: Array) -> Array:
     """(nq, v) masked-min handoff from the stacked (v, nq, h) Phase-1
     tensor, on the Phase-2 layout (single derivation point, shared by the
@@ -322,6 +327,7 @@ def _min_handoff(D: Array) -> Array:
     return annotate.emd_ladder(jnp.min(D, axis=-1).T)
 
 
+@scopes.scoped(scopes.PHASE1)
 def _rev_handoff(D: Array) -> Array:
     """(nq, v, h) query-major reverse-direction handoff from the stacked
     (v, nq, h) Phase-1 tensor, on the Phase-2 layout (single derivation
@@ -329,6 +335,7 @@ def _rev_handoff(D: Array) -> Array:
     return annotate.emd_ladder(jnp.moveaxis(D, 1, 0))
 
 
+@scopes.scoped(scopes.PHASE1)
 def phase1_min_batched(coords: Array, Q_ids: Array, Q_w: Array,
                        precision: str = "f32") -> Array:
     """Masked-min Phase-1 fast path (LC-RWMD / zero Phase-2 rounds): only
@@ -377,23 +384,27 @@ def lc_act_scores(corpus: Corpus, q_ids: Array, q_w: Array, iters: int = 1,
     k = iters + 1
     if use_kernels:
         from repro.kernels import ops as kops
-        Z, S = kops.dist_topk(corpus.coords, corpus.coords[q_ids], k,
-                              qmask=(q_w > 0.0), block_v=block_v,
-                              block_h=block_h)
-        W = take_bins(q_w, S)
+        with jax.named_scope(scopes.PHASE1):
+            Z, S = kops.dist_topk(corpus.coords, corpus.coords[q_ids], k,
+                                  qmask=(q_w > 0.0), block_v=block_v,
+                                  block_h=block_h)
+            W = take_bins(q_w, S)
     else:
         Z, W = phase1(corpus.coords, q_ids, q_w, k)
-    if iters >= 1 and use_kernels:
-        from repro.kernels import ops as kops
-        # rung-major gathers: (k, n, hmax) / (iters, n, hmax)
-        return kops.act_phase2(corpus.w, Z.T[:, corpus.ids],
-                               W.T[:iters][:, corpus.ids], block_n=block_n,
-                               block_h=block_h)
-    Zg = Z[corpus.ids]                                   # (n, hmax, k)
-    if iters == 0:
-        return jnp.sum(corpus.w * Zg[..., 0], axis=-1)
-    Wg = W[corpus.ids][..., :iters]                      # (n, hmax, iters)
-    return pour(corpus.w, Zg, Wg, iters)
+    with jax.named_scope(scopes.PHASE2):
+        if iters >= 1 and use_kernels:
+            # rung-major gathers: (k, n, hmax) / (iters, n, hmax)
+            with jax.named_scope(scopes.LADDER_GATHER):
+                Zg, Wg = Z.T[:, corpus.ids], W.T[:iters][:, corpus.ids]
+            return kops.act_phase2(corpus.w, Zg, Wg, block_n=block_n,
+                                   block_h=block_h)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = Z[corpus.ids]                           # (n, hmax, k)
+        if iters == 0:
+            return jnp.sum(corpus.w * Zg[..., 0], axis=-1)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Wg = W[corpus.ids][..., :iters]              # (n, hmax, iters)
+        return pour(corpus.w, Zg, Wg, iters)
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernels", "block_v",
@@ -454,19 +465,22 @@ def lc_omr_scores(corpus: Corpus, q_ids: Array, q_w: Array, *,
     """LC-OMR: Algorithm 1 batched over the corpus (top-2 per vocab row)."""
     if use_kernels:
         from repro.kernels import ops as kops
-        Z, S = kops.dist_topk(corpus.coords, corpus.coords[q_ids], 2,
-                              qmask=(q_w > 0.0), block_v=block_v,
-                              block_h=block_h)
-        W = take_bins(q_w, S)
+        with jax.named_scope(scopes.PHASE1):
+            Z, S = kops.dist_topk(corpus.coords, corpus.coords[q_ids], 2,
+                                  qmask=(q_w > 0.0), block_v=block_v,
+                                  block_h=block_h)
+            W = take_bins(q_w, S)
     else:
         Z, W = phase1(corpus.coords, q_ids, q_w, 2)
-    Zg = Z[corpus.ids]                                   # (n, hmax, 2)
-    W0g = W[corpus.ids][..., 0]                          # one gather each
-    x = corpus.w
-    overlap = Zg[..., 0] == 0.0
-    rest = x - jnp.minimum(x, W0g)
-    per_entry = jnp.where(overlap, rest * Zg[..., 1], x * Zg[..., 0])
-    return jnp.sum(per_entry, axis=-1)
+    with jax.named_scope(scopes.PHASE2):
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = Z[corpus.ids]                           # (n, hmax, 2)
+            W0g = W[corpus.ids][..., 0]                  # one gather each
+        x = corpus.w
+        overlap = Zg[..., 0] == 0.0
+        rest = x - jnp.minimum(x, W0g)
+        per_entry = jnp.where(overlap, rest * Zg[..., 1], x * Zg[..., 0])
+        return jnp.sum(per_entry, axis=-1)
 
 
 # --------------------------------------------------------------------------
@@ -541,8 +555,11 @@ def _phase1_batched_dispatch(corpus: Corpus, Q_ids: Array, Q_w: Array,
     ``precision`` threads the policy's compute dtype into the kernel's
     matmul operands and its storage dtype into the handoff ladders
     (``out_dtype`` — the kernel's Z block buffers shrink with it)."""
-    if use_kernels:
-        from repro.kernels import ops as kops
+    if not use_kernels:
+        return phase1_batched(corpus.coords, Q_ids, Q_w, k,
+                              precision=precision)
+    from repro.kernels import ops as kops
+    with jax.named_scope(scopes.PHASE1):
         policy = resolve_precision(precision)
         coords, qcs = corpus.coords, corpus.coords[Q_ids]
         if policy.compute != "float32":
@@ -562,18 +579,21 @@ def _phase1_batched_dispatch(corpus: Corpus, Q_ids: Array, Q_w: Array,
                                       out_dtype=policy.storage)
         W = take_bins(Q_w, S[..., :k - 1]).astype(policy.storage)
         return annotate.emd_ladder(Z), annotate.emd_ladder(W)
-    return phase1_batched(corpus.coords, Q_ids, Q_w, k, precision=precision)
 
 
+@scopes.scoped(scopes.PHASE2)
 def pour_min_blocked(corpus: Corpus, Z0: Array, block_q: int) -> Array:
     """Zero-round Phase 2 on the masked-min handoff: each block of
     ``block_q`` queries gathers its (bq, n, hmax) nearest-distance slice
     once and reduces. Z0: (nq, v) -> (nq, n) scores."""
     def blk(Zb):                                         # (bq, v)
-        return jnp.sum(corpus.w * Zb[:, corpus.ids], axis=-1)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = Zb[:, corpus.ids]
+        return jnp.sum(corpus.w * Zg, axis=-1)
     return _map_query_blocks(blk, (Z0,), Z0.shape[0], block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def pour_blocked(corpus: Corpus, Z: Array, W: Array, iters: int,
                  block_q: int, *, use_kernels: bool = False,
                  block_n: int = 256, block_h: int = 256, mesh=None) -> Array:
@@ -588,7 +608,9 @@ def pour_blocked(corpus: Corpus, Z: Array, W: Array, iters: int,
     x = corpus.w
     if iters == 0:
         def blk0(Zb):                                    # (bq, v, k)
-            return jnp.sum(x * Zb[..., 0][:, corpus.ids], axis=-1)
+            with jax.named_scope(scopes.LADDER_GATHER):
+                Zg = Zb[..., 0][:, corpus.ids]
+            return jnp.sum(x * Zg, axis=-1)
         return _map_query_blocks(blk0, (Z,), nq, block_q)
     W = W[..., :iters]
     if use_kernels:
@@ -602,8 +624,9 @@ def pour_blocked(corpus: Corpus, Z: Array, W: Array, iters: int,
 
         def blk_k(Zb, Wb):
             # rung-major gathers for the kernel: (bq, k, n, hmax)
-            Zg = jnp.swapaxes(Zb, 1, 2)[:, :, corpus.ids]
-            Wg = jnp.swapaxes(Wb, 1, 2)[:, :, corpus.ids]
+            with jax.named_scope(scopes.LADDER_GATHER):
+                Zg = jnp.swapaxes(Zb, 1, 2)[:, :, corpus.ids]
+                Wg = jnp.swapaxes(Wb, 1, 2)[:, :, corpus.ids]
             return kops.act_phase2_batched(x, Zg, Wg, block_n=block_n,
                                            block_h=block_h)
         return _map_query_blocks(blk_k, (Z, W), nq, block_q)
@@ -612,12 +635,14 @@ def pour_blocked(corpus: Corpus, Z: Array, W: Array, iters: int,
         # Gather in storage dtype (half the HBM traffic under bf16),
         # pour in the f32 accumulator dtype (cumsum/clip never run on
         # bf16). Both upcasts are no-ops for the default f32 policy.
-        Zg = _accum(Zb[:, corpus.ids])                   # (bq, n, hmax, k)
-        Wg = _accum(Wb[:, corpus.ids])                   # (bq, n, hmax, iters)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = _accum(Zb[:, corpus.ids])               # (bq, n, hmax, k)
+            Wg = _accum(Wb[:, corpus.ids])               # (bq, n, hmax, iters)
         return pour(x, Zg, Wg, iters)                    # (bq, n)
     return _map_query_blocks(blk, (Z, W), nq, block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def omr_reduce_blocked(corpus: Corpus, Z: Array, W0: Array,
                        block_q: int) -> Array:
     """Query-blocked Algorithm-1 reduction on the top-2 handoff:
@@ -625,8 +650,9 @@ def omr_reduce_blocked(corpus: Corpus, Z: Array, W0: Array,
     x = corpus.w
 
     def blk(Zb, W0b):                                    # (bq, v, 2), (bq, v)
-        Zg = Zb[:, corpus.ids]                           # (bq, n, hmax, 2)
-        W0g = W0b[:, corpus.ids]                         # (bq, n, hmax)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = Zb[:, corpus.ids]                       # (bq, n, hmax, 2)
+            W0g = W0b[:, corpus.ids]                     # (bq, n, hmax)
         overlap = Zg[..., 0] == 0.0
         rest = x - jnp.minimum(x, W0g)
         per_entry = jnp.where(overlap, rest * Zg[..., 1], x * Zg[..., 0])
@@ -634,6 +660,7 @@ def omr_reduce_blocked(corpus: Corpus, Z: Array, W0: Array,
     return _map_query_blocks(blk, (Z, W0), Z.shape[0], block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def rev_min_blocked(corpus: Corpus, Dq: Array, Q_w: Array, block: int,
                     block_q: int) -> Array:
     """Reverse-direction masked (min,+) reduction on the query-major
@@ -658,7 +685,8 @@ def rev_min_blocked(corpus: Corpus, Dq: Array, Q_w: Array, block: int,
     def qblock(Db, Wb):                                  # (bq, v, h), (bq, h)
         def rblock(args):
             ids_blk, valid_blk = args
-            Dg = _accum(Db[:, ids_blk])                  # (bq, b, hmax, h)
+            with jax.named_scope(scopes.LADDER_GATHER):
+                Dg = _accum(Db[:, ids_blk])              # (bq, b, hmax, h)
             Dg = jnp.where(valid_blk[None, ..., None], Dg, big)
             cmin = jnp.min(Dg, axis=2)                   # (bq, b, h)
             return jnp.einsum("qbh,qh->qb", cmin, Wb,
@@ -668,6 +696,7 @@ def rev_min_blocked(corpus: Corpus, Dq: Array, Q_w: Array, block: int,
     return _map_query_blocks(qblock, (Dq, Q_w), Dq.shape[0], block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def rev_min_full(corpus: Corpus, Dq: Array, Q_w: Array,
                  block_q: int) -> Array:
     """Mesh variant of :func:`rev_min_blocked`: no row-blocking ``lax.map``
@@ -679,8 +708,9 @@ def rev_min_full(corpus: Corpus, Dq: Array, Q_w: Array,
     big = _pad_const(acc)
 
     def qblock(Db, Wb):                                  # (bq, v, h), (bq, h)
-        Dg = jnp.where(valid[None, ..., None],
-                       _accum(Db[:, corpus.ids]), big)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Dg = _accum(Db[:, corpus.ids])
+        Dg = jnp.where(valid[None, ..., None], Dg, big)
         cmin = jnp.min(Dg, axis=2)                       # (bq, n, h)
         return jnp.einsum("qnh,qh->qn", cmin, Wb,
                           precision=matmul_precision(cmin.dtype))
@@ -872,6 +902,7 @@ def lc_ict_scores(corpus: Corpus, q_ids: Array, q_w: Array) -> Array:
     return ict_pour(corpus.w, _ict_caps(q_w, C.shape), C)
 
 
+@scopes.scoped(scopes.PHASE2)
 def ict_reduce_blocked(corpus: Corpus, Dq: Array, Q_w: Array,
                        block_q: int) -> Array:
     """Query-blocked Algorithm-2 reduction on the query-major distance
@@ -882,7 +913,8 @@ def ict_reduce_blocked(corpus: Corpus, Dq: Array, Q_w: Array,
         # Gather in storage dtype, sort + pour the ladder in the f32
         # accumulator (the sort itself is exact in any dtype, but the
         # pour's cumulative caps are not).
-        C = _accum(Db[:, corpus.ids])                    # (bq, n, hmax, h)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            C = _accum(Db[:, corpus.ids])                # (bq, n, hmax, h)
         cap = _ict_caps(Wb[:, None, :], C.shape)
         return ict_pour(corpus.w, cap, C)
     return _map_query_blocks(blk, (Dq, Q_w), Dq.shape[0], block_q)
@@ -934,6 +966,7 @@ def gather_per_query(A: Array, idx: Array) -> Array:
     return jax.vmap(lambda a, i: a[i])(A, idx)
 
 
+@scopes.scoped(scopes.PHASE2)
 def pour_min_cand_blocked(corpus: Corpus, Z0: Array, cand: Array,
                           block_q: int, *, use_kernels: bool = False,
                           block_n: int = 128, block_v: int = 256,
@@ -964,11 +997,13 @@ def pour_min_cand_blocked(corpus: Corpus, Z0: Array, cand: Array,
         return _map_query_blocks(blk_k, (Z0, cand), Z0.shape[0], block_q)
 
     def blk(Zb, cb):                                     # (bq, v), (bq, b)
-        Zg = gather_per_query(Zb, corpus.ids[cb])       # (bq, b, hmax)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = gather_per_query(Zb, corpus.ids[cb])   # (bq, b, hmax)
         return jnp.sum(corpus.w[cb] * Zg, axis=-1)
     return _map_query_blocks(blk, (Z0, cand), Z0.shape[0], block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def pour_cand_blocked(corpus: Corpus, Z: Array, W: Array, cand: Array,
                       iters: int, block_q: int, *,
                       use_kernels: bool = False, block_n: int = 128,
@@ -1006,12 +1041,14 @@ def pour_cand_blocked(corpus: Corpus, Z: Array, W: Array, cand: Array,
         ids_g = corpus.ids[cb]                           # (bq, b, hmax)
         # Gather in storage dtype; pour in the f32 accumulator (its
         # capacity cumsum must not round in bf16).
-        Zg = _accum(gather_per_query(Zb, ids_g))        # (bq, b, hmax, k)
-        Wg = _accum(gather_per_query(Wb, ids_g))        # (bq, b, hmax, iters)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = _accum(gather_per_query(Zb, ids_g))    # (bq, b, hmax, k)
+            Wg = _accum(gather_per_query(Wb, ids_g))    # (bq, b, hmax, iters)
         return pour(corpus.w[cb], Zg, Wg, iters)         # (bq, b)
     return _map_query_blocks(blk, (Z, W, cand), nq, block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def omr_reduce_cand_blocked(corpus: Corpus, Z: Array, W0: Array,
                             cand: Array, block_q: int, *,
                             use_kernels: bool = False, block_n: int = 128,
@@ -1041,8 +1078,9 @@ def omr_reduce_cand_blocked(corpus: Corpus, Z: Array, W0: Array,
     def blk(Zb, W0b, cb):
         ids_g = corpus.ids[cb]
         x = corpus.w[cb]                                 # (bq, b, hmax)
-        Zg = gather_per_query(Zb, ids_g)                # (bq, b, hmax, 2)
-        W0g = gather_per_query(W0b, ids_g)              # (bq, b, hmax)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Zg = gather_per_query(Zb, ids_g)            # (bq, b, hmax, 2)
+            W0g = gather_per_query(W0b, ids_g)          # (bq, b, hmax)
         overlap = Zg[..., 0] == 0.0
         rest = x - jnp.minimum(x, W0g)
         per_entry = jnp.where(overlap, rest * Zg[..., 1], x * Zg[..., 0])
@@ -1050,6 +1088,7 @@ def omr_reduce_cand_blocked(corpus: Corpus, Z: Array, W0: Array,
     return _map_query_blocks(blk, (Z, W0, cand), Z.shape[0], block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def rev_min_cand_blocked(corpus: Corpus, Dq: Array, Q_w: Array,
                          cand: Array, block_q: int, *,
                          use_kernels: bool = False, block_n: int = 128,
@@ -1086,7 +1125,8 @@ def rev_min_cand_blocked(corpus: Corpus, Dq: Array, Q_w: Array,
     def blk(Db, Wb, cb):                                 # (bq, v, h), (bq, h)
         ids_g = corpus.ids[cb]                           # (bq, b, hmax)
         valid = corpus.w[cb] > 0.0
-        Dg = _accum(gather_per_query(Db, ids_g))        # (bq, b, hmax, h)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            Dg = _accum(gather_per_query(Db, ids_g))    # (bq, b, hmax, h)
         Dg = jnp.where(valid[..., None], Dg, big)
         cmin = jnp.min(Dg, axis=2)                       # (bq, b, h)
         # multiply + last-axis reduce, NOT einsum: the dot op's
@@ -1097,6 +1137,7 @@ def rev_min_cand_blocked(corpus: Corpus, Dq: Array, Q_w: Array,
     return _map_query_blocks(blk, (Dq, Q_w, cand), Dq.shape[0], block_q)
 
 
+@scopes.scoped(scopes.PHASE2)
 def ict_reduce_cand_blocked(corpus: Corpus, Dq: Array, Q_w: Array,
                             cand: Array, block_q: int, *,
                             use_kernels: bool = False, block_n: int = 128,
@@ -1129,7 +1170,8 @@ def ict_reduce_cand_blocked(corpus: Corpus, Dq: Array, Q_w: Array,
     def blk(Db, Wb, cb):
         ids_g = corpus.ids[cb]
         # Gather in storage dtype; ladder pour in the f32 accumulator.
-        C = _accum(gather_per_query(Db, ids_g))         # (bq, b, hmax, h)
+        with jax.named_scope(scopes.LADDER_GATHER):
+            C = _accum(gather_per_query(Db, ids_g))     # (bq, b, hmax, h)
         cap = _ict_caps(Wb[:, None, :], C.shape)
         return ict_pour(corpus.w[cb], cap, C)
     return _map_query_blocks(blk, (Dq, Q_w, cand), Dq.shape[0], block_q)

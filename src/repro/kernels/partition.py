@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import lc
+from repro.core import lc, scopes
 from repro.kernels import ops as kops
 from repro.launch.mesh import data_axes, model_axis_size
 
@@ -164,8 +164,9 @@ def act_pour_sharded(mesh, ids, w, Z, W, iters: int, *, block_q: int = 8,
                else jax.lax.bitcast_convert_type(W_l, wdt))
 
         def blk(Zb, Wb):
-            Zg = jnp.swapaxes(Zb, 1, 2)[:, :, ids_l]     # (bq, k, n/sh, hmax)
-            Wg = jnp.swapaxes(Wb, 1, 2)[:, :, ids_l]
+            with jax.named_scope(scopes.LADDER_GATHER):
+                Zg = jnp.swapaxes(Zb, 1, 2)[:, :, ids_l]  # (bq, k, n/sh, hmax)
+                Wg = jnp.swapaxes(Wb, 1, 2)[:, :, ids_l]
             return kops.act_phase2_batched(w_l, Zg, Wg, block_n=block_n,
                                            block_h=block_h)
         return lc._map_query_blocks(blk, (Z_l, W_l), Z_l.shape[0], block_q)

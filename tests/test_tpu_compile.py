@@ -119,3 +119,69 @@ def test_cand_kernel_compiles_under_highest_precision(one_chip):
         _compile(lambda *a: cand_pallas(*a, mode="pour", k=4, iters=3,
                                         block_n=128, block_v=256),
                  one_chip, *shapes)
+
+
+@pytest.fixture
+def mosaic_kernels(monkeypatch):
+    """The engines' Pallas kernels compiled by Mosaic, not interpreted,
+    though this process's backend is the CPU. Traces cached before or
+    after in this process are dropped, so none mixes the two."""
+    from repro.kernels import ops
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    yield
+    jax.clear_caches()
+
+
+def _kernel_scopes(hlo: str) -> dict[str, set]:
+    """The emd. scope paths of each Pallas kernel's custom calls, and of
+    the fusions (under ``"fusion"``)."""
+    import re
+    out = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([a-z_0-9]+?)(?:\.\d+)? = ", line)
+        on = re.search(r'op_name="([^"]*)"', line)
+        kernel = 'custom_call_target="tpu_custom_call"' in line
+        if m and on and (kernel or re.search(r"[\]})] fusion\(", line)):
+            path = tuple(re.findall(r"(?<![\w.])emd\.[\w.]*\w", on.group(1)))
+            out.setdefault(m.group(1) if kernel else "fusion", set()).add(path)
+    return out
+
+
+def test_compiled_kernels_carry_layer_scopes(one_chip, mosaic_kernels):
+    """On the chip the kernels are custom calls: each carries its layer's
+    scope (Phase 1, the pour, the cascade stage it scores for), where the
+    benchmark's trace reduction reads it."""
+    from repro.cascade import CascadeSpec, CascadeStage
+    from repro.cascade import search as cascade_search
+    from repro.core import lc
+
+    n, v, hmax, m = 512, 512, 64, 8
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((n, hmax), jnp.int32), ((n, hmax), jnp.float32),
+        ((v, m), jnp.float32), ((NQ, hmax), jnp.int32),
+        ((NQ, hmax), jnp.float32))]
+    kw = dict(use_kernels=True, block_q=4, block_v=256, block_h=256)
+
+    def batched(ids, w, coords, q, qw):
+        return lc.lc_act_scores_batched(lc.Corpus(ids, w, coords), q, qw,
+                                        iters=3, block_n=256, **kw)
+
+    spec = CascadeSpec(stages=(CascadeStage("wcd", 0.4),
+                               CascadeStage("rwmd", 0.1)),
+                       rescorer="act", rescorer_iters=3)
+
+    def cascade(ids, w, coords, q, qw):
+        return cascade_search._cascade_device(
+            lc.Corpus(ids, w, coords), q, qw, spec, 16, block_n=128, **kw)
+
+    got = _kernel_scopes(jax.jit(batched).lower(*args).compile().as_text())
+    # The ladder gather runs as fusions of its own, ahead of the pour.
+    assert ("emd.phase2", "emd.ladder_gather") in got.pop("fusion")
+    assert got == {"dist_topk_pallas": {("emd.phase1",)},
+                   "act_phase2_pallas": {("emd.phase2",)}}
+    got = _kernel_scopes(jax.jit(cascade).lower(*args).compile().as_text())
+    got.pop("fusion")
+    assert got == {"cand_pallas": {("emd.cascade.stage2.rwmd", "emd.phase2"),
+                                   ("emd.cascade.rescore.act",
+                                    "emd.phase2")}}
