@@ -55,6 +55,8 @@ class EmdIndex:
     _cascade_step: Any = None
     _tuned: Any = None
     _source: Any = None
+    _segments: lc.Segments | None = None
+    _gather_fill_pct: float | None = None
 
     def __repr__(self) -> str:
         mesh = "" if self._mesh is None else f", mesh={dict(self._mesh.shape)}"
@@ -86,6 +88,12 @@ class EmdIndex:
         configs under ``"force"``); the applied picks are recorded on
         :attr:`tuned_blocks` and the jitted steps below compile with
         them baked in.
+
+        Where the scores come from the single-device kernel pour (batched
+        LC-ACT with at least one round on ``backend="pallas"``, no
+        cascade), the segmented row layout that pour gathers from
+        (``lc.segment_rows``) is derived here too, once per build; its
+        fill is :attr:`gather_fill_pct`.
         """
         config = EngineConfig() if config is None else config
         tuned: dict = {}
@@ -105,8 +113,15 @@ class EmdIndex:
         if config.backend != "distributed":
             if source is not None:
                 source = jax.device_put(source)
+            segments = fill = None
+            if (config.backend == "pallas" and config.method == "act"
+                    and config.effective_iters > 0
+                    and config.batch_engine == "batched"
+                    and config.cascade is None):
+                segments, fill = lc.segment_rows(corpus, config.block_n)
             return cls(corpus=jax.device_put(corpus), config=config,
-                       _tuned=tuned, _source=source)
+                       _tuned=tuned, _source=source, _segments=segments,
+                       _gather_fill_pct=fill)
 
         from repro.configs.emd_20news import EMDWorkload
         from repro.launch import mesh as mesh_mod
@@ -173,6 +188,12 @@ class EmdIndex:
         timings)."""
         return dict(self._tuned or {})
 
+    @property
+    def gather_fill_pct(self) -> float | None:
+        """Real bins over the slots the kernel pour's ladder gather reads,
+        in %, where the index built the segmented layout; else ``None``."""
+        return self._gather_fill_pct
+
     # ------------------------------------------------------------ scoring
     @staticmethod
     def _check_queries(q_ids: Array, q_w: Array) -> tuple[Array, Array,
@@ -227,7 +248,8 @@ class EmdIndex:
                                           **kw)
         return retrieval.batch_scores(self.corpus, q_ids, q_w,
                                       symmetric=self.config.symmetric,
-                                      engine=self.config.batch_engine, **kw)
+                                      engine=self.config.batch_engine,
+                                      segments=self._segments, **kw)
 
     def search(self, q_ids: Array, q_w: Array, top_l: int | None = None, *,
                cascade=None) -> tuple[Array, Array]:

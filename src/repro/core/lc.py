@@ -23,6 +23,13 @@ dense X of the paper never hit HBM at production sizes (see
 this module is the readable pjit-able reference engine that the kernels are
 validated against).
 
+The single-device kernel pour of the batched engine can read a second,
+segmented copy of the rows instead (:class:`Segments`, built once by
+``EmdIndex.build``): only the lane-wide segments that hold real bins, so
+its ladder gather skips the padding slots, which pour exactly 0. The
+reference branch, the mesh path and every other consumer gather from the
+padded layout.
+
 NOTE (serving callers): prefer ``repro.api.EmdIndex`` — these engines are
 the thin compute layer behind its ``backend="reference"``/``"pallas"``
 paths; calling them directly bypasses batching, symmetric scoring, and
@@ -35,6 +42,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import scopes
 from repro.core.geometry import pairwise_dist
@@ -73,6 +81,67 @@ class Corpus:
     @property
     def m(self) -> int:
         return self.coords.shape[1]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Segments:
+    """Segmented row layout that the kernel pour's ladder gather reads.
+
+    Each row's real bins (weight > 0), in their order in the row, are cut
+    into segments of ``width`` slots; only those segments are stored, so
+    the gather skips the padding the dense-bucket layout carries.
+
+    ids:  (S, width) int32 vocabulary indices; unused slots carry id 0.
+    w:    (S, width) float32 weights (unused slots and padding segments
+          weigh 0). Rows keep their order and each row's segments are
+          contiguous; S is padded with zero segments to the pour's row
+          block, so the kernel launch pads nothing.
+    rows: (n, c) int32 segment indices of each row, c = the most segments
+          any row has; an entry of S (out of range) marks no segment.
+    """
+    ids: Array
+    w: Array
+    rows: Array
+
+    @property
+    def width(self) -> int:
+        return self.ids.shape[1]
+
+
+def segment_rows(corpus: Corpus, block_n: int) -> tuple[Segments, float]:
+    """Derive the :class:`Segments` layout of ``corpus`` on the host.
+
+    The segment width is the TPU lane width, or the row width rounded to
+    a sublane where rows are narrower: ``min(128, round_up(hmax, 8))``.
+    A row of L real bins takes ceil(L / width) segments and a row with
+    none takes none. ``block_n`` is the pour kernel's row tile.
+
+    Returns the layout and its fill: real bins over gathered slots, in %.
+    """
+    from repro.kernels.tiling import LANE, SUBLANE, round_up
+
+    ids, w = np.asarray(corpus.ids), np.asarray(corpus.w)
+    n, hmax = ids.shape
+    width = min(LANE, round_up(hmax, SUBLANE))
+    r, j = np.nonzero(w > 0.0)           # row-major: each row's bins in order
+    lengths = np.bincount(r, minlength=n)
+    counts = -(-lengths // width)
+    s_real = int(counts.sum())
+    block = min(block_n, round_up(max(s_real, 1), SUBLANE))
+    s = round_up(max(s_real, 1), block)
+    first = np.cumsum(counts) - counts
+    p = np.arange(r.size) - (np.cumsum(lengths) - lengths)[r]  # place in row
+    seg, lane = first[r] + p // width, p % width
+    seg_ids = np.zeros((s, width), ids.dtype)
+    seg_w = np.zeros((s, width), w.dtype)
+    seg_ids[seg, lane] = ids[r, j]
+    seg_w[seg, lane] = w[r, j]
+    k = np.arange(max(int(counts.max(initial=0)), 1))
+    rows = np.where(k < counts[:, None], first[:, None] + k, s)
+    fill = 100.0 * r.size / (s * width)
+    return Segments(ids=jnp.asarray(seg_ids), w=jnp.asarray(seg_w),
+                    rows=jnp.asarray(rows, jnp.int32)), fill
 
 
 #: Finite sentinel for padding query slots. Large enough never to be chosen
@@ -596,14 +665,22 @@ def pour_min_blocked(corpus: Corpus, Z0: Array, block_q: int) -> Array:
 @scopes.scoped(scopes.PHASE2)
 def pour_blocked(corpus: Corpus, Z: Array, W: Array, iters: int,
                  block_q: int, *, use_kernels: bool = False,
-                 block_n: int = 256, block_h: int = 256, mesh=None) -> Array:
+                 block_n: int = 256, block_h: int = 256, mesh=None,
+                 segments: Segments | None = None) -> Array:
     """Query-blocked Phase 2/3 pour: (nq, v, k) handoff ladders ->
     (nq, n) lower bounds. Each block of ``block_q`` queries gathers its
     (bq, n, hmax, k) cost/capacity ladders once and pours (fused Pallas
     kernel when ``use_kernels``); ``iters=0`` degenerates to the
     nearest-cost dump of Phase 3. On a ``mesh`` whose axes divide, the
     kernel path runs inside a ``shard_map`` shim with the query blocking
-    per shard (queries over DP, database rows over "model")."""
+    per shard (queries over DP, database rows over "model").
+
+    Given ``segments`` (the corpus's :class:`Segments`), the single-device
+    kernel path gathers from that layout instead: (bq, k, S, width)
+    ladders for the real bins' segments only, poured at ``block_h`` =
+    the segment width into (bq, S) segment costs, which are summed into
+    rows. The reference branch and ``iters=0`` always read the padded
+    (n, hmax) rows."""
     nq = Z.shape[0]
     x = corpus.w
     if iters == 0:
@@ -621,6 +698,21 @@ def pour_blocked(corpus: Corpus, Z: Array, W: Array, iters: int,
                 return partition.act_pour_sharded(
                     mesh, corpus.ids, corpus.w, Z, W, iters,
                     block_q=block_q, block_n=block_n, block_h=block_h)
+
+        if segments is not None:
+            def blk_s(Zb, Wb):
+                # rung-major gathers over segments: (bq, k, S, width)
+                with jax.named_scope(scopes.LADDER_GATHER):
+                    Zg = jnp.swapaxes(Zb, 1, 2)[:, :, segments.ids]
+                    Wg = jnp.swapaxes(Wb, 1, 2)[:, :, segments.ids]
+                return kops.act_phase2_batched(segments.w, Zg, Wg,
+                                               block_n=block_n,
+                                               block_h=segments.width)
+            t = _map_query_blocks(blk_s, (Z, W), nq, block_q)   # (nq, S)
+            # (nq, n, c) segment costs of each row, once for the whole
+            # batch; no segment reads 0
+            return jnp.take(t, segments.rows, axis=1, mode="fill",
+                            fill_value=0.0).sum(axis=-1)
 
         def blk_k(Zb, Wb):
             # rung-major gathers for the kernel: (bq, k, n, hmax)
@@ -727,13 +819,16 @@ def lc_act_scores_batched(corpus: Corpus, Q_ids: Array, Q_w: Array,
                           iters: int = 1, *, use_kernels: bool = False,
                           block_q: int = 8, block_v: int = 256,
                           block_h: int = 256, block_n: int = 256,
-                          mesh=None, precision: str = "f32") -> Array:
+                          mesh=None, precision: str = "f32",
+                          segments: Segments | None = None) -> Array:
     """Batched LC-ACT: (nq, h) query batch -> (nq, n) lower bounds
     (stage-1 ranked Phase 1 composed with the query-blocked pour).
     ``mesh`` (static, hashable) routes the kernel path through the
     ``kernels/partition`` shard_map shims when its axes divide;
     ``precision`` (static policy name) sets the handoff storage / matmul
-    compute dtypes — reductions always accumulate in float32."""
+    compute dtypes — reductions always accumulate in float32.
+    ``segments``: the corpus's segmented layout, which the single-device
+    kernel pour gathers from (:func:`pour_blocked`)."""
     if iters == 0 and not use_kernels:
         Z0 = phase1_min_batched(corpus.coords, Q_ids, Q_w,
                                 precision=precision)
@@ -743,7 +838,7 @@ def lc_act_scores_batched(corpus: Corpus, Q_ids: Array, Q_w: Array,
                                     precision=precision)
     return pour_blocked(corpus, Z, W, iters, block_q,
                         use_kernels=use_kernels, block_n=block_n,
-                        block_h=block_h, mesh=mesh)
+                        block_h=block_h, mesh=mesh, segments=segments)
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernels", "block_q",

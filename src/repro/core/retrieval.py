@@ -270,12 +270,12 @@ def _act(corpus, q_ids, q_w, *, iters=1, use_kernels=False, block_v=256,
 @_register_batch("act")
 def _act_batch(corpus, q_ids, q_w, *, iters=1, use_kernels=False,
                block_v=256, block_h=256, block_n=256, block_q=8, mesh=None,
-               precision="f32", **_):
+               precision="f32", segments=None, **_):
     return lc.lc_act_scores_batched(corpus, q_ids, q_w, iters=iters,
                                     use_kernels=use_kernels, block_q=block_q,
                                     block_v=block_v, block_h=block_h,
                                     block_n=block_n, mesh=mesh,
-                                    precision=precision)
+                                    precision=precision, segments=segments)
 
 
 @_register_cand("act")
@@ -446,7 +446,8 @@ def batch_scores(corpus: lc.Corpus, q_ids: Array, q_w: Array, *,
                  use_kernels: bool = False, block_v: int = 256,
                  block_h: int = 256, block_n: int = 256,
                  rev_block: int = 256, block_q: int = 8, mesh=None,
-                 precision: str = "f32") -> Array:
+                 precision: str = "f32",
+                 segments: lc.Segments | None = None) -> Array:
     """Batch of queries ``(nq, h)`` -> ``(nq, n)`` score matrix.
 
     ``engine="batched"`` (default) dispatches to the method's multi-query
@@ -464,7 +465,9 @@ def batch_scores(corpus: lc.Corpus, q_ids: Array, q_w: Array, *,
     fallback that runs each query through the exact single-query compute
     graph via ``lax.map``, matching a Python loop of ``query_scores``
     calls bit-for-bit; use it to verify the batched engine or on methods
-    without a registered ``batch_fn``.
+    without a registered ``batch_fn``. ``segments``: the corpus's
+    segmented row layout (``lc.segment_rows``), read by the batched ACT
+    kernel pour only.
     """
     if engine not in ("batched", "scan", "dist"):
         raise ValueError(f"unknown engine {engine!r}; "
@@ -476,7 +479,8 @@ def batch_scores(corpus: lc.Corpus, q_ids: Array, q_w: Array, *,
                 else s.batch_fn
         kw = dict(iters=iters, use_kernels=use_kernels, block_v=block_v,
                   block_h=block_h, block_n=block_n, rev_block=rev_block,
-                  block_q=block_q, mesh=mesh, precision=precision)
+                  block_q=block_q, mesh=mesh, precision=precision,
+                  segments=segments)
         if symmetric and not spec.symmetric:
             if spec.reverse is None:
                 raise ValueError(

@@ -12,6 +12,7 @@ import os
 import re
 
 import jax
+import numpy as np
 import pytest
 
 from repro.api import EmdIndex, EngineConfig
@@ -77,6 +78,35 @@ def test_batched_engine_layers(corpus, use_kernels):
     # Phase 1 never runs inside Phase 2, nor Phase 2 inside Phase 1.
     for _, _, p in ops:
         assert not (scopes.PHASE1 in p and scopes.PHASE2 in p), p
+
+
+def test_segmented_pour_layers():
+    """Over the segmented row layout, the segment ladder gathers still sit
+    under ``(emd.phase2, emd.ladder_gather)``, and the sum of segment
+    costs into rows (one more gather) is ``emd.phase2``'s own."""
+    rng = np.random.default_rng(5)
+    n, hmax, v = 24, 200, 96
+    real = np.arange(hmax) < rng.integers(1, hmax + 1, (n, 1))
+    wide = lc.Corpus(ids=rng.integers(0, v, (n, hmax)).astype(np.int32),
+                     w=(real / real.sum(1, keepdims=True)).astype(np.float32),
+                     coords=rng.normal(size=(v, 8)).astype(np.float32))
+    segments, _ = lc.segment_rows(wide, 8)
+    assert segments.rows.shape[1] > 1            # rows of two segments
+    q, w = wide.ids[:5], wide.w[:5]
+    kw = dict(iters=3, use_kernels=True, block_q=2, block_v=32, block_h=8,
+              block_n=8)
+    gathers = {}
+    for seg in (None, segments):
+        ops = op_scopes(lc.lc_act_scores_batched.lower(
+            wide, q, w, segments=seg, **kw).compile())
+        gathers[seg is not None] = {p for _, kind, p in ops
+                                    if kind == "gather" and scopes.PHASE2
+                                    in p}
+        for _, _, p in ops:
+            assert not (scopes.PHASE1 in p and scopes.PHASE2 in p), p
+    assert gathers[False] == {(scopes.PHASE2, scopes.LADDER_GATHER)}
+    assert gathers[True] == {(scopes.PHASE2, scopes.LADDER_GATHER),
+                             (scopes.PHASE2,)}
 
 
 @pytest.mark.parametrize("use_kernels", [False, True])

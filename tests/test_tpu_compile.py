@@ -78,6 +78,25 @@ def test_act_phase2_compiles(one_chip, width):
              ((nq, w.iters, np_, hp), jnp.float32))
 
 
+#: Segments S of the segmented row layout (``core.lc.segment_rows``) at
+#: the deployments' widths: 128-lane segments of the real bins of each
+#: row, from the benchmark generators' length multisets (20News: 71.1 real
+#: bins per row over 18,828 rows; MNIST: 150 lit pixels over 15,000),
+#: rounded up to the pour's 256-row block.
+SEGMENTS = {"20news": 22_272, "mnist": 25_600}
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_act_phase2_segments_compile(one_chip, width):
+    """The pour over the segmented layout: (S, 128) rows at block_h 128."""
+    w, s = WIDTHS[width], SEGMENTS[width]
+    _compile(lambda x, zg, wg: act_phase2_pallas(x, zg, wg, block_n=256,
+                                                 block_h=LANE),
+             one_chip, ((s, LANE), jnp.float32),
+             ((NQ, w.iters + 1, s, LANE), jnp.float32),
+             ((NQ, w.iters, s, LANE), jnp.float32))
+
+
 #: mode -> (block_n, rows kwargs): the cascade's default row tile for the
 #: narrow ladders, the smallest legal one where the rows are query bins.
 CAND_MODES = {
@@ -185,3 +204,28 @@ def test_compiled_kernels_carry_layer_scopes(one_chip, mosaic_kernels):
     assert got == {"cand_pallas": {("emd.cascade.stage2.rwmd", "emd.phase2"),
                                    ("emd.cascade.rescore.act",
                                     "emd.phase2")}}
+
+
+def test_compiled_segmented_pour_carries_layer_scopes(one_chip,
+                                                      mosaic_kernels):
+    """Over the segmented row layout the pour kernel keeps its scope and
+    the segment ladder gathers theirs, compiled for the chip."""
+    from repro.core import lc
+
+    n, v, hmax, m, s = 512, 512, 300, 8, 1024
+    args = [jax.ShapeDtypeStruct(sh, d, sharding=one_chip) for sh, d in (
+        ((n, hmax), jnp.int32), ((n, hmax), jnp.float32),
+        ((v, m), jnp.float32), ((NQ, hmax), jnp.int32),
+        ((NQ, hmax), jnp.float32), ((s, LANE), jnp.int32),
+        ((s, LANE), jnp.float32), ((n, 3), jnp.int32))]
+
+    def batched(ids, w, coords, q, qw, seg_ids, seg_w, rows):
+        return lc.lc_act_scores_batched(
+            lc.Corpus(ids, w, coords), q, qw, iters=3, use_kernels=True,
+            block_q=4, block_v=256, block_h=256, block_n=256,
+            segments=lc.Segments(seg_ids, seg_w, rows))
+
+    got = _kernel_scopes(jax.jit(batched).lower(*args).compile().as_text())
+    assert ("emd.phase2", "emd.ladder_gather") in got.pop("fusion")
+    assert got == {"dist_topk_pallas": {("emd.phase1",)},
+                   "act_phase2_pallas": {("emd.phase2",)}}
