@@ -199,8 +199,17 @@ class Run:
         d = self.data
         self.index = EmdIndex.build(Corpus(ids=d.ids, w=d.w,
                                            coords=d.coords),
-                                    EngineConfig(**e))
+                                    EngineConfig(**e), mesh=self.mesh(e))
         return self.index
+
+    def mesh(self, engine: dict):
+        """The distributed backend's mesh over the cell's own chips, rows
+        over ``model`` (data 1 x model ``chips``); None for the
+        single-device backends."""
+        if engine.get("backend") != "distributed":
+            return None
+        from repro.launch.mesh import make_mesh
+        return make_mesh((1, self.cell.chips), ("data", "model"))
 
     def span(self, name: str):
         import jax

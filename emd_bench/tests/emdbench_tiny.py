@@ -20,7 +20,8 @@ TEXT = dict(n=300, v=512, m=16, hmax=64)
 TEXT_GEN = dict(chunk=64, mean_words=12.0)
 IMAGE = dict(n=300)
 IMAGE_GEN = dict(chunk=64)
-TRAFFIC = {"batch": dict(pool=32, check_sample=8)}
+TRAFFIC = {"batch": dict(pool=32, check_sample=8),
+           "serve": dict(pool=32, rate_qps=8.0, max_batch=4)}
 
 
 def bench() -> dict:

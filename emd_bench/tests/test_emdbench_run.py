@@ -14,7 +14,8 @@ import emdbench_tiny as tiny
 
 from emd_bench import check, control
 
-CELLS = ["news-act7-batch", "mnist-act7-batch", "news-fast-cascade"]
+CELLS = ["news-act7-batch", "mnist-act7-batch", "news-fast-cascade",
+         "news-serve-poisson"]
 
 
 def _command(cwd, *args):
@@ -73,7 +74,8 @@ FAULTS = [("news-act7-batch", _alter_one_answer),
           ("mnist-act7-batch", _alter_one_answer),
           ("mnist-act7-batch", _drop_half_the_batch),
           ("news-fast-cascade", _alter_one_answer),
-          ("news-fast-cascade", _drop_half_the_batch)]
+          ("news-fast-cascade", _drop_half_the_batch),
+          ("news-serve-poisson", _alter_one_answer)]
 
 
 @pytest.mark.parametrize("name,fault", FAULTS,
