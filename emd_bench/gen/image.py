@@ -16,7 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from emd_bench.gen.common import Data, normal_sizes, seed_key, shuffled
+from emd_bench.gen.common import (Data, make_rows, normal_sizes, seed_key,
+                                  shuffled)
 
 
 def grid_coords(side: int) -> jax.Array:
@@ -35,17 +36,11 @@ def _segment_dist2(p, a, b):
     return jnp.sum(d * d, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("side", "hmax", "chunk"))
-def _images(key, protos, lengths, *, side, hmax, chunk, jitter, blur):
-    rows = lengths.shape[0]
-    classes = protos.shape[0]
-    kl, kj = jax.random.split(key)
-    labels = jax.random.randint(kl, (rows,), 0, classes)
-    pad = -rows % chunk
-    labels = jnp.pad(labels, (0, pad)).reshape(-1, chunk)
-    lens = jnp.pad(lengths, (0, pad)).reshape(-1, chunk)
+def _chunks(kj, first, protos, labels, lens, *, side, hmax, jitter, blur):
+    """(rows, hmax) ids and weights of the chunks ``first``,
+    ``first + 1``, ...: chunk i jitters from ``fold_in(kj, i)`` alone."""
     keys = jax.vmap(lambda i: jax.random.fold_in(kj, i))(
-        jnp.arange(labels.shape[0]))
+        first + jnp.arange(labels.shape[0]))
     grid = grid_coords(side)
     v = side * side
 
@@ -64,11 +59,24 @@ def _images(key, protos, lengths, *, side, hmax, chunk, jitter, blur):
 
     assert hmax <= v, (hmax, v)
     ids, w = jax.lax.map(one, (keys, labels, lens))
-    return ids.reshape(-1, hmax)[:rows], w.reshape(-1, hmax)[:rows]
+    return ids.reshape(-1, hmax), w.reshape(-1, hmax)
 
 
-def make(cfg: dict, seed: int, pool: int) -> Data:
-    """The configuration's images and ``pool`` held-out query images."""
+@functools.partial(jax.jit, static_argnames=("side", "hmax", "chunk",
+                                             "mesh"))
+def _images(key, protos, lengths, *, side, hmax, chunk, jitter, blur,
+            mesh=None):
+    """(rows, hmax) ids and weights for ``lengths.shape[0]`` images,
+    ``chunk`` rows at a time; with a ``mesh``, over its chips."""
+    chunks = functools.partial(_chunks, side=side, hmax=hmax, jitter=jitter,
+                               blur=blur)
+    return make_rows(chunks, key, protos, lengths, chunk, mesh)
+
+
+def make(cfg: dict, seed: int, pool: int, mesh=None) -> Data:
+    """The configuration's images and ``pool`` held-out query images;
+    with a ``mesh``, the images over its ``model`` chips (the same
+    rows)."""
     g = cfg["generator"]
     side, hmax, n = g["side"], cfg["hmax"], cfg["n"]
     if cfg["v"] != side * side or cfg["m"] != 2:
@@ -83,7 +91,7 @@ def make(cfg: dict, seed: int, pool: int) -> Data:
     q_len = shuffled(kql, normal_sizes(pool, *lit))
     kw = dict(side=side, hmax=hmax, chunk=g["chunk"], jitter=g["jitter"],
               blur=g["blur"])
-    ids, w = _images(kd, protos, jnp.asarray(doc_len), **kw)
+    ids, w = _images(kd, protos, jnp.asarray(doc_len), mesh=mesh, **kw)
     q_ids, q_w = _images(kq, protos, jnp.asarray(q_len), **kw)
     return Data(ids=ids, w=w, coords=grid_coords(side), q_ids=q_ids,
                 q_w=q_w, doc_len=doc_len, q_len=q_len)

@@ -23,7 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from emd_bench.gen.common import Data, lognormal_sizes, seed_key, shuffled
+from emd_bench.gen.common import (Data, lognormal_sizes, make_rows, seed_key,
+                                  shuffled)
 
 
 @functools.partial(jax.jit, static_argnames=("v", "m", "classes"))
@@ -40,19 +41,12 @@ def _vocabulary(key, *, v, m, classes, topic_strength, zipf_s):
     return coords, logp
 
 
-@functools.partial(jax.jit, static_argnames=("hmax", "chunk"))
-def _documents(key, logp, lengths, *, hmax, chunk):
-    """(rows, hmax) ids and weights for ``lengths.shape[0]`` documents,
-    ``chunk`` rows at a time."""
-    rows = lengths.shape[0]
-    classes = logp.shape[0]
-    kl, kd = jax.random.split(key)
-    labels = jax.random.randint(kl, (rows,), 0, classes)
-    pad = -rows % chunk
-    labels = jnp.pad(labels, (0, pad)).reshape(-1, chunk)
-    lens = jnp.pad(lengths, (0, pad)).reshape(-1, chunk)
+def _chunks(kd, first, logp, labels, lens, *, hmax):
+    """(rows, hmax) ids and weights of the chunks ``first``,
+    ``first + 1``, ...: chunk i draws from ``fold_in(kd, i)`` alone."""
+    chunk = labels.shape[1]
     keys = jax.vmap(lambda i: jax.random.fold_in(kd, i))(
-        jnp.arange(labels.shape[0]))
+        first + jnp.arange(labels.shape[0]))
 
     def one(args):
         k, lab, ln = args
@@ -65,11 +59,20 @@ def _documents(key, logp, lengths, *, hmax, chunk):
         return jnp.where(live, top, 0).astype(jnp.int32), w
 
     ids, w = jax.lax.map(one, (keys, labels, lens))
-    return ids.reshape(-1, hmax)[:rows], w.reshape(-1, hmax)[:rows]
+    return ids.reshape(-1, hmax), w.reshape(-1, hmax)
 
 
-def make(cfg: dict, seed: int, pool: int) -> Data:
-    """The configuration's corpus and ``pool`` held-out queries."""
+@functools.partial(jax.jit, static_argnames=("hmax", "chunk", "mesh"))
+def _documents(key, logp, lengths, *, hmax, chunk, mesh=None):
+    """(rows, hmax) ids and weights for ``lengths.shape[0]`` documents,
+    ``chunk`` rows at a time; with a ``mesh``, over its chips."""
+    return make_rows(functools.partial(_chunks, hmax=hmax), key, logp,
+                     lengths, chunk, mesh)
+
+
+def make(cfg: dict, seed: int, pool: int, mesh=None) -> Data:
+    """The configuration's corpus and ``pool`` held-out queries; with a
+    ``mesh``, the corpus rows over its ``model`` chips (the same rows)."""
     g = cfg["generator"]
     n, v, m, hmax = cfg["n"], cfg["v"], cfg["m"], cfg["hmax"]
     kv, kl, kql, kd, kq = jax.random.split(seed_key(seed), 5)
@@ -82,7 +85,7 @@ def make(cfg: dict, seed: int, pool: int) -> Data:
                                           g["sigma_words"],
                                           g["min_query_words"], hmax))
     ids, w = _documents(kd, logp, jnp.asarray(doc_len), hmax=hmax,
-                        chunk=g["chunk"])
+                        chunk=g["chunk"], mesh=mesh)
     q_ids, q_w = _documents(kq, logp, jnp.asarray(q_len), hmax=hmax,
                             chunk=g["chunk"])
     return Data(ids=ids, w=w, coords=coords, q_ids=q_ids, q_w=q_w,

@@ -5,10 +5,15 @@ Everything that belongs to one cell is found by name:
 * the cell's entry in ``BENCHMARK.json`` names its configuration and
   traffic mix;
 * ``configs/<config>.json``: the deployment's sizes, its generator
-  (``gen/<generator>.py``), the engine settings and ground distance;
+  (``gen/<generator>.py``), the engine settings and ground distance,
+  and ``"rows_over_chips": true`` where the corpus lies over the cell's
+  chips, each chip making and holding its own block of rows;
 * ``traffic/<traffic>.json``: the mix's parameters and its loop
-  (``traffic/<loop>.py``), which runs the window;
-* ``limits/<cell>.json``: the limit of each number compared;
+  (``traffic/<loop>.py``), which runs the window; its ``engine`` may
+  name a cascade's candidate source, ``"source": {"kind": ...}``;
+* ``limits/<cell>.json``: the limit of each number compared
+  (``check.NUMBERS``, or ``check.SOURCED_NUMBERS`` for a sourced
+  cascade);
 * ``metrics/<metric>.py``: the reader of each per-layer metric;
 * ``work/<kernel>.py``: the operations and bytes each kernel needs.
 
@@ -21,6 +26,7 @@ import contextlib
 import dataclasses
 import gc
 import importlib.util
+import inspect
 import json
 import shutil
 import sys
@@ -86,6 +92,59 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
                 limits=load_json(BENCH / "limits" / f"{name}.json"),
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
                 per_layer=[m for m in bench["per_layer"] if mine(m)])
+
+
+def engine_of(cell: Cell) -> dict:
+    """The engine settings: the configuration's, with the mix's on
+    top."""
+    return {**cell.config["engine"], **cell.traffic.get("engine", {})}
+
+
+def compared(cell: Cell) -> tuple:
+    """The numbers that decide the cell's ``correct``; refuses a cell
+    whose limits file lacks one."""
+    from emd_bench import check
+
+    engine = engine_of(cell)
+    if "source" in engine and "stages" not in engine:
+        raise Refused(f"{cell.name}'s mix names a candidate source but no "
+                      "cascade stages for it to feed")
+    names = (check.SOURCED_NUMBERS if "source" in engine
+             else check.NUMBERS)
+    missing = [k for k in names if k not in cell.limits]
+    if missing:
+        raise Refused(f"limits/{cell.name}.json gives no limit for "
+                      f"{missing}")
+    return names
+
+
+def cell_mesh(chips: int):
+    """The distributed backend's mesh over a cell's chips: data 1 x
+    model ``chips``, rows over ``model``."""
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, chips), ("data", "model"))
+
+
+def source_spec(source: dict):
+    """The candidate source a mix names: ``{"kind": <registered kind>,
+    <field>: <value>, ...}`` built through the program's registry."""
+    from repro.candidates import SOURCES
+
+    fields = dict(source)
+    kind = fields.pop("kind", None)
+    if kind not in SOURCES:
+        raise Refused(f"no candidate source {kind!r}; registered: "
+                      f"{sorted(SOURCES)}")
+    cls = SOURCES[kind]
+    known = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(fields) - known)
+    if unknown:
+        raise Refused(f"source {kind!r} has no field {unknown}; its "
+                      f"fields: {sorted(known)}")
+    try:
+        return cls(**fields)
+    except ValueError as e:
+        raise Refused(f"source {kind!r}: {e}") from e
 
 
 def require_accelerator(chips: int):
@@ -179,7 +238,7 @@ class Run:
     def engine(self) -> dict:
         """The engine settings: the configuration's, with the mix's on
         top."""
-        return {**self.config["engine"], **self.traffic.get("engine", {})}
+        return engine_of(self.cell)
 
     def build_index(self):
         from repro.api import EmdIndex, EngineConfig
@@ -187,15 +246,18 @@ class Run:
         from repro.core.lc import Corpus
 
         e = {k: v for k, v in self.engine().items()
-             if k not in ("stages", "rescorer")}
+             if k not in ("stages", "rescorer", "source")}
         if e.get("cascade"):
-            # The cascade the mix states, stage by stage, is the one run.
+            # The cascade the mix states, stage by stage, is the one run,
+            # fed by the candidate source it names.
             full = self.engine()
+            source = full.get("source")
             e["cascade"] = CascadeSpec(
                 stages=tuple(CascadeStage(s[0], s[1], *s[2:])
                              for s in full["stages"]),
                 rescorer=full["rescorer"][0],
-                rescorer_iters=full["rescorer"][1])
+                rescorer_iters=full["rescorer"][1],
+                source=None if source is None else source_spec(source))
         d = self.data
         self.index = EmdIndex.build(Corpus(ids=d.ids, w=d.w,
                                            coords=d.coords),
@@ -208,8 +270,7 @@ class Run:
         single-device backends."""
         if engine.get("backend") != "distributed":
             return None
-        from repro.launch.mesh import make_mesh
-        return make_mesh((1, self.cell.chips), ("data", "model"))
+        return cell_mesh(self.cell.chips)
 
     def span(self, name: str):
         import jax
@@ -264,6 +325,7 @@ def check_answers(run: Run, win: Window, sample: int, *, passes: int = 6,
     from emd_bench.reference import Reference
 
     answers = win.answers if answers is None else answers
+    tie = run.cell.limits.get("score_err", 0.0)
     rng = np.random.default_rng(run.seed & (2**63 - 1))
     pick = rng.choice(len(answers), min(sample, len(answers)),
                       replace=False) if answers else []
@@ -283,7 +345,7 @@ def check_answers(run: Run, win: Window, sample: int, *, passes: int = 6,
             cache[q] = (np.asarray(full), np.asarray(top, np.float64))
         if ctl is not None:
             _, scores, rows = ctl.query(d.q_ids[q], d.q_w[q])
-        per.append(check.judge_answer(scores, rows, *cache[q], n))
+        per.append(check.judge_answer(scores, rows, *cache[q], n, tie))
     return check.combine(per, win.lost)
 
 
@@ -300,13 +362,41 @@ def per_layer(run: Run, win: Window, reduced, peaks: dict) -> dict:
     return out
 
 
+def rows_mesh(cell: Cell, gen):
+    """The mesh whose ``model`` chips hold the corpus rows, for a
+    configuration that asks for it (``rows_over_chips``); else None.
+    Refuses a layout that cannot hold: one chip, another backend than
+    ``distributed``, a generator that cannot shard, or n that is not a
+    multiple of chips x the generator's ``chunk``."""
+    if not cell.config.get("rows_over_chips"):
+        return None
+    c, chips = cell.config, cell.chips
+    backend = engine_of(cell).get("backend")
+    why = None
+    if chips < 2:
+        why = "the cell has one chip"
+    elif backend != "distributed":
+        why = f"backend {backend!r} is not distributed"
+    elif "mesh" not in inspect.signature(gen.make).parameters:
+        why = f"generator {c['generator']['name']!r} cannot shard"
+    elif c["n"] % (chips * c["generator"]["chunk"]):
+        why = (f"n={c['n']} is not a multiple of chips x chunk = {chips} x "
+               f"{c['generator']['chunk']}")
+    if why:
+        raise Refused(f"{c['name']} asks for rows over chips, but {why}")
+    return cell_mesh(chips)
+
+
 def make_data(cell: Cell, seed: int):
-    """The cell's corpus and query pool from ``seed``, on the device."""
+    """The cell's corpus and query pool from ``seed``, on the device: the
+    corpus rows over the cell's chips where the configuration says so."""
     import jax
 
     gen = load_module(BENCH / "gen" / f"{cell.config['generator']['name']}"
                       ".py")
-    data = gen.make(cell.config, seed, cell.traffic["pool"])
+    mesh = rows_mesh(cell, gen)
+    over = {} if mesh is None else {"mesh": mesh}
+    data = gen.make(cell.config, seed, cell.traffic["pool"], **over)
     jax.block_until_ready((data.ids, data.q_ids))
     return data
 
@@ -328,6 +418,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     """Run one cell on ``devices``; returns the result line's object."""
     from emd_bench import check
 
+    names = compared(cell)
     run = Run(cell, seed, seconds, trace, t_start)
     peaks = peak_table(devices[0].device_kind) if trace else None
     run.data = make_data(cell, seed)
@@ -355,7 +446,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     t0 = time.monotonic()
     numbers = check_answers(run, win, cell.traffic["check_sample"])
-    correct, checks = check.verdict(numbers, cell.limits)
+    correct, checks = check.verdict(numbers, cell.limits, names)
     run.note(f"reference: {time.monotonic() - t0:.3f} s for "
              f"{min(cell.traffic['check_sample'], len(win.answers))} answers")
     correct = correct and win.failed == 0
@@ -366,6 +457,8 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
         from emd_bench import trace as tr
         device.update(busy_s=reduced.busy_s, window_s=reduced.window_s)
         out["breakdown"] = tr.breakdown(reduced)
+    for k in (k for k in numbers if k not in checks):
+        print(f"reading {k}: {numbers[k]!r}", file=sys.stderr)
     for k, c in checks.items():
         print(f"check {k}: {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

@@ -15,14 +15,20 @@ importing nothing of the program under test:
 * WCD: distance between weighted centroids;
 * a cascade: keep the best rows of each stage among the previous
   stage's survivors, rescore the last survivors, take the top l. Ties
-  go to the lowest row id everywhere.
+  go to the lowest row id everywhere;
+* a cascade fed by a candidate source: the exact top l of its
+  rescorer's measure over the whole corpus (the source is approximate by
+  design; its answers are judged by recall against this).
 
 Every float32 matrix product runs in ``passes`` bfloat16 passes: 6 is
 float32 at ``highest`` precision (the configurations' own), 3 is
 ``high`` (the control), 1 is one bfloat16 pass. ``storage="bfloat16"``
 rounds the Phase-1 cost and capacity ladders to bfloat16 (the other
 control). Work runs one query at a time and in blocks of rows, so that
-it fits a chip beside nothing else.
+it fits a chip beside nothing else. A corpus whose rows lie over several
+chips is scored chip by chip, each chip its own rows, and the scores
+joined in row order: each row's arithmetic is the one-device
+reference's, so the scores are too.
 """
 from __future__ import annotations
 
@@ -164,48 +170,83 @@ def budgets(stages, n: int, top_l: int) -> list[int]:
     return out
 
 
+def chip_blocks(ids, w, coords) -> list[tuple]:
+    """(ids, w, coords) of each chip's own rows, in row order: the whole
+    corpus where it lies on one device; else each chip's block of the
+    row-sharded corpus, beside its own copy of ``coords``."""
+    if len(ids.sharding.device_set) == 1:
+        return [(ids, w, coords)]
+
+    def by_start(x):
+        return {s.index[0].start or 0: s.data
+                for s in sorted(x.addressable_shards,
+                                key=lambda s: s.device.id)}
+    ids_at, w_at = by_start(ids), by_start(w)
+    blocks = []
+    for start in sorted(ids_at):
+        dev = next(iter(ids_at[start].devices()))
+        blocks.append((ids_at[start], jax.device_put(w_at[start], dev),
+                       jax.device_put(coords, dev)))
+    return blocks
+
+
 class Reference:
     """The reference search of one deployment under one engine setting.
 
     ``engine`` holds the search as the traffic states it: ``method`` and
     ``iters`` for a full-corpus search, or ``stages`` (a list of
     ``[method, budget]``) and ``rescorer`` (``[method, iters]``) for a
-    cascade."""
+    cascade, and ``source`` where a candidate source feeds it."""
 
     def __init__(self, ids, w, coords, engine: dict, snap: float, *,
                  passes: int = 6, storage: str = "float32"):
-        self.ids, self.w, self.coords = ids, w, coords
+        self.blocks = chip_blocks(ids, w, coords)
+        self.n = ids.shape[0]
         self.engine, self.snap = engine, snap
         self.passes, self.storage = passes, storage
         self.top_l = engine["top_l"]
         self._cent = None
 
-    def _score(self, method: str, iters: int, q_ids, q_w):
-        if method == "act":
-            return act_scores(self.ids, self.w, self.coords, q_ids, q_w,
-                              iters=iters, snap=self.snap,
-                              passes=self.passes, storage=self.storage)
-        if method == "rwmd":
-            return act_scores(self.ids, self.w, self.coords, q_ids, q_w,
-                              iters=0, snap=self.snap, passes=self.passes,
+    def _block_score(self, b: int, method: str, iters: int, q_ids, q_w):
+        ids, w, coords = self.blocks[b]
+        if method in ("act", "rwmd"):
+            return act_scores(ids, w, coords, q_ids, q_w,
+                              iters=iters if method == "act" else 0,
+                              snap=self.snap, passes=self.passes,
                               storage=self.storage)
         if method == "wcd":
             if self._cent is None:
-                self._cent = centroids(self.ids, self.w, self.coords,
-                                       passes=self.passes)
-            qc = centroids(q_ids[None], q_w[None], self.coords,
+                self._cent = [centroids(i, x, c, passes=self.passes)
+                              for i, x, c in self.blocks]
+            qc = centroids(q_ids[None], q_w[None], coords,
                            passes=self.passes, rows=1)[0]
-            return wcd_scores(self._cent, qc)
+            return wcd_scores(self._cent[b], qc)
         raise ValueError(f"the reference has no method {method!r}")
+
+    def _score(self, method: str, iters: int, q_ids, q_w):
+        """(n,) scores of every row: each chip scores its own rows, all
+        chips dispatched before any result is read."""
+        if len(self.blocks) == 1:
+            return self._block_score(0, method, iters, q_ids, q_w)
+        parts = []
+        for b, (ids, _, _) in enumerate(self.blocks):
+            dev = next(iter(ids.devices()))
+            parts.append(self._block_score(
+                b, method, iters, jax.device_put(q_ids, dev),
+                jax.device_put(q_w, dev)))
+        return jnp.asarray(np.concatenate([np.asarray(p) for p in parts]))
 
     def query(self, q_ids, q_w):
         """(final scores of every row, top-l values, top-l rows) of one
         query: the final scores are the measure the top l is ranked by
-        (the rescorer's, for a cascade)."""
+        (the rescorer's, for a cascade). A sourced cascade's top l is the
+        rescorer's over every row."""
         e = self.engine
-        n = self.ids.shape[0]
-        if "stages" not in e:
-            s = self._score(e["method"], e["iters"], q_ids, q_w)
+        n = self.n
+        if "stages" not in e or "source" in e:
+            method, iters = ((e["method"], e["iters"]) if "stages" not in e
+                             else e["rescorer"])
+            s = self._score(method, iters, q_ids, q_w)
             vals, rows = topl(s, self.top_l)
             return s, vals, rows
         allowed = None
