@@ -14,9 +14,9 @@ entry it reports
   strictly fewer candidate rows than the n the full scorer reads.
 
 NOTE on the kernel entries off-TPU: without a TPU the kernels run in
-interpret mode, where the in-kernel one-hot gather is emulated as dense
-matmuls on the CPU — their queries/sec is a conformance smoke number,
-not a perf claim (the MXU gather win is a TPU measurement; see ROADMAP).
+interpret mode on the CPU — their queries/sec is a conformance smoke
+number, not a perf claim (kernel speed is a TPU measurement; see
+PERF.md).
 
 Results append to the CSV stream and land in ``BENCH_cascade.json``
 (repo root, override with BENCH_CASCADE_JSON) with a distributed-step

@@ -21,11 +21,13 @@ Two checked profiles:
   tiles that fit. The profile is the static half of the tile autotuner
   (``repro.kernels.autotune``): :func:`footprint` is the model it sweeps,
   and ``autotune.admissible_configs`` enumerates only tile choices
-  :func:`check_launch` admits. The candidate kernels stream the query's
-  table one ``block_v`` slab at a time into a persistent (block_n, rows,
-  hmax) gather accumulator, so their residency is tile-sized, not
-  corpus-sized — but ``rows`` is the query's h for ``cand_dist``, which
-  holds it to the smallest legal row tile (8) at 20News.
+  :func:`check_launch` admits. ``cand_pour`` reads one candidate block
+  of ladders gathered outside the kernel, (rows, block_n, hmax) with
+  rows the ladder's rungs. ``cand_dist`` streams the query's table one
+  ``block_v`` slab at a time into a persistent (block_n, rows, hmax)
+  gather accumulator, so its residency is tile-sized, not corpus-sized —
+  but ``rows`` is the query's h there, which holds it to the smallest
+  legal row tile (8) at 20News.
 """
 from __future__ import annotations
 
@@ -66,8 +68,7 @@ def check_configs() -> list[tuple[str, str, dict]]:
                     dict(nq=8, b=bench["b"], h=bench["h"], v=bench["v"],
                          qh=bench["h"], mode=mode, block_n=64)))
     # Paper scale: Phase-1/2 tiles are h/n-blocked so the defaults hold,
-    # and so does the candidate pour's (its accumulator is block_n rows
-    # of a 16-rung ladder).
+    # and so does the candidate pour's (block_n rows of a 22-rung ladder).
     k = PAPER.iters + 1
     out += [
         ("paper:dist_topk", "dist_topk",

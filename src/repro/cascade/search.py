@@ -261,8 +261,8 @@ def cascade_search(corpus: lc.Corpus, Q_ids: Array, Q_w: Array,
     remaining knobs mirror ``retrieval.batch_scores``; ``use_kernels``
     routes the full-corpus stage-1 scoring through the Phase-1/2 kernels
     AND every candidate stage + jittable registry rescorer through the
-    fused candidate kernels (``kernels/cand_pour`` — per-query gather and
-    reduction in one launch, matching the reference candidate engines to
+    candidate kernels (``kernels/cand_pour`` — per-query gather and
+    reduction, matching the reference candidate engines to
     within a few ulps, so an admissible cascade's exact-top-l guarantee is
     unchanged; ``block_n``/``block_v`` tile them). ``mesh`` (static,
     hashable) routes the kernel path of every stage through the

@@ -1041,17 +1041,22 @@ def lc_ict_scores_batched(corpus: Corpus, Q_ids: Array, Q_w: Array, *,
 # ``rev_min_blocked`` (its reduction is mul+sum where the full engine
 # contracts with einsum — see the comment there for why).
 #
-# ``use_kernels`` routes each consumer through the fused candidate Pallas
-# kernels (``kernels/cand_pour``): the per-query ladder gather and the
-# reduction run in ONE launch on a query-batch x candidate-block grid, so
-# the (nq, b, hmax, k) gather tensor never hits HBM (only the small
-# (nq, b, hmax) sub-corpus ids/weights do). Phase 1 stays the shared jnp
-# pipeline on BOTH paths and the kernels reuse the reference reductions
-# (``pour``/``ict_pour``/the expressions below) on identically shaped
-# tiles, so kernel and reference candidate scores agree to within a
-# few ulps (the gather itself is bitwise-exact) — the conformance contract
-# ``tests/test_cand_kernels.py`` pins, with the residual ulp explained
-# in ``kernels/cand_pour``'s module docstring.
+# ``use_kernels`` routes each consumer through the candidate Pallas
+# kernels (``kernels/cand_pour``), which take one of two gathers by the
+# table's shape. The ladder consumers (pour, zero-round pour, OMR) have
+# one table row per rung, so ``kernels/ops`` gathers each slot's rungs by
+# id with XLA, rung-major (bq, rows, b, hp), under ``emd.ladder_gather``
+# as the batch path does, and the kernel only reduces: the cost follows
+# the candidates' slots, not the vocabulary. The distance consumers
+# (reverse RWMD, ICT) have one row per query bin, and a gathered copy
+# would be (bq, b, hmax, h), gigabytes at text widths; their kernel
+# gathers in-kernel by one-hot products over vocabulary slabs, so that
+# copy never exists. Phase 1 stays the shared jnp pipeline on BOTH paths
+# and the kernels reuse the reference reductions (``pour``/``ict_pour``/
+# the expressions below), so kernel and reference candidate scores agree
+# to within a few ulps (both gathers are bitwise-exact) — the conformance
+# contract ``tests/test_cand_kernels.py`` pins, with the residual ulp
+# explained in ``kernels/cand_pour``'s module docstring.
 # --------------------------------------------------------------------------
 
 
@@ -1064,14 +1069,13 @@ def gather_per_query(A: Array, idx: Array) -> Array:
 @scopes.scoped(scopes.PHASE2)
 def pour_min_cand_blocked(corpus: Corpus, Z0: Array, cand: Array,
                           block_q: int, *, use_kernels: bool = False,
-                          block_n: int = 128, block_v: int = 256,
-                          mesh=None) -> Array:
+                          block_n: int = 128, mesh=None) -> Array:
     """Candidate-compacted zero-round pour: Z0 (nq, v), cand (nq, b)
-    -> (nq, b) scores at the candidate rows. ``use_kernels`` fuses the
-    gather + dump into one ``kernels/cand_pour`` launch (block_n
-    candidate rows x block_v vocabulary rows per tile); on a ``mesh``
-    whose DP axes divide the query batch, the launch runs inside a
-    ``shard_map`` shim with the sub-corpus gather kept outside."""
+    -> (nq, b) scores at the candidate rows. ``use_kernels`` gathers the
+    nearest costs by id and dumps them in a ``kernels/cand_pour`` launch
+    (block_n candidate rows per tile); on a ``mesh`` whose DP axes divide
+    the query batch, both run inside a ``shard_map`` shim with the
+    sub-corpus gather kept outside."""
     if use_kernels:
         from repro.kernels import ops as kops
         if mesh is not None:
@@ -1081,14 +1085,13 @@ def pour_min_cand_blocked(corpus: Corpus, Z0: Array, cand: Array,
 
                 def sh_k(idsb, xb, Zb):
                     return kops.cand_pour(idsb, xb, Zb[..., None], None, 0,
-                                          block_n=block_n, block_v=block_v)
+                                          block_n=block_n)
                 return partition.cand_sharded(mesh, sh_k, (idsg, xg, Z0),
                                               block_q)
 
         def blk_k(Zb, cb):                               # (bq, v), (bq, b)
             return kops.cand_pour(corpus.ids[cb], corpus.w[cb],
-                                  Zb[..., None], None, 0, block_n=block_n,
-                                  block_v=block_v)
+                                  Zb[..., None], None, 0, block_n=block_n)
         return _map_query_blocks(blk_k, (Z0, cand), Z0.shape[0], block_q)
 
     def blk(Zb, cb):                                     # (bq, v), (bq, b)
@@ -1102,17 +1105,16 @@ def pour_min_cand_blocked(corpus: Corpus, Z0: Array, cand: Array,
 def pour_cand_blocked(corpus: Corpus, Z: Array, W: Array, cand: Array,
                       iters: int, block_q: int, *,
                       use_kernels: bool = False, block_n: int = 128,
-                      block_v: int = 256, mesh=None) -> Array:
+                      mesh=None) -> Array:
     """Candidate-compacted Phase 2/3 pour: (nq, v, k) handoff ladders +
-    (nq, b) candidate rows -> (nq, b) lower bounds. ``use_kernels`` fuses
-    gather + pour into one ``kernels/cand_pour`` launch (``shard_map``
-    shim on a dividing ``mesh``)."""
+    (nq, b) candidate rows -> (nq, b) lower bounds. ``use_kernels``
+    gathers the ladders by id and pours them in a ``kernels/cand_pour``
+    launch (``shard_map`` shim on a dividing ``mesh``)."""
     nq = Z.shape[0]
     if iters == 0:
         return pour_min_cand_blocked(corpus, Z[..., 0], cand, block_q,
                                      use_kernels=use_kernels,
-                                     block_n=block_n, block_v=block_v,
-                                     mesh=mesh)
+                                     block_n=block_n, mesh=mesh)
     W = W[..., :iters]
     if use_kernels:
         from repro.kernels import ops as kops
@@ -1123,13 +1125,13 @@ def pour_cand_blocked(corpus: Corpus, Z: Array, W: Array, cand: Array,
 
                 def sh_k(idsb, xb, Zb, Wb):
                     return kops.cand_pour(idsb, xb, Zb, Wb, iters,
-                                          block_n=block_n, block_v=block_v)
+                                          block_n=block_n)
                 return partition.cand_sharded(mesh, sh_k, (idsg, xg, Z, W),
                                               block_q)
 
         def blk_k(Zb, Wb, cb):
             return kops.cand_pour(corpus.ids[cb], corpus.w[cb], Zb, Wb,
-                                  iters, block_n=block_n, block_v=block_v)
+                                  iters, block_n=block_n)
         return _map_query_blocks(blk_k, (Z, W, cand), nq, block_q)
 
     def blk(Zb, Wb, cb):
@@ -1147,10 +1149,10 @@ def pour_cand_blocked(corpus: Corpus, Z: Array, W: Array, cand: Array,
 def omr_reduce_cand_blocked(corpus: Corpus, Z: Array, W0: Array,
                             cand: Array, block_q: int, *,
                             use_kernels: bool = False, block_n: int = 128,
-                            block_v: int = 256, mesh=None) -> Array:
+                            mesh=None) -> Array:
     """Candidate-compacted Algorithm-1 reduction: Z (nq, v, 2), W0 (nq, v),
-    cand (nq, b) -> (nq, b) LC-OMR bounds. ``use_kernels`` fuses gather +
-    reduce into one ``kernels/cand_pour`` launch (mode "omr";
+    cand (nq, b) -> (nq, b) LC-OMR bounds. ``use_kernels`` gathers by id
+    and reduces in a ``kernels/cand_pour`` launch (mode "omr";
     ``shard_map`` shim on a dividing ``mesh``)."""
     if use_kernels:
         from repro.kernels import ops as kops
@@ -1161,13 +1163,13 @@ def omr_reduce_cand_blocked(corpus: Corpus, Z: Array, W0: Array,
 
                 def sh_k(idsb, xb, Zb, W0b):
                     return kops.cand_omr(idsb, xb, Zb, W0b,
-                                         block_n=block_n, block_v=block_v)
+                                         block_n=block_n)
                 return partition.cand_sharded(mesh, sh_k, (idsg, xg, Z, W0),
                                               block_q)
 
         def blk_k(Zb, W0b, cb):
             return kops.cand_omr(corpus.ids[cb], corpus.w[cb], Zb, W0b,
-                                 block_n=block_n, block_v=block_v)
+                                 block_n=block_n)
         return _map_query_blocks(blk_k, (Z, W0, cand), Z.shape[0], block_q)
 
     def blk(Zb, W0b, cb):
@@ -1297,20 +1299,21 @@ def _pin_handoff(*arrays):
     return out[0] if len(arrays) == 1 else out
 
 
-_CAND_STATIC = ("use_kernels", "block_q", "block_n", "block_v", "mesh",
-                "precision")
+_CAND_STATIC = ("use_kernels", "block_q", "block_n", "mesh", "precision")
+#: The one-hot candidate kernels (reverse RWMD, ICT) also tile the
+#: vocabulary.
+_CAND_DIST_STATIC = _CAND_STATIC + ("block_v",)
 
 
 @functools.partial(jax.jit, static_argnames=("iters",) + _CAND_STATIC)
 def lc_act_scores_cand(corpus: Corpus, Q_ids: Array, Q_w: Array,
                        cand: Array, iters: int = 1, *,
                        use_kernels: bool = False, block_q: int = 8,
-                       block_n: int = 128, block_v: int = 256,
-                       mesh=None, precision="f32") -> Array:
+                       block_n: int = 128, mesh=None,
+                       precision="f32") -> Array:
     """Candidate-compacted batched LC-ACT: (nq, h) queries scored against
     each query's own (b,) candidate rows -> (nq, b)."""
-    kw = dict(use_kernels=use_kernels, block_n=block_n, block_v=block_v,
-              mesh=mesh)
+    kw = dict(use_kernels=use_kernels, block_n=block_n, mesh=mesh)
     if iters == 0:
         Z0 = _pin_handoff(phase1_min_batched(corpus.coords, Q_ids, Q_w,
                                              precision=precision))
@@ -1323,17 +1326,16 @@ def lc_act_scores_cand(corpus: Corpus, Q_ids: Array, Q_w: Array,
 @functools.partial(jax.jit, static_argnames=_CAND_STATIC)
 def lc_rwmd_scores_cand(corpus: Corpus, Q_ids: Array, Q_w: Array,
                         cand: Array, *, use_kernels: bool = False,
-                        block_q: int = 8, block_n: int = 128,
-                        block_v: int = 256, mesh=None,
+                        block_q: int = 8, block_n: int = 128, mesh=None,
                         precision="f32") -> Array:
     """Candidate-compacted batched LC-RWMD db -> query."""
     return lc_act_scores_cand(corpus, Q_ids, Q_w, cand, iters=0,
                               use_kernels=use_kernels, block_q=block_q,
-                              block_n=block_n, block_v=block_v, mesh=mesh,
+                              block_n=block_n, mesh=mesh,
                               precision=precision)
 
 
-@functools.partial(jax.jit, static_argnames=_CAND_STATIC)
+@functools.partial(jax.jit, static_argnames=_CAND_DIST_STATIC)
 def lc_rwmd_scores_rev_cand(corpus: Corpus, Q_ids: Array, Q_w: Array,
                             cand: Array, *, use_kernels: bool = False,
                             block_q: int = 8, block_n: int = 128,
@@ -1350,18 +1352,17 @@ def lc_rwmd_scores_rev_cand(corpus: Corpus, Q_ids: Array, Q_w: Array,
 @functools.partial(jax.jit, static_argnames=_CAND_STATIC)
 def lc_omr_scores_cand(corpus: Corpus, Q_ids: Array, Q_w: Array,
                        cand: Array, *, use_kernels: bool = False,
-                       block_q: int = 8, block_n: int = 128,
-                       block_v: int = 256, mesh=None,
+                       block_q: int = 8, block_n: int = 128, mesh=None,
                        precision="f32") -> Array:
     """Candidate-compacted batched LC-OMR."""
     Z, W = _pin_handoff(*phase1_batched(corpus.coords, Q_ids, Q_w, 2,
                                         precision=precision))
     return omr_reduce_cand_blocked(corpus, Z, W[..., 0], cand, block_q,
                                    use_kernels=use_kernels, block_n=block_n,
-                                   block_v=block_v, mesh=mesh)
+                                   mesh=mesh)
 
 
-@functools.partial(jax.jit, static_argnames=_CAND_STATIC)
+@functools.partial(jax.jit, static_argnames=_CAND_DIST_STATIC)
 def lc_ict_scores_cand(corpus: Corpus, Q_ids: Array, Q_w: Array,
                        cand: Array, *, use_kernels: bool = False,
                        block_q: int = 8, block_n: int = 128,

@@ -93,7 +93,8 @@ class MethodSpec:
                  For the five LC methods ``use_kernels=True`` routes the
                  gather + reduction through the fused candidate Pallas
                  kernels (``kernels/cand_pour``; ``block_n`` tiles the
-                 candidate rows, ``block_v`` the in-kernel gather),
+                 candidate rows, ``block_v`` the in-kernel one-hot gather
+                 of ``rwmd_rev`` and ``ict``),
                  matching the reference path to within a few ulps
                  (gather exact, same reduction formulas); the bow/wcd baselines
                  have no kernel form and ignore the flag.
@@ -203,11 +204,10 @@ def _rwmd_rev_dist(corpus, q_ids, q_w, *, rev_block=256, block_q=8,
 
 @_register_cand("rwmd")
 def _rwmd_cand(corpus, q_ids, q_w, cand, *, block_q=8, use_kernels=False,
-               block_n=256, block_v=256, mesh=None, precision="f32", **_):
+               block_n=256, mesh=None, precision="f32", **_):
     return lc.lc_rwmd_scores_cand(corpus, q_ids, q_w, cand, block_q=block_q,
                                   use_kernels=use_kernels, block_n=block_n,
-                                  block_v=block_v, mesh=mesh,
-                                  precision=precision)
+                                  mesh=mesh, precision=precision)
 
 
 @_register_cand("rwmd_rev")
@@ -251,11 +251,10 @@ def _omr_batch(corpus, q_ids, q_w, *, use_kernels=False, block_v=256,
 
 @_register_cand("omr")
 def _omr_cand(corpus, q_ids, q_w, cand, *, block_q=8, use_kernels=False,
-              block_n=256, block_v=256, mesh=None, precision="f32", **_):
+              block_n=256, mesh=None, precision="f32", **_):
     return lc.lc_omr_scores_cand(corpus, q_ids, q_w, cand, block_q=block_q,
                                  use_kernels=use_kernels, block_n=block_n,
-                                 block_v=block_v, mesh=mesh,
-                                 precision=precision)
+                                 mesh=mesh, precision=precision)
 
 
 @_register("act", paper_name="LC-ACT-k", uses_iters=True,
@@ -280,11 +279,11 @@ def _act_batch(corpus, q_ids, q_w, *, iters=1, use_kernels=False,
 
 @_register_cand("act")
 def _act_cand(corpus, q_ids, q_w, cand, *, iters=1, block_q=8,
-              use_kernels=False, block_n=256, block_v=256, mesh=None,
-              precision="f32", **_):
+              use_kernels=False, block_n=256, mesh=None, precision="f32",
+              **_):
     return lc.lc_act_scores_cand(corpus, q_ids, q_w, cand, iters=iters,
                                  block_q=block_q, use_kernels=use_kernels,
-                                 block_n=block_n, block_v=block_v, mesh=mesh,
+                                 block_n=block_n, mesh=mesh,
                                  precision=precision)
 
 
@@ -573,8 +572,8 @@ def cand_scores(corpus: lc.Corpus, q_ids: Array, q_w: Array, cand: Array, *,
     This is the cascade subsystem's stage primitive (Phase 1 is shared
     with the full-corpus engines; only Phase 2/3 compacts to the
     candidates), dispatched through ``MethodSpec.cand_fn``.
-    ``use_kernels=True`` fuses the per-query candidate gather and the
-    reduction into one ``kernels/cand_pour`` launch for the LC methods,
+    ``use_kernels=True`` runs the per-query candidate gather and the
+    reduction through the ``kernels/cand_pour`` kernels for the LC methods,
     matching the reference path to within a few ulps (see the
     ``cand_fn`` field doc and ``kernels/cand_pour``'s conformance notes).
     """

@@ -3,9 +3,9 @@
 dist_topk   — fused pairwise-distance + row-top-k (LC-ACT Phase 1).
 act_phase2  — fused k-round constrained pour (LC-ACT Phases 2+3) over
               the full corpus.
-cand_pour   — fused per-query candidate gather + Phase-2/3 reduction for
-              the cascade's compacted stages (pour / OMR / reverse-min /
-              ICT modes; the (nq, b, hmax, k) gather never hits HBM).
+cand_pour   — per-query candidate reductions for the cascade's compacted
+              stages: pour / OMR over ladders gathered by id, reverse-min /
+              ICT with an in-kernel one-hot gather.
 
 Written for TPU (pl.pallas_call + BlockSpec VMEM tiling); validated with
 interpret=True on CPU. ``ops`` holds the jitted padding wrappers; ``ref``

@@ -41,7 +41,7 @@ from repro.kernels import ops
 FAMILY_KNOBS: dict[str, tuple[tuple[str, str], ...]] = {
     "dist_topk": (("block_v", "v"), ("block_h", "h")),
     "act_phase2": (("block_n", "n"), ("block_h", "h")),
-    "cand_pour": (("block_n", "b"), ("block_v", "v")),
+    "cand_pour": (("block_n", "b"),),
     "cand_dist": (("block_n", "b"), ("block_v", "v")),
 }
 

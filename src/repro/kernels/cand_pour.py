@@ -1,51 +1,57 @@
-"""Pallas TPU kernels: fused candidate gather + Phase-2/3 reduction.
+"""Pallas TPU kernels: the cascade's candidate reductions.
 
 The cascade's candidate engines (``core/lc`` ``*_cand_blocked``) score each
-query against its own (b,) surviving rows: the reference path gathers the
-per-entry cost/capacity ladders with XLA (``Z[ids[cand]]`` — the
-(nq, b, hmax, k) tensor lands in HBM) and then reduces. These kernels do
-BOTH in one launch on a (query, candidate block, vocabulary slab) grid:
-each step gathers its candidate block's entries from one ``block_v`` slab
-of the query's table into a VMEM accumulator, and the last slab reduces
-the completed per-row ladders to the (bb, 1) scores — the
-(nq, b, hmax, k) gather tensor never materializes.
+query against its own (b,) surviving rows from per-query tables that
+Phase 1 hands over. Two paths, split by mode (the wrappers in
+``kernels/ops`` pick by the mode, which is static):
 
-Layout. Every per-query table is ROW-MAJOR over its ladder rungs:
-(rows, v), one row per rung (or per query bin), the vocabulary on the
-128-wide lane axis. One candidate row's ids (1, hp) broadcast over a
+* Ladder modes ("pour", "omr"): the table has one row per rung, (rows, v)
+  with rows = k + 2 * iters or 3. The wrapper gathers each candidate
+  slot's rungs by its vocabulary id with one XLA gather, rung-major with
+  the slots on lanes, (nq, rows, b, hp), under ``emd.ladder_gather``; the
+  kernel only reduces, on a (query, candidate block) grid, a sublane tile
+  of candidate rows at a time. Its cost follows the candidates, not v.
+* Distance modes ("rev_min", "ict"): the table has one row per query bin
+  (h = 500 at 20NEWS widths), so a gathered copy would be
+  (nq, b, hmax, h), 8.6 GB for 8 queries at 1,024 candidates. These keep
+  the in-kernel one-hot gather on a (query, candidate block, vocabulary
+  slab) grid: each step gathers its candidate block's entries from one
+  ``block_v`` slab of the query's table into a VMEM accumulator, and the
+  last slab reduces the completed per-row ladders.
+
+One-hot layout. The table is row-major over its rows, the vocabulary on
+the 128-wide lane axis. One candidate row's ids (1, hp) broadcast over a
 slab's sublanes give the (block_v, hp) one-hot, and the MXU contraction
 ``table_slab (rows, block_v) @ onehot (block_v, hp)`` lands the row's
-gathered ladder as (rows, hp): rung l is sublane row l, the slots run
-along lanes, so the pour is plain row arithmetic and the per-row score a
-lane reduction. hp is hmax padded to a lane multiple; pad slots carry id
-0 and weight 0 and contribute exactly 0.
+gathered ladder as (rows, hp). hp is hmax padded to a lane multiple; pad
+slots carry id 0 and weight 0 and contribute exactly 0.
 
-Exactness. The one-hot runs on the MXU in bfloat16 (0/1 are exact). A
-float32 table rides as three bfloat16 parts that sum back to it exactly
-(:func:`split_bf16`), each contracted against the same one-hot
-with f32 accumulation: every gathered value is 1.0 * part + exact zeros,
-so the gather is BITWISE the XLA gather's result, compiled or
-interpreted, at 3 MXU passes where a float32 contraction would take 6.
+Exactness. Either gather copies the table's values bit for bit. The XLA
+gather does by definition. The one-hot runs on the MXU in bfloat16 (0/1
+are exact), and a float32 table rides as three bfloat16 parts that sum
+back to it exactly (:func:`split_bf16`), each contracted against the same
+one-hot with f32 accumulation: every gathered value is 1.0 * part + exact
+zeros, at 3 MXU passes where a float32 contraction would take 6.
 
 The reductions use the reference engines' formulas (``lc.pour``,
 ``lc.ict_pour``, the Algorithm-1 and masked-min expressions). What needs
 a sort or a cumulative sum — ICT's cost order, both pours' exclusive
 capacity prefix — depends only on the vocabulary row, so the wrappers
-(``kernels/ops``) compute it once per query over the (v, ...) table with
-the reference's own ``jnp`` ops and gather it like any other rung. The
-conformance contract (``tests/test_cand_kernels.py``): the gather is
-bitwise-exact and scores match the reference candidate engines to within
-a few ulps (the remaining ulps are summation order).
+compute it once per query over the (v, ...) table with the reference's
+own ``jnp`` ops and gather it like any other rung. The conformance
+contract (``tests/test_cand_kernels.py``): the gathers are bitwise-exact
+and scores match the reference candidate engines to within a few ulps
+(the remaining ulps are summation order).
 
-Covers every candidate reduction in the registry:
-  mode "pour"    — LC-ACT Phase 2/3 (iters >= 1) and the LC-RWMD
-                   masked-min dump (iters == 0); rows Z | W | prefix(W).
-  mode "omr"     — LC-OMR Algorithm-1 top-2 reduction; rows Z0, Z1, W0.
-  mode "rev_min" — reverse-RWMD masked (min,+) over the distance handoff;
-                   rows = query bins.
-  mode "ict"     — LC-ICT full-ladder pour; rows = sorted costs | sorted
-                   capacities | their exclusive prefix | the max-FINITE
-                   dump cost (never ``lc.PAD_DIST`` — see ``ict_pour``).
+Modes:
+  "pour"    — LC-ACT Phase 2/3 (iters >= 1) and the LC-RWMD masked-min
+              dump (iters == 0); rows Z | W | prefix(W).
+  "omr"     — LC-OMR Algorithm-1 top-2 reduction; rows Z0, Z1, W0.
+  "rev_min" — reverse-RWMD masked (min,+) over the distance handoff;
+              rows = query bins.
+  "ict"     — LC-ICT full-ladder pour; rows = sorted costs | sorted
+              capacities | their exclusive prefix | the max-FINITE dump
+              cost (never ``lc.PAD_DIST`` — see ``ict_pour``).
 """
 from __future__ import annotations
 
@@ -57,16 +63,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import matmul_precision, pad_dist_for
-from repro.kernels.tiling import compiler_params, round_up
+from repro.kernels.tiling import SUBLANE, compiler_params, round_up
 
-#: Modes whose table is built from the Phase-1 ranked (Z, W) handoff.
+#: Modes whose table is built from the Phase-1 ranked (Z, W) handoff, one
+#: row per rung: gathered by id outside the kernel.
 POUR_MODES = ("pour", "omr")
-#: Modes whose table is built from the (v, h) distance handoff.
+#: Modes whose table is built from the (v, h) distance handoff, one row
+#: per query bin: gathered by one-hot inside the kernel.
 DIST_MODES = ("rev_min", "ict")
 MODES = POUR_MODES + DIST_MODES
 
-#: Table rows of one segment are padded to the bf16 sublane tile, so
-#: every segment and bf16 part starts on a tile boundary. Slots (hmax)
+#: One-hot table rows of one segment are padded to the bf16 sublane tile,
+#: so every segment and bf16 part starts on a tile boundary. Slots (hmax)
 #: are padded to ``tiling.LANE``.
 ROW_TILE = 16
 
@@ -98,17 +106,18 @@ def split_bf16(table: jax.Array) -> jax.Array:
 
 
 def segment_rows(w: int) -> int:
-    """Padded row count of a ``w``-row table segment."""
+    """Padded row count of a ``w``-row one-hot table segment."""
     return round_up(w, ROW_TILE)
 
 
 def table_rows(mode: str, *, k: int = 1, iters: int = 0, qh: int = 1) -> int:
-    """Rows of one (unsplit) table of ``mode`` — the gathered ladder
-    height per candidate entry (see the module docstring)."""
+    """Rows of one (unsplit) table of ``mode`` — the ladder height per
+    candidate entry (see the module docstring). The ladder modes' rows
+    are gathered as they are; the one-hot modes' are tile-padded."""
     if mode == "pour":
-        return segment_rows(k + 2 * iters)
+        return k + 2 * iters
     if mode == "omr":
-        return segment_rows(3)
+        return 3
     if mode == "rev_min":
         return segment_rows(qh)
     return 3 * segment_rows(qh) + ROW_TILE                 # "ict"
@@ -135,12 +144,12 @@ def gather_slab(tab_ref, ids, parts: int, rows: int, acc):
 
 
 def _sum_lanes(e):
-    return jnp.sum(e, axis=1, keepdims=True)                  # (1, 1)
+    return jnp.sum(e, axis=-1, keepdims=True)
 
 
 def _reduce_pour(g, x, *, k: int, iters: int):
-    """``lc.pour`` on one row's gathered ladder: rows Z[0:k],
-    W[k:k+iters], exclusive prefix of W[k+iters:k+2*iters]."""
+    """``lc.pour`` on gathered ladders: rows Z[0:k], W[k:k+iters],
+    exclusive prefix of W[k+iters:k+2*iters]."""
     if iters == 0:
         return _sum_lanes(x * g[0:1])
     poured = None
@@ -183,8 +192,28 @@ def _reduce_ict(g, x, *, hs: int):
     return _sum_lanes(poured + remainder * dump)
 
 
-def _cand_kernel(*refs, mode: str, parts: int, rows: int, k: int,
-                 iters: int, hs: int):
+def _ladder_kernel(g_ref, x_ref, t_ref, *, mode: str, k: int, iters: int):
+    """Grid = (nq, cand_blocks). Reduces the block's gathered
+    (rows, bb, hp) ladders a sublane tile of candidate rows at a time;
+    each rung is loaded from VMEM where the reduction uses it."""
+    bb = x_ref.shape[1]
+    sub = SUBLANE if bb % SUBLANE == 0 else bb
+
+    def reduce(i, carry):
+        rows = pl.ds(pl.multiple_of(i * sub, sub), sub)
+        g = g_ref.at[0, :, rows, :]                          # (rows, sub, hp)
+        x = x_ref[0, rows, :].astype(jnp.float32)            # (sub, hp)
+        if mode == "pour":
+            t = _reduce_pour(g, x, k=k, iters=iters)
+        else:
+            t = _reduce_omr(g, x)
+        t_ref[:, rows, :] = t                                # (1, sub, 1)
+        return carry
+
+    jax.lax.fori_loop(0, bb // sub, reduce, 0)
+
+
+def _onehot_kernel(*refs, mode: str, parts: int, rows: int, hs: int):
     """Grid = (nq, cand_blocks, vocab_slabs); the slab axis is innermost.
     Each step adds its slab's one-hot gather into the persistent
     (bb, rows, hp) accumulator; the last slab reduces row by row."""
@@ -211,11 +240,7 @@ def _cand_kernel(*refs, mode: str, parts: int, rows: int, k: int,
         def reduce(i, carry):
             g = acc_ref[i]                                       # (rows, hp)
             x = x_ref[0, pl.ds(i, 1), :].astype(jnp.float32)     # (1, hp)
-            if mode == "pour":
-                t = _reduce_pour(g, x, k=k, iters=iters)
-            elif mode == "omr":
-                t = _reduce_omr(g, x)
-            elif mode == "rev_min":
+            if mode == "rev_min":
                 t = _reduce_rev_min(g, x, qw_ref[0].astype(jnp.float32))
             else:
                 t = _reduce_ict(g, x, hs=hs)
@@ -228,35 +253,55 @@ def _cand_kernel(*refs, mode: str, parts: int, rows: int, k: int,
 @functools.partial(jax.jit, static_argnames=("mode", "k", "iters", "qh",
                                              "block_n", "block_v",
                                              "interpret"))
-def cand_pallas(idsg: jax.Array, xg: jax.Array, table: jax.Array,
-                qw: jax.Array | None = None, *, mode: str, k: int = 1,
-                iters: int = 0, qh: int = 1, block_n: int = 128,
-                block_v: int = 256, interpret: bool = False) -> jax.Array:
-    """Fused candidate gather + reduction over a query batch.
+def cand_pallas(xg: jax.Array, table: jax.Array,
+                idsg: jax.Array | None = None, qw: jax.Array | None = None,
+                *, mode: str, k: int = 1, iters: int = 0, qh: int = 1,
+                block_n: int = 128, block_v: int = 256,
+                interpret: bool = False) -> jax.Array:
+    """Candidate reduction over a query batch.
 
     Args:
-      idsg:  (nq, b, hp) int32 vocabulary ids of each query's candidate
-             rows (``corpus.ids[cand]``, slots padded to a lane multiple;
-             padding slots/rows carry id 0 and weight 0).
-      xg:    (nq, b, hp) residual weights (``corpus.w[cand]``).
-      table: (nq, P * rows, vp) bfloat16 parts of the per-query table
-             (:func:`split_bf16`, part-major; ``rows`` =
-             :func:`table_rows` of the mode).
+      xg:    (nq, b, hp) residual weights of each query's candidate rows
+             (``corpus.w[cand]``, slots padded to a lane multiple;
+             padding slots/rows carry weight 0).
+      table: ladder modes — (nq, rows, b, hp) float32 ladders gathered at
+             each candidate slot, rung-major (``rows`` =
+             :func:`table_rows` of the mode); one-hot modes — the
+             (nq, P * rows, vp) bfloat16 parts of the per-query table
+             (:func:`split_bf16`, part-major).
+      idsg:  (nq, b, hp) int32 vocabulary ids of the candidate slots
+             (``corpus.ids[cand]``, padding id 0); one-hot modes only.
       qw:    (nq, rows, 1) query weights per bin ("rev_min" only; 0 at
              padded bins).
     Returns t: (nq, b, 1) scores at the candidate rows.
-    Caller guarantees b % block_n == 0 and vp % block_v == 0 (ops.py).
+    Caller guarantees b % block_n == 0, and vp % block_v == 0 for the
+    one-hot modes (ops.py).
     """
     assert mode in MODES, mode
-    nq, b, hp = idsg.shape
-    assert xg.shape == idsg.shape and b % block_n == 0, (xg.shape, block_n)
+    nq, b, hp = xg.shape
+    assert b % block_n == 0, (xg.shape, block_n)
     rows = table_rows(mode, k=k, iters=iters, qh=qh)
+    if mode in POUR_MODES:
+        assert table.shape == (nq, rows, b, hp), (table.shape, rows)
+        return pl.pallas_call(
+            functools.partial(_ladder_kernel, mode=mode, k=k, iters=iters),
+            grid=(nq, b // block_n),
+            in_specs=[
+                pl.BlockSpec((1, rows, block_n, hp),
+                             lambda q, i: (q, 0, i, 0)),
+                pl.BlockSpec((1, block_n, hp), lambda q, i: (q, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_n, 1), lambda q, i: (q, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((nq, b, 1), jnp.float32),
+            compiler_params=compiler_params("parallel", "parallel"),
+            interpret=interpret,
+        )(table, xg)
+    assert idsg.shape == xg.shape, (idsg.shape, xg.shape)
     vp = table.shape[-1]
     parts = table.shape[1] // rows
     assert table.shape[1] == parts * rows and vp % block_v == 0, table.shape
-    kernel = functools.partial(_cand_kernel, mode=mode, parts=parts,
-                               rows=rows, k=k, iters=iters,
-                               hs=segment_rows(qh))
+    kernel = functools.partial(_onehot_kernel, mode=mode, parts=parts,
+                               rows=rows, hs=segment_rows(qh))
     in_specs = [
         pl.BlockSpec((1, block_n, hp), lambda q, i, u: (q, i, 0)),
         pl.BlockSpec((1, block_n, hp), lambda q, i, u: (q, i, 0)),

@@ -19,6 +19,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.core.precision import pad_dist_for
 from repro.kernels.cand_pour import (DIST_MODES, POUR_MODES, cand_pallas,
                                      segment_rows, split_bf16, table_rows)
@@ -123,36 +124,90 @@ def act_phase2(x: jax.Array, zg: jax.Array, wg: jax.Array, *,
 
 # ------------------------------------------------------ candidate kernels
 #
-# Fused per-query candidate gather + Phase-2/3 reduction (cascade stages).
+# Per-query candidate reductions (cascade stages), ``kernels/cand_pour``.
 # Shapes: idsg/xg (nq, b, hmax) are the candidate sub-corpus
-# (corpus.ids[cand] / corpus.w[cand] — already compacted, k+ times smaller
-# than the ladder gathers these kernels avoid); the Phase-1 handoff rides
-# in per-query tables, laid out row-major (rows, v) and split into exact
-# bfloat16 parts here (``kernels/cand_pour``). Padding added here
-# (candidate rows to a block_n multiple, slots to a lane multiple, table
-# rows to a tile, vocabulary to a block_v multiple) contributes exactly
-# zero cost and is sliced off.
+# (corpus.ids[cand] / corpus.w[cand]); the Phase-1 handoff rides in
+# per-query tables laid out row-major (rows, v). The ladder modes (pour,
+# omr) gather each slot's rungs here by id and hand the kernel the
+# gathered ladders; the one-hot modes (rev_min, ict) hand it the table's
+# exact bfloat16 parts and gather in-kernel. Padding added here
+# (candidate rows to a block_n multiple, slots to a lane multiple, and for
+# the one-hot modes table rows to a tile and the vocabulary to a block_v
+# multiple) contributes exactly zero cost and is sliced off.
+
+#: Largest gathered ladder (nq, rows, b, hp) float32 one launch
+#: materialises. Beyond it the candidate axis is gathered and reduced in
+#: chunks of whole candidate blocks, so the ladder stays bounded however
+#: large the budget.
+GATHER_CAP_BYTES = 2**30
 
 
-def _cand_launch(idsg, xg, table, mode: str, *, qw=None, k: int = 1,
-                 iters: int = 0, qh: int = 1, block_n: int, block_v: int):
-    """Pad, split and launch one fused candidate kernel. ``table``:
-    (nq, w, v) row-major per-query table (w <= the mode's rows)."""
-    nq, b, hmax = idsg.shape
-    v = table.shape[-1]
-    rows = table_rows(mode, k=k, iters=iters, qh=qh)
+def _gather_chunk(nq: int, rows: int, bp: int, hp: int, block_n: int) -> int:
+    """Candidate rows per gather: all ``bp`` if their ladder fits
+    :data:`GATHER_CAP_BYTES`, else the most whole ``block_n`` blocks that
+    do (at least one)."""
+    per_row = nq * rows * hp * 4
+    if bp * per_row <= GATHER_CAP_BYTES:
+        return bp
+    return max(1, GATHER_CAP_BYTES // (per_row * block_n)) * block_n
+
+
+def _pad_cands(idsg, xg, block_n: int):
+    """Pad the candidate rows to a ``block_n`` multiple (``block_n``
+    clamped to the rows) and the slots to a lane multiple; pads carry id
+    0 and weight 0. Returns the padded pair and the row tile."""
+    _, b, hmax = idsg.shape
     block_n = min(block_n, _round_up(b, 8))
+    pad = ((0, 0), (0, _round_up(b, block_n) - b),
+           (0, _round_up(hmax, tiling.LANE) - hmax))
+    return jnp.pad(idsg, pad), jnp.pad(xg, pad), block_n
+
+
+def _ladder_launch(idsg, xg, table, mode: str, *, k: int = 1,
+                   iters: int = 0, block_n: int):
+    """Gather each candidate slot's rungs by its id and reduce them.
+    ``table``: (nq, rows, v) row-major per-query table, one row per
+    rung."""
+    b = idsg.shape[1]
+    idsg, xg, block_n = _pad_cands(idsg, xg, block_n)
+    nq, bp, hp = idsg.shape
+    table = table.astype(jnp.float32)
+    interpret = _interpret_default()
+
+    def launch(ids, x):
+        with jax.named_scope(scopes.LADDER_GATHER):
+            g = jax.vmap(lambda t, i: t[:, i])(table, ids)  # (nq, rows, c, hp)
+        return cand_pallas(x, g, mode=mode, k=k, iters=iters,
+                           block_n=block_n, interpret=interpret)
+
+    chunk = _gather_chunk(nq, table.shape[1], bp, hp, block_n)
+    if chunk == bp:
+        return launch(idsg, xg)[:, :b, 0]
+    nc = -(-bp // chunk)
+    pad = ((0, 0), (0, nc * chunk - bp), (0, 0))
+
+    def chunks(a):                              # -> (nc, nq, chunk, hp)
+        return jnp.swapaxes(jnp.pad(a, pad).reshape(nq, nc, chunk, hp), 0, 1)
+    t = jax.lax.map(lambda c: launch(*c), (chunks(idsg), chunks(xg)))
+    return jnp.swapaxes(t, 0, 1).reshape(nq, nc * chunk)[:, :b]
+
+
+def _onehot_launch(idsg, xg, table, mode: str, *, qh: int, block_n: int,
+                   block_v: int, qw=None):
+    """Pad, split and launch one one-hot candidate kernel. ``table``:
+    (nq, w, v) row-major per-query table (w <= the mode's rows)."""
+    nq, b, _ = idsg.shape
+    idsg, xg, block_n = _pad_cands(idsg, xg, block_n)
+    v = table.shape[-1]
+    rows = table_rows(mode, qh=qh)
     block_v = min(block_v, _round_up(v, 8))
-    bp, vp = _round_up(b, block_n), _round_up(v, block_v)
-    hp = _round_up(hmax, tiling.LANE)
-    pad = ((0, 0), (0, bp - b), (0, hp - hmax))
+    vp = _round_up(v, block_v)
     table = jnp.pad(table, ((0, 0), (0, rows - table.shape[1]), (0, vp - v)))
     parts = split_bf16(table).reshape(nq, -1, vp)
     if qw is not None:
         qw = jnp.pad(qw.astype(jnp.float32),
                      ((0, 0), (0, rows - qh)))[..., None]
-    t = cand_pallas(jnp.pad(idsg, pad), jnp.pad(xg, pad), parts, qw,
-                    mode=mode, k=k, iters=iters, qh=qh, block_n=block_n,
+    t = cand_pallas(xg, parts, idsg, qw, mode=mode, qh=qh, block_n=block_n,
                     block_v=block_v, interpret=_interpret_default())
     return t[:, :b, 0]
 
@@ -162,12 +217,12 @@ def _rows(*cols):
     return jnp.stack(cols, axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "block_n", "block_v"))
+@functools.partial(jax.jit, static_argnames=("iters", "block_n"))
 def cand_pour(idsg: jax.Array, xg: jax.Array, Z: jax.Array,
-              W: jax.Array | None, iters: int, *, block_n: int = 128,
-              block_v: int = 256) -> jax.Array:
-    """Fused candidate gather + pour: the LC-ACT (iters >= 1) and LC-RWMD
-    masked-min (iters == 0) candidate reductions in one kernel launch.
+              W: jax.Array | None, iters: int, *,
+              block_n: int = 128) -> jax.Array:
+    """Candidate gather + pour: the LC-ACT (iters >= 1) and LC-RWMD
+    masked-min (iters == 0) candidate reductions.
 
     idsg/xg (nq, b, hmax); Z (nq, v, >= iters+1) cost ladder;
     W (nq, v, >= iters) capacity ladder (``None`` when iters == 0)
@@ -177,29 +232,28 @@ def cand_pour(idsg: jax.Array, xg: jax.Array, Z: jax.Array,
     """
     k = iters + 1
     if iters == 0:
-        return _cand_launch(idsg, xg, _rows(Z[..., 0]), "pour", k=1,
-                            block_n=block_n, block_v=block_v)
+        return _ladder_launch(idsg, xg, _rows(Z[..., 0]), "pour",
+                              block_n=block_n)
     # the pour's capacity prefix, taken per vocabulary row exactly as
     # ``lc.pour`` takes it per gathered entry (f32 accumulator)
     Wf = W[..., :iters].astype(jnp.float32)
     prefix = jnp.cumsum(Wf, axis=-1) - Wf
     table = jnp.concatenate([Z[..., :k].astype(jnp.float32), Wf, prefix],
                             axis=-1)
-    return _cand_launch(idsg, xg, jnp.swapaxes(table, 1, 2), "pour", k=k,
-                        iters=iters, block_n=block_n, block_v=block_v)
+    return _ladder_launch(idsg, xg, jnp.swapaxes(table, 1, 2), "pour", k=k,
+                          iters=iters, block_n=block_n)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_v"))
+@functools.partial(jax.jit, static_argnames=("block_n",))
 def cand_omr(idsg: jax.Array, xg: jax.Array, Z: jax.Array, W0: jax.Array,
-             *, block_n: int = 128, block_v: int = 256) -> jax.Array:
-    """Fused candidate gather + LC-OMR Algorithm-1 reduction.
+             *, block_n: int = 128) -> jax.Array:
+    """Candidate gather + LC-OMR Algorithm-1 reduction.
 
     idsg/xg (nq, b, hmax); Z (nq, v, 2) top-2 costs; W0 (nq, v) first
     capacities -> (nq, b) scores.
     """
     table = _rows(Z[..., 0], Z[..., 1], W0.astype(Z.dtype))
-    return _cand_launch(idsg, xg, table, "omr", block_n=block_n,
-                        block_v=block_v)
+    return _ladder_launch(idsg, xg, table, "omr", block_n=block_n)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_v"))
@@ -213,8 +267,8 @@ def cand_rev_min(idsg: jax.Array, xg: jax.Array, Dq: jax.Array,
     ``lc.PAD_DIST``, matching ``lc.rev_min_cand_blocked``).
     """
     h = Dq.shape[-1]
-    return _cand_launch(idsg, xg, jnp.swapaxes(Dq, 1, 2), "rev_min", qw=qw,
-                        qh=h, block_n=block_n, block_v=block_v)
+    return _onehot_launch(idsg, xg, jnp.swapaxes(Dq, 1, 2), "rev_min",
+                          qw=qw, qh=h, block_n=block_n, block_v=block_v)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_v"))
@@ -245,8 +299,8 @@ def cand_ict(idsg: jax.Array, xg: jax.Array, Dq: jax.Array,
         return jnp.pad(jnp.swapaxes(a, 1, 2), ((0, 0), (0, hs - h), (0, 0)))
     table = jnp.concatenate([seg(cost), seg(cap), seg(prefix),
                              dump[:, None, :]], axis=1)
-    return _cand_launch(idsg, xg, table, "ict", qh=h, block_n=block_n,
-                        block_v=block_v)
+    return _onehot_launch(idsg, xg, table, "ict", qh=h, block_n=block_n,
+                          block_v=block_v)
 
 
 # --------------------------------------------------- static block metadata
@@ -380,30 +434,60 @@ def _act_phase2_layout(*, nq: int, n: int, h: int, iters: int,
         ))
 
 
-def _cand_layout(family: str, *, nq: int, b: int, h: int, v: int,
-                 mode: str, k: int = 1, iters: int = 0, qh: int = 1,
-                 block_n: int = 128, block_v: int = 256,
-                 dtype: str = "float32") -> KernelBlocks:
-    """Both candidate families: one gather-accumulate grid per mode
-    (``kernels/cand_pour``). The table rides as exact bf16 parts — one
-    for a bf16 storage table, three for float32 (the pour's capacity
-    prefix and ICT's ladders are always float32)."""
-    _positive(nq=nq, b=b, h=h, v=v, k=k, qh=qh, block_n=block_n,
+def _cand_pour_layout(*, nq: int, b: int, h: int, v: int, k: int,
+                      iters: int, mode: str = "pour", block_n: int = 128,
+                      dtype: str = "float32") -> KernelBlocks:
+    """The ladder modes' reduction grid (``kernels/cand_pour``): each step
+    reads one candidate block of gathered ladders. They are float32
+    whatever the storage ``dtype`` (the wrapper upcasts the table before
+    it gathers), and no buffer holds the vocabulary ``v``."""
+    assert mode in POUR_MODES, mode
+    _positive(nq=nq, b=b, h=h, v=v, k=k, block_n=block_n)
+    if iters < 0:
+        raise ValueError(f"iters must be >= 0, got {iters}")
+    rows = table_rows(mode, k=k, iters=iters)
+    block_n = min(block_n, _round_up(b, 8))
+    bp = _round_up(b, block_n)
+    hp = _round_up(h, tiling.LANE)
+    return KernelBlocks(
+        family="cand_pour",
+        grid=(nq, bp // block_n),
+        buffers=(
+            BlockBuffer("ladders", (1, rows, block_n, hp),
+                        array=(nq, rows, bp, hp)),
+            BlockBuffer("xg", (1, block_n, hp), array=(nq, bp, hp)),
+            BlockBuffer("t", (1, block_n, 1), role="out",
+                        array=(nq, bp, 1)),
+            # one sublane tile of candidate rows: the rung in use and the
+            # pour's running sums
+            BlockBuffer("reduce_tmp", (4, tiling.SUBLANE, hp),
+                        role="scratch"),
+        ))
+
+
+def _cand_dist_layout(*, nq: int, b: int, h: int, v: int, qh: int,
+                      mode: str = "rev_min", block_n: int = 128,
+                      block_v: int = 256,
+                      dtype: str = "float32") -> KernelBlocks:
+    """The one-hot modes' gather-accumulate grid (``kernels/cand_pour``).
+    The table rides as exact bf16 parts: one for a bf16 storage table,
+    three for float32 (ICT's ladders are always float32)."""
+    assert mode in DIST_MODES, mode
+    _positive(nq=nq, b=b, h=h, v=v, qh=qh, block_n=block_n,
               block_v=block_v)
-    rows = table_rows(mode, k=k, iters=iters, qh=qh)
-    f32_table = (dtype != "bfloat16" or mode == "ict"
-                 or (mode == "pour" and iters > 0))
+    rows = table_rows(mode, qh=qh)
+    f32_table = dtype != "bfloat16" or mode == "ict"
     tab_rows = (3 if f32_table else 1) * rows
     block_n = min(block_n, _round_up(b, 8))
     block_v = min(block_v, _round_up(v, 8))
     bp, vp = _round_up(b, block_n), _round_up(v, block_v)
     hp = _round_up(h, tiling.LANE)
     reduce_tmp = {"rev_min": (rows, hp),
-                  "ict": (3, segment_rows(qh), hp)}.get(mode, (8, hp))
+                  "ict": (3, segment_rows(qh), hp)}[mode]
     qw = ((BlockBuffer("qw", (1, rows, 1), array=(nq, rows, 1)),)
           if mode == "rev_min" else ())
     return KernelBlocks(
-        family=family,
+        family="cand_dist",
         grid=(nq, bp // block_n, vp // block_v),
         buffers=(
             BlockBuffer("idsg", (1, block_n, hp), "int32",
@@ -421,21 +505,6 @@ def _cand_layout(family: str, *, nq: int, b: int, h: int, v: int,
             BlockBuffer("slab", (rows, hp), role="scratch"),
             BlockBuffer("reduce_tmp", reduce_tmp, role="scratch"),
         ))
-
-
-def _cand_pour_layout(*, nq: int, b: int, h: int, v: int, k: int,
-                      iters: int, mode: str = "pour", **blocks
-                      ) -> KernelBlocks:
-    assert mode in POUR_MODES, mode
-    return _cand_layout("cand_pour", nq=nq, b=b, h=h, v=v, mode=mode, k=k,
-                        iters=iters, **blocks)
-
-
-def _cand_dist_layout(*, nq: int, b: int, h: int, v: int, qh: int,
-                      mode: str = "rev_min", **blocks) -> KernelBlocks:
-    assert mode in DIST_MODES, mode
-    return _cand_layout("cand_dist", nq=nq, b=b, h=h, v=v, mode=mode, qh=qh,
-                        **blocks)
 
 
 #: family name -> layout function. The enumerable surface

@@ -149,7 +149,7 @@ def test_vmem_rejects_over_budget_blocks():
     out = vmem.check_launch(
         "seeded", "cand_pour",
         dict(nq=8, b=4096, h=500, v=69_682, k=8, iters=7,
-             block_n=4096, block_v=256))
+             block_n=4096))
     assert any("exceeds" in v.message for v in out)
 
 
@@ -163,12 +163,12 @@ def test_vmem_rejects_blocks_mosaic_refuses():
     assert vmem.check_launch("seeded", "cand_dist",
                              {**dims, "block_n": 8}) == []
     # a vocabulary slab must be a lane multiple unless it spans the table
-    out = vmem.check_launch("seeded", "cand_pour",
-                            dict(nq=8, b=64, h=16, v=4096, k=4, iters=3,
+    out = vmem.check_launch("seeded", "cand_dist",
+                            dict(nq=8, b=64, h=16, v=4096, qh=16,
                                  block_v=64))
     assert any("(8, 128)-or-full" in v.message for v in out)
-    assert vmem.check_launch("seeded", "cand_pour",
-                             dict(nq=8, b=64, h=16, v=40, k=4, iters=3,
+    assert vmem.check_launch("seeded", "cand_dist",
+                             dict(nq=8, b=64, h=16, v=40, qh=16,
                                   block_v=64)) == []
 
 

@@ -2,7 +2,9 @@
 
 The contract (see ``kernels/cand_pour``'s module docstring):
 
-* the in-kernel one-hot gather is BITWISE equal to an XLA gather;
+* both gathers are BITWISE equal to an XLA gather: the ladder modes'
+  (pour, omr) hand the kernel ``table[:, ids]`` itself, the one-hot
+  modes' (rev_min, ict) in-kernel one-hot gather reproduces it;
 * every fused candidate kernel matches its XLA-gather oracle and the
   reference ``lc_*_scores_cand`` engine to within ``ULP_TOL`` (4) float32
   ulps — the kernels reuse the reference reduction formulas on
@@ -176,7 +178,7 @@ def test_cand_pour_op_matches_oracle(nq, b, hmax, v, iters, rng):
     idsg, xg = _cand_inputs(rng, nq, b, hmax, v)
     Z, W = _handoff(rng, nq, v, iters + 1, iters)
     got = kops.cand_pour(idsg, xg, Z, None if iters == 0 else W, iters,
-                         block_n=8, block_v=16)
+                         block_n=8)
     want = kref.cand_pour_ref(idsg, xg, Z, None if iters == 0 else W, iters)
     assert_ulp_equal(got, want, err_msg=f"pour it={iters}")
 
@@ -187,7 +189,7 @@ def test_cand_omr_op_matches_oracle(nq, b, hmax, v, rng):
     Z, W = _handoff(rng, nq, v, 2, 1)
     # exact-zero nearest costs exercise the overlap branch
     Z = Z.at[:, ::3, 0].set(0.0)
-    got = kops.cand_omr(idsg, xg, Z, W[..., 0], block_n=8, block_v=16)
+    got = kops.cand_omr(idsg, xg, Z, W[..., 0], block_n=8)
     want = kref.cand_omr_ref(idsg, xg, Z, W[..., 0])
     assert_ulp_equal(got, want, err_msg="omr")
 
@@ -240,6 +242,136 @@ def test_cand_gather_is_bitwise_exact(rng):
     )(ids, parts)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(table[:, ids[0]]))
+
+
+# ------------------------------------------- the two gathers, by mode
+
+def _pallas_grids(fn, *args):
+    """Grids of every ``pallas_call`` in ``fn``'s jaxpr, nested ones
+    (jit, ``lax.map``) included."""
+    import jax
+
+    grids = []
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                grids.append(tuple(e.params["grid_mapping"].grid))
+            for p in e.params.values():
+                for sub in p if isinstance(p, (list, tuple)) else [p]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("mode,iters", [("pour", 0), ("pour", 3),
+                                        ("omr", 0)])
+def test_cand_ladder_gather_is_table_at_ids(monkeypatch, rng, mode, iters):
+    """The ladder modes hand the kernel each slot's rungs gathered by id:
+    ``table[:, ids]`` of the query's row-major table, bit for bit, pad
+    slots (id 0) and pad candidate rows included."""
+    nq, b, hmax, v, block_n = 2, 13, 7, 37, 8
+    idsg, xg = _cand_inputs(rng, nq, b, hmax, v)
+    Z, W = _handoff(rng, nq, v, iters + 2, max(iters, 1))
+    handed = []
+
+    def spy(x, g, *args, **kw):
+        handed.append(np.asarray(g))
+        return jnp.zeros(x.shape[:2] + (1,), jnp.float32)
+
+    monkeypatch.setattr(kops, "cand_pallas", spy)
+    if mode == "omr":
+        kops.cand_omr.__wrapped__(idsg, xg, Z, W[..., 0], block_n=block_n)
+        cols = [Z[..., 0], Z[..., 1], W[..., 0]]
+    else:
+        kops.cand_pour.__wrapped__(idsg, xg, Z, W if iters else None, iters,
+                                   block_n=block_n)
+        Wf = W[..., :iters]
+        cols = ([Z[..., l] for l in range(iters + 1)]
+                + [Wf[..., l] for l in range(iters)]
+                + [(jnp.cumsum(Wf, axis=-1) - Wf)[..., l]
+                   for l in range(iters)])
+    table = np.asarray(jnp.stack(cols, axis=1))          # (nq, rows, v)
+    ids = np.zeros((nq, 16, 128), np.int32)              # padded as launched
+    ids[:, :b, :hmax] = np.asarray(idsg)
+    (got,) = handed
+    want = np.stack([table[q][:, ids[q]] for q in range(nq)])
+    assert got.shape == (nq, len(cols), 16, 128)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("mode", ["pour", "omr"])
+def test_cand_ladder_grid_has_no_vocabulary_axis(rng, mode):
+    """The ladder modes' launch is a (query, candidate block) grid: the
+    same at v = 48 and v = 4,800 for the same b and hmax."""
+    nq, b, hmax = 2, 24, 7
+    grids = []
+    for v in (48, 4800):
+        idsg, xg = _cand_inputs(rng, nq, b, hmax, v)
+        Z, W = _handoff(rng, nq, v, 4, 3)
+        if mode == "omr":
+            grids.append(_pallas_grids(
+                lambda i, x, z, w: kops.cand_omr(i, x, z, w, block_n=8),
+                idsg, xg, Z, W[..., 0]))
+        else:
+            grids.append(_pallas_grids(
+                lambda i, x, z, w: kops.cand_pour(i, x, z, w, 3, block_n=8),
+                idsg, xg, Z, W))
+    assert grids[0] == grids[1] == [(nq, b // 8)]
+
+
+@pytest.mark.parametrize("mode", ["rev_min", "ict"])
+def test_cand_dist_modes_keep_the_onehot_gather(rng, mode):
+    """Reverse RWMD and ICT keep the in-kernel one-hot gather: their grid
+    streams the vocabulary in ``block_v`` slabs, and their scores match
+    the XLA-gather oracle."""
+    nq, b, hmax, h = 2, 13, 7, 6
+    op = kops.cand_rev_min if mode == "rev_min" else kops.cand_ict
+    oracle = (kref.cand_rev_min_ref if mode == "rev_min"
+              else kref.cand_ict_ref)
+    for v, slabs in ((48, 3), (4800, 300)):
+        idsg, xg = _cand_inputs(rng, nq, b, hmax, v)
+        Dq = jnp.asarray(rng.uniform(size=(nq, v, h)), jnp.float32)
+        qw = jnp.asarray(rng.uniform(size=(nq, h)), jnp.float32)
+
+        def run(i, x, d, w):
+            return op(i, x, d, w, block_n=8, block_v=16)
+        assert _pallas_grids(run, idsg, xg, Dq, qw) == [(nq, 2, slabs)]
+        assert_ulp_equal(run(idsg, xg, Dq, qw), oracle(idsg, xg, Dq, qw),
+                         err_msg=f"{mode} v={v}")
+
+
+@pytest.mark.parametrize("mode", ["pour", "omr"])
+def test_cand_ladder_chunks_over_the_gather_cap(monkeypatch, rng, mode):
+    """Over ``GATHER_CAP_BYTES`` the ladder is gathered and reduced in
+    chunks of whole candidate blocks, with the unchunked scores."""
+    import jax
+
+    nq, b, hmax, v, iters = 2, 45, 7, 37, 3
+    idsg, xg = _cand_inputs(rng, nq, b, hmax, v)
+    Z, W = _handoff(rng, nq, v, iters + 1, iters)
+    if mode == "omr":
+        def run(i, x, z, w):
+            return kops.cand_omr(i, x, z, w[..., 0], block_n=8)
+    else:
+        def run(i, x, z, w):
+            return kops.cand_pour(i, x, z, w, iters, block_n=8)
+    args = (idsg, xg, Z, W)
+    want = np.asarray(run(*args))
+    rows = 3 if mode == "omr" else 1 + 3 * iters
+    # room for two blocks of 8 candidate rows of 128 slots
+    cap = 2 * nq * rows * 8 * 128 * 4
+    monkeypatch.setattr(kops, "GATHER_CAP_BYTES", cap)
+    jax.clear_caches()
+    try:
+        assert _pallas_grids(run, *args) == [(nq, 2)]   # 48 rows, 3 chunks
+        got = np.asarray(run(*args))
+    finally:
+        jax.clear_caches()
+    np.testing.assert_array_equal(got, want)
 
 
 # --------------------------------------------- ict remainder-dump contract

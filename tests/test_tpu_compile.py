@@ -107,22 +107,48 @@ CAND_MODES = {
 }
 
 
+def _cand_shapes(mode, b, hp, vp, **kw):
+    """Arguments of ``cand_pallas``: gathered (nq, rows, b, hp) ladders
+    for the ladder modes, ids and the split (nq, 3 * rows, vp) table for
+    the one-hot modes."""
+    rows = table_rows(mode, **kw)
+    x = ((NQ, b, hp), jnp.float32)
+    if mode not in DIST_MODES:
+        return [x, ((NQ, rows, b, hp), jnp.float32)]
+    shapes = [x, ((NQ, 3 * rows, vp), jnp.bfloat16), ((NQ, b, hp), jnp.int32)]
+    if mode == "rev_min":
+        shapes.append(((NQ, rows, 1), jnp.float32))
+    return shapes
+
+
 @pytest.mark.parametrize("mode", sorted(CAND_MODES))
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_cand_kernels_compile(one_chip, width, mode):
     w = WIDTHS[width]
     block_n, kw = CAND_MODES[mode]
     kw = dict(kw, qh=w.hmax) if mode in DIST_MODES else kw
-    b, hp = 512, round_up(w.hmax, LANE)
-    vp = round_up(w.vocab, 256)
-    rows = table_rows(mode, **kw)
-    shapes = [((NQ, b, hp), jnp.int32), ((NQ, b, hp), jnp.float32),
-              ((NQ, 3 * rows, vp), jnp.bfloat16)]
-    if mode == "rev_min":
-        shapes.append(((NQ, rows, 1), jnp.float32))
-    _compile(lambda *a: cand_pallas(*a, mode=mode, block_n=block_n,
-                                       block_v=256, **kw),
+    shapes = _cand_shapes(mode, 512, round_up(w.hmax, LANE),
+                          round_up(w.vocab, 256), **kw)
+    _compile(lambda *a: cand_pallas(*a, mode=mode, block_n=block_n, **kw),
              one_chip, *shapes)
+
+
+#: launch -> (candidate rows, ladder kwargs): the reductions of the
+#: ``news-fast-cascade`` cell, stage 2 (RWMD, one rung over 7,680 rows)
+#: and the rescorer (ACT-3, ten rungs over 1,024), at 20NEWS's 512 slots.
+CELL_LADDERS = {
+    "stage2": (7680, dict(k=1, iters=0)),
+    "rescore": (1024, dict(k=4, iters=3)),
+}
+
+
+@pytest.mark.parametrize("launch", sorted(CELL_LADDERS))
+def test_cand_ladder_kernel_compiles_at_cell_widths(one_chip, launch):
+    b, kw = CELL_LADDERS[launch]
+    hp = round_up(NEWS.hmax, LANE)
+    assert table_rows("pour", **kw) == {"stage2": 1, "rescore": 10}[launch]
+    _compile(lambda *a: cand_pallas(*a, mode="pour", block_n=256, **kw),
+             one_chip, *_cand_shapes("pour", b, hp, 0, **kw))
 
 
 def test_cand_kernel_compiles_under_highest_precision(one_chip):
@@ -131,12 +157,11 @@ def test_cand_kernel_compiles_under_highest_precision(one_chip):
     "highest" matmul precision does not reach the bf16 one-hot gather:
     Mosaic refuses fp32 contraction on bf16 operands."""
     w = NEWS
-    rows = table_rows("pour", k=4, iters=3)
-    shapes = [((NQ, 512, 512), jnp.int32), ((NQ, 512, 512), jnp.float32),
-              ((NQ, 3 * rows, round_up(w.vocab, 256)), jnp.bfloat16)]
+    kw = dict(qh=w.hmax)
+    shapes = _cand_shapes("ict", 512, round_up(w.hmax, LANE),
+                          round_up(w.vocab, 256), **kw)
     with jax.default_matmul_precision("highest"):
-        _compile(lambda *a: cand_pallas(*a, mode="pour", k=4, iters=3,
-                                        block_n=128, block_v=256),
+        _compile(lambda *a: cand_pallas(*a, mode="ict", block_n=8, **kw),
                  one_chip, *shapes)
 
 
@@ -200,7 +225,11 @@ def test_compiled_kernels_carry_layer_scopes(one_chip, mosaic_kernels):
     assert got == {"dist_topk_pallas": {("emd.phase1",)},
                    "act_phase2_pallas": {("emd.phase2",)}}
     got = _kernel_scopes(jax.jit(cascade).lower(*args).compile().as_text())
-    got.pop("fusion")
+    # Each candidate kernel's ladders are gathered by id ahead of it, in
+    # the stage that scores them.
+    fusions = got.pop("fusion")
+    for stage in ("emd.cascade.stage2.rwmd", "emd.cascade.rescore.act"):
+        assert (stage, "emd.phase2", "emd.ladder_gather") in fusions
     assert got == {"cand_pallas": {("emd.cascade.stage2.rwmd", "emd.phase2"),
                                    ("emd.cascade.rescore.act",
                                     "emd.phase2")}}
